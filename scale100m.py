@@ -89,10 +89,10 @@ def _gather_host_rows_f64(vec):
 
 
 def main():
-    from transmogrifai_tpu.utils.backend import ensure_backend, start_keepalive
+    from transmogrifai_tpu.utils.backend import compile_cache_dir, require_tpu
 
-    platform, fallback = ensure_backend(fresh=True)
-    start_keepalive(60.0)
+    dev = require_tpu("scale100m")
+    compile_cache_dir()
     from transmogrifai_tpu.parallel import mesh
     from transmogrifai_tpu.utils.listener import OpListener
 
@@ -107,7 +107,8 @@ def main():
 
     t_start = time.perf_counter()
     phases = {}
-    log(f"platform={platform} rows={N_ROWS} local_rows={n_local} "
+    log(f"platform={dev['platform']} kind={dev['kind']} "
+        f"devices={dev['count']} rows={N_ROWS} local_rows={n_local} "
         f"range=[{lo},{hi})")
 
     t0 = time.perf_counter()
@@ -126,11 +127,7 @@ def main():
     phases["train_s"] = round(time.perf_counter() - t0, 2)
     log("train done")
 
-    stage_times = {}
-    for m in listener.metrics.stage_metrics:
-        key = f"{m.stage_name}.{m.phase}"
-        stage_times[key] = round(
-            stage_times.get(key, 0.0) + m.duration_ms / 1e3, 2)
+    stage_times = scale10m.stage_times(listener)
     best_model = None
     for st in model.stages:
         s = getattr(st, "summary", None)
@@ -162,7 +159,8 @@ def main():
         "unit": "s",
         "rows": N_ROWS,
         "raw_features": scale10m.N_NUM + scale10m.N_CAT,
-        "platform": platform,
+        "platform": dev["platform"], "device_kind": dev["kind"],
+        "device_count": dev["count"],
         "host_count": H, "host_index": h,
         "host_rows": [lo, hi],
         "phases": phases,
@@ -190,8 +188,6 @@ def main():
             "projected_train_s_by_hosts": {
                 str(n): round(proj / n, 1) for n in (1, 2, 4, 8, 16)},
         }
-    if fallback:
-        out["backend_fallback"] = fallback
 
     line = json.dumps(out)
     print(line)

@@ -3,12 +3,19 @@
 - obs.regress.compare: direction-aware verdicts with relative tolerance,
   zero-baseline handling, platform-mismatch skip;
 - baseline discovery picks the newest BENCH round + STREAM_BENCH;
-- tools/perfgate.py (subprocess): exit 0 on the unchanged tree (the
-  acceptance check), 1 on a synthetically regressed record, 0 under
+- tools/perfgate.py (subprocess): exit 0 when the baselines are checked
+  against themselves, 1 on a synthetically regressed record, 0 under
   --warn-only, 2 with no baselines; JSONL records are extracted.
+
+The repo commits no ``BENCH_r*.json`` any more (the old rounds' records went
+with the transport they were taken through), so the baseline directory is a
+fixture: two bench rounds in the ``BENCH_r*`` wrapper shape plus the
+committed ``STREAM_BENCH.json``.
 """
+import functools
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -26,6 +33,18 @@ def _base(**kw):
            "platform": "tpu"}
     rep.update(kw)
     return rep
+
+
+@pytest.fixture
+def baseline_dir(tmp_path):
+    root = tmp_path / "baselines"
+    root.mkdir()
+    (root / "BENCH_r08.json").write_text(
+        json.dumps({"rc": 0, "parsed": _base(value=12.0)}))
+    (root / "BENCH_r09.json").write_text(
+        json.dumps({"rc": 0, "parsed": _base()}))
+    shutil.copy(os.path.join(REPO, "STREAM_BENCH.json"), root)
+    return str(root)
 
 
 def test_compare_ok_and_directions():
@@ -74,12 +93,12 @@ def test_compare_missing_keys_skip():
     assert st["mfu"] == "skipped_missing"
 
 
-def test_load_baselines_repo_root():
-    bl = regress.load_baselines(REPO)
+def test_load_baselines_newest_round(baseline_dir):
+    bl = regress.load_baselines(baseline_dir)
     assert "selector_sweep_models_per_sec" in bl
     assert "transform_stream_speedup" in bl
     name, rep = bl["selector_sweep_models_per_sec"]
-    assert name.startswith("BENCH_r") and isinstance(rep["value"], float)
+    assert name == "BENCH_r09.json" and rep["value"] == 200.0
 
 
 def test_extract_reports_jsonl(tmp_path):
@@ -99,15 +118,20 @@ def _run_gate(*args, cwd=REPO):
                           capture_output=True, text=True, cwd=cwd)
 
 
-def test_gate_self_check_passes():
-    """The acceptance check: bare perfgate on the unchanged tree exits 0."""
-    r = _run_gate()
+def _gate(baseline_dir, *args):
+    return _run_gate("--baseline-dir", baseline_dir, *args)
+
+
+def test_gate_self_check_passes(baseline_dir):
+    """Baselines checked against themselves exit 0."""
+    r = _gate(baseline_dir)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "pass" in r.stdout
 
 
-def test_gate_regressed_record_fails(tmp_path):
-    bl = regress.load_baselines(REPO)
+def test_gate_regressed_record_fails(tmp_path, baseline_dir):
+    _run_gate = functools.partial(_gate, baseline_dir)
+    bl = regress.load_baselines(baseline_dir)
     _, base = bl["selector_sweep_models_per_sec"]
     bad = dict(base, value=base["value"] * 0.5)
     p = tmp_path / "regressed.json"
@@ -121,8 +145,9 @@ def test_gate_regressed_record_fails(tmp_path):
     assert "REGRESSION (warn-only)" in r.stdout
 
 
-def test_gate_fresh_jsonl_and_unknown_metric(tmp_path):
-    bl = regress.load_baselines(REPO)
+def test_gate_fresh_jsonl_and_unknown_metric(tmp_path, baseline_dir):
+    _run_gate = functools.partial(_gate, baseline_dir)
+    bl = regress.load_baselines(baseline_dir)
     _, base = bl["selector_sweep_models_per_sec"]
     p = tmp_path / "telemetry.jsonl"
     p.write_text(json.dumps({"report": dict(base)}) + "\n"
@@ -136,8 +161,9 @@ def test_gate_fresh_jsonl_and_unknown_metric(tmp_path):
     assert [v["metric"] for v in skips] == ["brand_new"]
 
 
-def test_gate_tolerance_flag(tmp_path):
-    bl = regress.load_baselines(REPO)
+def test_gate_tolerance_flag(tmp_path, baseline_dir):
+    _run_gate = functools.partial(_gate, baseline_dir)
+    bl = regress.load_baselines(baseline_dir)
     _, base = bl["selector_sweep_models_per_sec"]
     mild = dict(base, value=base["value"] * 0.9)  # -10%
     p = tmp_path / "mild.json"

@@ -1,0 +1,318 @@
+"""Ask the v5e's compiler first: the jitted programs of the main path, at the
+shapes ``chip_smoke.py`` launches, compiled for a chip that is described and
+not attached (on-chip-measurement guide, section 2).
+
+What a pass means: the TPU compiler accepts the program and it fits one
+chip's 16 GB.  Nothing runs — no result, no time.
+
+The topology is described inside a module-scoped fixture, never at import:
+only the test worker that is handed this file loads the TPU library, and it
+compiles in its own process with jax's persistent cache switched off (an
+entry compiled for an absent chip cannot be read back).  ``jax.devices()``
+is still the CPU here, so the TPU-only histogram branch is steered with the
+repo's ``TMOG_HIST_MATMUL`` knob, read at trace time.
+
+Time: about two minutes in all on this sandbox's 8 cores, not the one
+minute asked for — the programs of this repo are whole sweeps, not
+two-second kernels.  Whole programs are kept where one takes under a
+minute: phase A's 28-candidate sweep is the slowest (45-60 s, most of it
+the three forest depth groups), the streamed chunk program ~20 s.  Two are
+cut to their dominant kernels, and say so where they are cut: phase B's
+sweep compiles its family training kernels (``_run_scores``) and not the
+metric kernel again, which costs ~30 s at any shape and is inside phase A's
+program; the row-sharded program keeps the linear and boosting fragments of
+a model column (a full column of the default grid compiles for ~50 s) — the
+same ``mesh_psum`` / ``mesh_all_gather`` call sites, the histogram psum
+inside ``_grow_level`` included — and leaves the forests to phase A.
+"""
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402  (shared shape constants; imports no jax)
+import scale10m  # noqa: E402
+
+HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TMOG_HIST_MATMUL", "1")  # the branch a TPU takes by itself
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.clear_caches()  # the knob is read at trace time
+    yield desc
+    mp.undo()
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+    jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    """Shapes of ``tree``'s leaves, placed by ``sharding``."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _rows(tree, n):
+    """The same leaves with the leading (row) axis set to ``n``."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((n,) + tuple(a.shape[1:]), a.dtype,
+                                       sharding=a.sharding), tree)
+
+
+def _fits(compiled) -> float:
+    ma = compiled.memory_analysis()
+    total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+             + ma.output_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one v5e"
+    return total
+
+
+# ---------------------------------------------------------------------------
+# Phase A: the Titanic app's selector sweep and its serving program
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def titanic():
+    """helloworld's workflow on its seeded frame: the selector, the arrays
+    its sweep sees and the fused plan of the full default grid."""
+    from helloworld.titanic import build_workflow, titanic_data
+    from transmogrifai_tpu.impl.sweep_fragments import build_sweep_plan
+    from transmogrifai_tpu.readers import DataReaders
+
+    wf, pred = build_workflow()
+    sel = pred.origin_stage
+    label_f, vec_f = sel.inputs
+    wf.set_reader(DataReaders.Simple.custom(titanic_data(),
+                                            key="PassengerId"))
+    data = wf.compute_data_up_to(vec_f, label_f)
+    X = np.asarray(data[vec_f.name].values, np.float32)
+    y = np.asarray(data[label_f.name].values, np.float32)
+    train_w, val_mask = sel.validator.make_folds(len(y), None)
+    return {"wf": wf, "sel": sel, "X": X, "y": y,
+            "train_w": np.asarray(train_w, np.float32),
+            "val_w": np.asarray(val_mask, np.float32),
+            "plan": lambda models: build_sweep_plan(
+                models, X, y, train_w, sel.validator.evaluator)}
+
+
+def test_phase_a_fused_sweep(titanic, one_chip):
+    from transmogrifai_tpu.ops import sweep
+
+    sel = titanic["sel"]
+    assert sum(len(g) for _, g in sel.models) == chip_smoke.TITANIC_CANDIDATES
+    assert titanic["train_w"].shape[0] == chip_smoke.TITANIC_FOLDS
+    plan = titanic["plan"](sel.models)
+    args = _on(one_chip, (plan.X, tuple(plan.xbs), plan.y, titanic["train_w"],
+                          titanic["val_w"], plan.blob))
+    compiled = sweep._run.lower(plan.spec, *args).compile()
+    _fits(compiled)
+    # the one-hot formulation: histograms ride dot ops, not segment scatters
+    assert "segment" not in compiled.as_text()
+
+
+def test_serve_largest_bucket_program(titanic, one_chip):
+    """``BucketScorer``'s program is the fitted transform DAG fused for one
+    bucket; the prediction head runs outside it, so it does not depend on
+    which candidate won and a one-candidate selector fits the DAG here."""
+    from transmogrifai_tpu.serve.aot import BucketScorer
+    from transmogrifai_tpu.serve.registry import shape_buckets
+
+    wf, sel = titanic["wf"], titanic["sel"]
+    full = sel.models
+    est, grids = full[0]
+    sel.models = [(est, grids[:1])]
+    try:
+        model = wf.train()
+    finally:
+        sel.models = full
+    buckets = shape_buckets(chip_smoke.SERVE_MAX_BATCH)
+    assert buckets[-1] == max(chip_smoke.SERVE_REQUEST_ROWS)
+    scorer = BucketScorer(model, buckets, jax.devices()[0])
+    args = _on(one_chip, scorer._template_args(buckets[-1]))
+    _fits(scorer._jitted.lower(args).compile())
+
+
+def test_rowsharded_program_on_2x2(titanic, topo):
+    """One model column of a 2x2 mesh: ``_run_rs`` over the column's two
+    chips; every collective stays inside that data-axis pair."""
+    from transmogrifai_tpu.impl.classification.trees import (
+        OpRandomForestClassifier)
+    from transmogrifai_tpu.ops import sweep
+    from transmogrifai_tpu.parallel import mesh as mesh_mod
+    from transmogrifai_tpu.parallel.spec_partition import partition_spec
+
+    models = [(e, g) for e, g in titanic["sel"].models
+              if not isinstance(e, OpRandomForestClassifier)]
+    plan = titanic["plan"](models)
+    F = titanic["train_w"].shape[0]
+    grid = mesh_mod.make_mesh(n_data=2, n_model=2, devices=topo.devices)
+    shard = partition_spec(plan.spec, plan.blob, 2, plan.n_rows,
+                           plan.n_features, F)[0]
+    column = Mesh(np.asarray(grid.devices)[:, 0], (mesh_mod.DATA_AXIS,))
+    rows = NamedSharding(column, P(mesh_mod.DATA_AXIS))
+    folds = NamedSharding(column, P(None, mesh_mod.DATA_AXIS))
+    n = plan.n_rows
+    n_pad = -(-n // 2) * 2
+    args = (_rows(_on(rows, (plan.X, tuple(plan.xbs), plan.y)), n_pad)
+            + (jax.ShapeDtypeStruct((F, n_pad), np.float32, sharding=folds),)
+            * 2
+            + (_on(NamedSharding(column, P()), shard.blob),))
+    compiled = sweep._run_rs.lower(shard.spec, column, n, *args).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert "all-reduce" in text and "all-gather" in text
+    groups = set(re.findall(r"replica_groups=\{(\{[^}]*\}(?:,\{[^}]*\})*)\}",
+                            text))
+    assert groups == {"{0,1}"}, groups  # the column's data pair, nothing else
+
+
+# ---------------------------------------------------------------------------
+# Phase B: the scale10m pipeline at chip_smoke's rows x 500 features
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def scale_features():
+    """scale10m's feature DAG (500 raw features -> transmogrify ->
+    SanityChecker) fitted on a small seeded sample: the fitted stages fix
+    the vector width and the streamed program; rows are set at lowering."""
+    from transmogrifai_tpu import OpWorkflow
+
+    width = dict(n_num=scale10m.FULL_NUM, n_cat=scale10m.FULL_CAT)
+    df = scale10m.synthesize(1024, seed=7, **width)
+    label, checked = scale10m.features(**width)
+    model = (OpWorkflow().set_result_features(checked).set_input_dataset(df)
+             .train())
+    return {"df": df, "model": model, "checked": checked.name,
+            "width": int(model.train_data[checked.name].values.shape[1])}
+
+
+def test_phase_b_fused_sweep_fits_one_chip(scale_features, one_chip):
+    """The 64-candidate LR + SVC + MLP plan at phase B's sweep rows x vector
+    width, chunked as the validator chunks it.  Each chunk compiles as
+    ``_run_scores``: the family training kernels, which hold X.  The metric
+    kernel that follows (inside ``_run``, or as ``_run_metrics`` past
+    ``SPLIT_METRICS_ELEMS``) is the one phase A's program already contains;
+    it costs ~30 s to compile at any shape and holds the [F, C, n] scores
+    that ``TMOG_FUSED_SCORES_BYTES`` bounds."""
+    from transmogrifai_tpu.evaluators import OpBinaryClassificationEvaluator
+    from transmogrifai_tpu.impl.sweep_fragments import build_sweep_plan
+    from transmogrifai_tpu.impl.tuning.validators import _chunk_candidates
+    from transmogrifai_tpu.ops import sweep
+    from transmogrifai_tpu.utils.env import env_float
+
+    n = chip_smoke.phase_b_sweep_rows(chip_smoke.PHASE_B_ROWS)
+    d, F = scale_features["width"], scale10m.FOLDS
+    grid = scale10m.candidates()
+    assert sum(len(g) for _, g in grid) == chip_smoke.PHASE_B_CANDIDATES
+    budget = env_float("TMOG_FUSED_SCORES_BYTES", 3e8)
+    chunks = _chunk_candidates(grid, max(int(budget // (F * n * 4.0)), 1))
+    # the plan needs data only for its label check: tiny rows, real width
+    rng = np.random.default_rng(0)
+    Xs = rng.normal(size=(64, d)).astype(np.float32)
+    ys = (np.arange(64) % 2).astype(np.float32)
+    rows = jax.ShapeDtypeStruct((n,), np.float32, sharding=one_chip)
+    folds = jax.ShapeDtypeStruct((F, n), np.float32, sharding=one_chip)
+    X = jax.ShapeDtypeStruct((n, d), np.float32, sharding=one_chip)
+    specs = set()  # chunks with the same fragments share one program
+    for chunk in chunks:
+        plan = build_sweep_plan(chunk, Xs, ys, np.ones((F, 64), np.float32),
+                                OpBinaryClassificationEvaluator())
+        assert plan is not None and plan.xbs == ()
+        if plan.spec in specs:
+            continue
+        specs.add(plan.spec)
+        scores_bytes = F * len(plan.spec[2]) * n * 4
+        assert scores_bytes <= budget
+        held = _fits(sweep._run_scores.lower(
+            plan.spec, X, (), rows, folds, _on(one_chip, plan.blob)).compile())
+        assert held + 16 * scores_bytes < HBM_BYTES  # room for the metric sort
+
+
+def test_streamed_chunk_program(scale_features, one_chip):
+    """One chunk of the streamed transform program: every fusable stage of
+    the fitted DAG, ``chunk_rows`` x 500 raw features in."""
+    from transmogrifai_tpu.workflow import stream
+
+    df, model = scale_features["df"], scale_features["model"]
+    plan = stream.build_plan(df, model.dag, live={scale_features["checked"]})
+    assert plan is not None and plan.n_stream >= 2
+    host_args, _ = stream.chunk_args(plan, df, 0, len(df), len(df))
+    C = min(stream.chunk_rows(), chip_smoke.PHASE_B_ROWS)
+    assert C > len(df)
+    _fits(stream.program_for(plan).lower(
+        _rows(_on(one_chip, host_args), C)).compile())
+
+
+# ---------------------------------------------------------------------------
+# The level-histogram build of ops/trees.py, both formulations
+# ---------------------------------------------------------------------------
+N_BINS, SLOTS, CHANNELS = 32, 64, 2  # 32 = the MLlib maxBins default
+
+
+def test_histogram_matmul_at_the_2gb_rule(scale_features, one_chip,
+                                          monkeypatch):
+    """The one-hot matmul at the largest n the 2 GB rule admits for phase
+    B's width (the rule counts the shared [n, c1*d*B] one-hot)."""
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.ops import trees as Tr
+
+    d = scale_features["width"]
+    n = int(2e9 // (d * N_BINS * CHANNELS * 4))
+    monkeypatch.delenv("TMOG_HIST_MATMUL")  # the rule itself, as a TPU reads it
+    monkeypatch.setattr(Tr.jax, "default_backend", lambda: "tpu")
+    assert Tr._hist_via_matmul(n, d, N_BINS, CHANNELS)
+    assert not Tr._hist_via_matmul(n + 1, d, N_BINS, CHANNELS)
+
+    def level(Xb, gh, slot, w):
+        Og = Tr.grad_onehot(Xb, gh, N_BINS)
+        S = jax.nn.one_hot(slot, SLOTS, dtype=jnp.float32)
+        return Tr._level_histograms_mm(Og, S, w, SLOTS, N_BINS, d, CHANNELS)
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(level).lower(
+        S((n, d), np.int8), S((n, CHANNELS), np.float32), S((n,), np.int32),
+        S((n,), np.float32)).compile()
+    _fits(compiled)
+
+
+def test_histogram_segment_sum_at_phase_b_width(scale_features, one_chip):
+    """The scatter formulation, which the rule falls back to past 2 GB: phase
+    B's sweep rows x vector width."""
+    from transmogrifai_tpu.ops import trees as Tr
+
+    d = scale_features["width"]
+    n = chip_smoke.phase_b_sweep_rows(chip_smoke.PHASE_B_ROWS)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda Xb, ghw, slot: Tr._level_histograms(Xb, ghw, slot, SLOTS,
+                                                   N_BINS)).lower(
+        S((n, d), np.int8), S((n, CHANNELS), np.float32),
+        S((n,), np.int32)).compile()
+    _fits(compiled)

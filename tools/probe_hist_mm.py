@@ -9,7 +9,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from bench import init_backend
 
-platform, _fb = init_backend()
+platform = init_backend()["platform"]
 import jax
 import jax.numpy as jnp
 import numpy as np

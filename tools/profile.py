@@ -35,7 +35,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from bench import init_backend
+from transmogrifai_tpu.utils.backend import device_summary
 
 parser = argparse.ArgumentParser(description=__doc__)
 parser.add_argument("cmd", nargs="?", default="trees",
@@ -48,7 +48,7 @@ parser.add_argument("--trace", default="",
                          "JSON here (open in Perfetto)")
 cli = parser.parse_args()
 
-init_backend()
+print("device:", device_summary(), file=sys.stderr)
 import jax
 import jax.numpy as jnp
 

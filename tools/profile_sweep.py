@@ -18,7 +18,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from bench import init_backend, titanic_arrays
+from bench import titanic_arrays
+from transmogrifai_tpu.utils.backend import device_summary
 
 args = argparse.ArgumentParser(description=__doc__)
 args.add_argument("--shards", type=int, default=0,
@@ -36,8 +37,8 @@ args.add_argument("--costmodel", action="store_true",
                        "error (MAPE, makespan ratio) after the run")
 args = args.parse_args()
 
-platform, fb = init_backend()
-print("platform:", platform, fb)
+platform = device_summary()["platform"]
+print("platform:", platform)
 
 from transmogrifai_tpu.evaluators.classification import OpBinaryClassificationEvaluator
 from transmogrifai_tpu.impl.classification.logistic import OpLogisticRegression

@@ -9,32 +9,6 @@ sweep instead of JVM thread pools.
 
 See SURVEY.md at the repo root for the full reference analysis.
 """
-import os as _os
-
-
-def _enable_compile_cache() -> None:
-    """Persistent XLA compilation cache — tree/selector kernels compile once
-    per (shape, static-params) ever, not once per process.  The sweep's wall
-    clock is otherwise dominated by recompiles (deep-tree programs take
-    10-30s to build).  Opt out with TRANSMOG_NO_COMPILE_CACHE=1."""
-    if _os.environ.get("TRANSMOG_NO_COMPILE_CACHE"):
-        return
-    try:
-        import jax
-
-        cache_dir = _os.environ.get(
-            "TRANSMOG_COMPILE_CACHE_DIR",
-            _os.path.join(_os.path.expanduser("~"), ".cache", "transmogrifai_tpu",
-                          "xla"))
-        _os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # cache is best-effort; never block import
-        pass
-
-
-_enable_compile_cache()
-
 from . import types
 from .columns import Column, Dataset, NumericColumn, ObjectColumn, PredictionColumn, VectorColumn
 from .features.builder import FeatureBuilder, from_dataframe

@@ -44,12 +44,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="seconds to serve (default: until Ctrl-C)")
     args = p.parse_args(argv)
 
-    from ..utils.backend import ensure_backend
+    from ..utils.backend import compile_cache_dir, device_summary
 
-    platform, fallback = ensure_backend()
-    if fallback:
-        print(f"transmogrifai-tpu-serve: falling back to {platform} "
-              f"({fallback})", file=sys.stderr)
+    dev = device_summary()
+    print(f"transmogrifai-tpu-serve: platform={dev['platform']} "
+          f"kind={dev['kind']} devices={dev['count']} "
+          f"compile_cache={compile_cache_dir()}", file=sys.stderr)
 
     from ..serve import ModelRegistry, ModelServer
     from ..workflow.model import load_model

@@ -17,9 +17,10 @@ and classifies each row against the device roofline
 * ``launch-bound``  — both roofs are tiny next to the measured wall
   (``max(roof) < TMOG_LAUNCH_BOUND_FRAC x wall``, default 0.1): dispatch /
   host overhead dominates, the regime ROADMAP item 1 predicts for the
-  sweep.  Unknown device kinds (CPU hosts) have no table entry and degrade
-  to this label too — calibrate via ``TMOG_PEAK_FLOPS`` /
-  ``TMOG_PEAK_HBM_GBPS`` to get real classification off-TPU.
+  sweep.  CPU hosts have no table entry and degrade to this label too —
+  calibrate via ``TMOG_PEAK_FLOPS`` / ``TMOG_PEAK_HBM_GBPS`` to get real
+  classification off-TPU.  A TPU kind missing from the table is an error,
+  not this label.
 
 On top of the rows, :func:`ledger_report` factors the headline MFU per
 family as ``mfu_f = compute_fraction_f x achieved_f / peak`` where
@@ -251,7 +252,7 @@ def ledger_report(rows: Optional[Sequence[Dict[str, Any]]] = None,
     if not rows:
         raise ValueError("ledger is empty — nothing to report "
                          "(enable the ledger before the launches run)")
-    peaks = device_peaks(device_kind)
+    peaks = device_peaks(device_kind, platform)
     if peak_flops is not None:
         peaks["peak_flops"] = peak_flops
     if peak_hbm_gbps is not None:

@@ -21,9 +21,11 @@ reason via the central fallback audit trail
 (tmp + rename) so a crashed process cannot poison the directory.
 
 Note this is deliberately NOT jax's own persistent compilation cache
-(``utils/backend.enable_compile_cache`` wires that one for the sweep path
-on TPU): XLA's CPU cache refuses its own entries, while serialized
-executables round-trip on every backend — which is what CI exercises.
+(``utils/backend.compile_cache_dir`` switches that one on, off the CPU):
+XLA's CPU cache refuses its own entries, while serialized executables
+round-trip on every backend — which is what CI exercises.  Keep this
+tier's directory under the same root (``utils/backend.cache_root()`` +
+``/aotx``).
 """
 from __future__ import annotations
 
@@ -124,8 +126,45 @@ def _entry_path(directory: str, name: str, key: str) -> str:
     return os.path.join(directory, f"{safe}-{key}.aotx")
 
 
-def _try_load(path: str) -> Tuple[Optional[Any], Optional[str]]:
-    """Deserialize one entry -> ``(compiled, failure_kind)``.
+def _load_on(device: Any, payload: bytes, in_tree: Any, out_tree: Any) -> Any:
+    """``serialize_executable.deserialize_and_load`` for ONE device, handing
+    the runtime the compile options (device assignment) as jax's own
+    persistent cache does on a read.  jax 0.9.0's loader passes none:
+    without ``execution_devices`` it loads over every local device, and
+    with it libtpu still assigns the executable to chip 0 whatever chip it
+    was compiled for ("Buffer passed to Execute() ... is on device TPU_1,
+    but replica is assigned to device TPU_0" on every replica but the
+    first).  No public entry point takes the options, hence the two private
+    names; entries are keyed by jax version, and any failure here is a
+    recorded compile fallback."""
+    import io
+
+    import jax
+    import numpy as np
+    from jax._src import compiler
+    from jax.experimental import serialize_executable
+
+    options = compiler.get_compile_options(
+        num_replicas=1, num_partitions=1,
+        device_assignment=np.array([[device.id]]), backend=device.client)
+
+    class Unpickler(serialize_executable._JaxPjrtUnpickler):
+        def persistent_load(self, pid):
+            if pid[0] == "exec":
+                return self.backend.deserialize_executable(
+                    pid[1], executable_devices=self.execution_devices,
+                    compile_options=options)
+            return super().persistent_load(pid)
+
+    unloaded, args_info_flat, no_kwargs = Unpickler(
+        io.BytesIO(payload), device.client, [device]).load()
+    return jax.stages.Compiled(unloaded.load(), [],
+                               in_tree.unflatten(args_info_flat), out_tree,
+                               no_kwargs=no_kwargs)
+
+
+def _try_load(path: str, device: Any) -> Tuple[Optional[Any], Optional[str]]:
+    """Deserialize one entry onto ``device`` -> ``(compiled, failure_kind)``.
 
     ``(executable, None)`` on success.  On any defect the fallback is
     recorded and ``compiled`` is None; ``failure_kind`` distinguishes
@@ -133,8 +172,6 @@ def _try_load(path: str) -> Tuple[Optional[Any], Optional[str]]:
     is bad) from ``"backend"`` (a VALID entry whose payload this backend
     refuses to deserialize — XLA:CPU round-trip gaps), which decides
     whether the in-process memo may stand in."""
-    from jax.experimental import serialize_executable
-
     t0 = time.perf_counter()
     entry = None
     try:
@@ -146,8 +183,7 @@ def _try_load(path: str) -> Tuple[Optional[Any], Optional[str]]:
             entry = None
             raise ValueError(f"entry version mismatch")
         _, payload, in_tree, out_tree = entry
-        compiled = serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree)
+        compiled = _load_on(device, payload, in_tree, out_tree)
     except Exception as e:  # noqa: BLE001 — corrupt entry -> compile fallback
         kind = "backend" if entry is not None else "corrupt"
         _record_fallback("corrupt_cache_entry" if kind == "corrupt"
@@ -212,7 +248,7 @@ def load_or_compile(name: str, lowered: Any, device: Any,
         if os.path.exists(path):
             with trace.span("compile_cache.load", program=name,
                             device=str(device)):
-                compiled, fail_kind = _try_load(path)
+                compiled, fail_kind = _try_load(path, device)
             if compiled is not None:
                 _mem_put(mkey, compiled)
                 _scope.inc("hits")

@@ -280,12 +280,9 @@ def _cost(fn, args, kwargs) -> Optional[Dict[str, Any]]:
         with trace_collectives() as colls:
             lowered = fn.lower(*args, **kwargs)
         compiled = lowered.compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0] if ca else {}
+        ca = compiled.cost_analysis() or {}
         return {"flops": float(ca.get("flops", 0.0)),
-                "bytes_accessed": float(ca.get("bytes accessed",
-                                               ca.get("bytes_accessed", 0.0))),
+                "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
                 "events": tuple(c for c in colls
                                 if c[0] in ("hist_subtracted", "gbt_chain",
                                             "bf16_hist"))}
@@ -366,12 +363,9 @@ def record_compiled(name: str, compiled, args: Tuple, device=None
     if not _enabled:
         return None
     try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0] if ca else {}
+        ca = compiled.cost_analysis() or {}
         cost = {"flops": float(ca.get("flops", 0.0)),
-                "bytes_accessed": float(ca.get("bytes accessed",
-                                               ca.get("bytes_accessed", 0.0)))}
+                "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
     except Exception:
         return None
     _accumulate(name, cost, _shape_key(args, {}),
