@@ -98,6 +98,34 @@ def test_env_cache_dir_is_left_to_jax(tmp_path):
     assert any(d.iterdir()), "no cache entry landed in the env directory"
 
 
+def test_named_scope_is_part_of_the_cache_key(tmp_path):
+    """Two programs that differ by a ``jax.named_scope`` alone (metadata: the
+    names a profiler trace shows) get an entry each once
+    ``compile_cache_dir()`` has run, so a directory filled before the scopes
+    existed cannot serve executables without them."""
+    d = tmp_path / "jaxcache"
+    r = _py("import os, jax, jax.numpy as jnp\n"
+            "from transmogrifai_tpu.utils import backend\n"
+            "backend.compile_cache_dir()\n"
+            "n = lambda: len([f for f in os.listdir(os.environ"
+            "['JAX_COMPILATION_CACHE_DIR']) if f.endswith('-cache')])\n"
+            "x = jnp.ones((32, 32)).block_until_ready()\n"
+            "def plain(a):\n"
+            "    return jnp.sin(a) @ a\n"
+            "def scoped(a):\n"
+            "    with jax.named_scope('metrics.rank'):\n"
+            "        return jnp.sin(a) @ a\n"
+            "scoped.__name__ = 'plain'\n"
+            "n0 = n()\n"
+            "jax.jit(plain)(x).block_until_ready()\n"
+            "n1 = n()\n"
+            "jax.jit(scoped)(x).block_until_ready()\n"
+            "print('ADDED', n1 - n0, n() - n1)",
+            JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(d))
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "ADDED 1 1" in r.stdout, r.stdout
+
+
 def test_rowsharded_compiles_skip_the_persistent_cache(tmp_path):
     """``ops/sweep._without_persistent_cache``: nothing compiled inside the
     block is written to (or read from) jax's persistent cache, nesting

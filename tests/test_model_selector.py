@@ -156,3 +156,146 @@ def test_splitter_stratified_holdout():
     assert len(ho) == 25
     assert (y[ho] == 1).sum() == 5
     assert len(np.intersect1d(tr, ho)) == 0
+
+
+# ---- the fit's spans and counts (obs/trace, utils/devcache) ------------------
+#: every span one fused selector fit opens, once each (README "Observability")
+FIT_SPANS = ("selector.fit", "selector.split", "selector.prepare",
+             "selector.gather", "selector.validate", "sweep.plan",
+             "sweep.launch", "sweep.dispatch", "sweep.gather",
+             "selector.refit", "selector.evaluate")
+
+
+def _traced_selector(mesh=None):
+    """``mesh=None``: the one-device path, as on one chip (the tests' eight
+    virtual devices would otherwise shard the two candidates)."""
+    X, y = _binary_data(n=240)
+    label, vec, ds = _selector_inputs(X, y)
+    sel = ModelSelector(
+        validator=OpCrossValidation(OpBinaryClassificationEvaluator(),
+                                    num_folds=3, stratify=True, mesh=mesh),
+        splitter=DataBalancer(sample_fraction=0.1, reserve_test_fraction=0.1),
+        models=[(OpLogisticRegression(max_iter=10),
+                 [{"reg_param": r, "elastic_net_param": 0.0}
+                  for r in (0.01, 0.1)])],
+    ).set_input(label, vec)
+    return sel, ds
+
+
+@pytest.fixture
+def tracer():
+    from transmogrifai_tpu.obs import trace
+
+    trace.disable()
+    trace.reset()
+    trace.enable(path=None)
+    yield trace
+    trace.disable()
+    trace.reset()
+
+
+def test_fit_opens_each_span_once_under_one_request(tracer):
+    sel, ds = _traced_selector()
+    sel.fit(ds)
+    evs = [e for e in tracer.events() if e["ph"] == "X"]
+    by_name = {}
+    for e in evs:
+        by_name.setdefault(e["name"], []).append(e)
+    for name in FIT_SPANS:
+        assert len(by_name.get(name, [])) == 1, (name, sorted(by_name))
+    # X and y go up once each, under the plan
+    uploads = by_name["devcache.upload"]
+    assert len(uploads) == 2
+    plan_id = by_name["sweep.plan"][0]["args"]["id"]
+    assert all(u["args"]["parent"] == plan_id for u in uploads)
+    # one tree: every span reaches selector.fit through its parents ...
+    root = by_name["selector.fit"][0]["args"]
+    parent_of = {e["args"]["id"]: e["args"]["parent"] for e in evs}
+    for e in evs:
+        at = e["args"]["id"]
+        while parent_of.get(at) is not None:
+            at = parent_of[at]
+        assert at == root["id"], e["name"]
+    # ... the dispatch too, though it runs on a hedge thread
+    launch = by_name["sweep.launch"][0]
+    assert by_name["sweep.dispatch"][0]["args"]["parent"] == launch["args"]["id"]
+    # ... and one request
+    assert root["req"] is not None
+    assert {e["args"]["req"] for e in evs} == {root["req"]}
+    # what each span says of its work
+    X = ds["features"].values
+    gathered = by_name["selector.gather"][0]["args"]
+    kept = by_name["selector.prepare"][0]["args"]["kept_rows"]
+    assert gathered["bytes"] == kept * X.shape[1] * X.itemsize
+    assert gathered["handoff"] is False
+    assert root["rows"] == len(X) and root["width"] == X.shape[1]
+    assert root["candidates"] == 2 and root["folds"] == 3
+    split = by_name["selector.split"][0]["args"]
+    assert split["train_rows"] + split["holdout_rows"] == len(X)
+    assert by_name["selector.evaluate"][0]["args"]["holdout_rows"] \
+        == split["holdout_rows"]
+    assert by_name["selector.refit"][0]["args"]["family"] == "OpLogisticRegression"
+    assert by_name["sweep.gather"][0]["args"]["d2h_bytes"] == 3 * 2 * 6 * 4
+    assert by_name["sweep.plan"][0]["args"]["candidates"] == 2
+
+
+def test_second_fit_gets_another_request(tracer):
+    sel, ds = _traced_selector()
+    sel.fit(ds)
+    sel.fit(ds)
+    fits = [e["args"] for e in tracer.events() if e["name"] == "selector.fit"]
+    assert len(fits) == 2
+    assert fits[0]["req"] != fits[1]["req"]
+    assert None not in (fits[0]["req"], fits[1]["req"])
+
+
+def test_workflow_train_request_is_inherited_by_the_fit(tracer):
+    sel, ds = _traced_selector()
+    with tracer.request():
+        with tracer.span("outer"):
+            sel.fit(ds)
+    evs = {e["name"]: e["args"] for e in tracer.events() if e["ph"] == "X"}
+    assert evs["selector.fit"]["req"] == evs["outer"]["req"]
+    assert evs["selector.fit"]["parent"] == evs["outer"]["id"]
+
+
+def test_devcache_counts_uploads_and_hits():
+    from transmogrifai_tpu import obs
+    from transmogrifai_tpu.utils import devcache
+
+    X = np.arange(60, dtype=np.float64).reshape(20, 3)
+    before = obs.snapshot()["devcache"]
+    devcache.device_array(X, np.float32)
+    first = obs.snapshot()["devcache"]
+    # counted at the target dtype: 20 x 3 float32
+    assert first["h2d_bytes"] - before["h2d_bytes"] == 20 * 3 * 4
+    assert first["h2d_uploads"] - before["h2d_uploads"] == 1
+    assert first["cache_hits"] == before["cache_hits"]
+    devcache.device_array(X, np.float32)
+    hit = obs.snapshot()["devcache"]
+    assert hit["h2d_bytes"] == first["h2d_bytes"]
+    assert hit["h2d_uploads"] == first["h2d_uploads"]
+    assert hit["cache_hits"] - first["cache_hits"] == 1
+
+
+def test_sharded_fit_links_each_shard_to_its_launch(tracer):
+    # the default mesh partitions the candidates over the virtual devices:
+    # every sweep.shard (a pool or hedge thread) has the sweep.launch as its
+    # parent and the fit's request, and its gather is under the shard
+    sel, ds = _traced_selector(mesh="auto")
+    sel.fit(ds)
+    evs = [e for e in tracer.events() if e["ph"] == "X"]
+    launch = [e for e in evs if e["name"] == "sweep.launch"]
+    shards = [e for e in evs if e["name"] == "sweep.shard"]
+    if not shards:
+        pytest.skip("one device: nothing to shard")
+    assert len(launch) == 1 and len(shards) >= 2
+    req = next(e for e in evs if e["name"] == "selector.fit")["args"]["req"]
+    for sh in shards:
+        assert sh["args"]["parent"] == launch[0]["args"]["id"]
+        assert sh["args"]["req"] == req
+        assert sh["tid"] != launch[0]["tid"]
+    shard_ids = {sh["args"]["id"] for sh in shards}
+    gathers = [e for e in evs if e["name"] == "sweep.gather"]
+    assert len(gathers) == len(shards)
+    assert {g["args"]["parent"] for g in gathers} == shard_ids

@@ -20,6 +20,7 @@ import numpy as np
 from ... import types as T
 from ...columns import Column, Dataset, NumericColumn, VectorColumn
 from ...evaluators.base import OpEvaluatorBase
+from ...obs import trace
 from ...stages.base import AllowLabelAsInput, BinaryEstimator
 from ..tuning.splitters import Splitter, SplitterSummary
 from ..tuning.validators import OpValidator, ValidationSummary
@@ -316,93 +317,119 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
     def fit_columns(self, cols: Sequence[Column], dataset: Dataset) -> "SelectedModel":
         label_col, vec_col = cols
         assert isinstance(label_col, NumericColumn) and isinstance(vec_col, VectorColumn)
+        # one request id for the whole fit (inherited from OpWorkflow.train
+        # when that is the caller); every phase below is a span under this one
+        with trace.request(), trace.span(
+                "selector.fit", rows=len(label_col.values),
+                width=int(vec_col.values.shape[1]),
+                candidates=sum(len(g) for _, g in self.models),
+                folds=getattr(self.validator, "num_folds", 1)):
+            return self._fit_columns(label_col, vec_col)
+
+    def _fit_columns(self, label_col: NumericColumn,
+                     vec_col: VectorColumn) -> "SelectedModel":
         keep = label_col.mask
         # avoid a full-matrix copy when no labels are missing (10M x p data)
         X = vec_col.values if keep.all() else vec_col.values[keep]
         y = label_col.values[keep].astype(np.float32)
         n = len(y)
+        row_bytes = int(X.shape[1]) * X.itemsize
 
         # 1. holdout reservation (splitter.split, Splitter.scala:58)
-        if self.splitter is not None and self.splitter.reserve_test_fraction > 0.0:
-            train_idx, hold_idx = self.splitter.split(n, y)
-        else:
-            train_idx, hold_idx = np.arange(n), np.array([], dtype=np.int64)
-        ytr = y[train_idx]
+        with trace.span("selector.split", rows=n) as sp:
+            if self.splitter is not None and self.splitter.reserve_test_fraction > 0.0:
+                train_idx, hold_idx = self.splitter.split(n, y)
+            else:
+                train_idx, hold_idx = np.arange(n), np.array([], dtype=np.int64)
+            ytr = y[train_idx]
+            sp.set(train_rows=len(train_idx), holdout_rows=len(hold_idx))
 
-        # 2. preValidationPrepare (DataBalancer.estimate etc.)
-        prep_summary: Optional[SplitterSummary] = None
-        prep_w = None
-        if self.splitter is not None:
-            prep_summary = self.splitter.pre_validation_prepare(ytr)
-            prep_w = self.splitter.prepare_weights(ytr)
-
-        # 2b. maxTrainingSample cap BEFORE materializing the sweep matrix
-        # (reference splitters downsample in preValidationPrepare /
-        # validationPrepare — DataSplitter.scala:65, DataBalancer.scala:84).
-        # Rows are drawn UNIFORMLY without replacement and the preparation
-        # weights are kept on the survivors, so the sweep still trains on the
-        # splitter's balanced distribution (a weighted without-replacement
-        # draw cannot upsample the minority and flattens the weights as the
-        # pool shrinks — it would neither match the balancer nor the raw
-        # distribution).
         cap = getattr(self.splitter, "max_training_sample", None) \
             if self.splitter is not None else None
-        if cap and len(train_idx) > cap:
-            rng = np.random.default_rng(self.validator.seed)
-            sub = np.sort(rng.choice(len(train_idx), size=int(cap),
-                                     replace=False))
-            train_idx = train_idx[sub]
-            ytr = y[train_idx]
-            if prep_w is not None:
-                prep_w = prep_w[sub]
-        Xtr = X[train_idx]
-        # device-side handoff: when the streaming transform executor produced
-        # this feature matrix, its chunks are still device-resident — gather
-        # the training rows ON DEVICE and seed the sweep's devcache under
-        # Xtr's identity, so the fused sweep finds a resident buffer instead
-        # of re-uploading the host matrix (workflow/stream.handoff_rows)
+        with trace.span("selector.prepare", cap=cap or 0) as sp:
+            # 2. preValidationPrepare (DataBalancer.estimate etc.)
+            prep_summary: Optional[SplitterSummary] = None
+            prep_w = None
+            if self.splitter is not None:
+                prep_summary = self.splitter.pre_validation_prepare(ytr)
+                prep_w = self.splitter.prepare_weights(ytr)
+
+            # 2b. maxTrainingSample cap BEFORE materializing the sweep matrix
+            # (reference splitters downsample in preValidationPrepare /
+            # validationPrepare — DataSplitter.scala:65, DataBalancer.scala:84).
+            # Rows are drawn UNIFORMLY without replacement and the preparation
+            # weights are kept on the survivors, so the sweep still trains on
+            # the splitter's balanced distribution (a weighted
+            # without-replacement draw cannot upsample the minority and
+            # flattens the weights as the pool shrinks — it would neither
+            # match the balancer nor the raw distribution).
+            if cap and len(train_idx) > cap:
+                rng = np.random.default_rng(self.validator.seed)
+                sub = np.sort(rng.choice(len(train_idx), size=int(cap),
+                                         replace=False))
+                train_idx = train_idx[sub]
+                ytr = y[train_idx]
+                if prep_w is not None:
+                    prep_w = prep_w[sub]
+            sp.set(kept_rows=len(train_idx))
+
         from ...workflow import stream as _stream
 
-        _stream.handoff_rows(
-            vec_col.values, Xtr,
-            train_idx if keep.all() else np.flatnonzero(keep)[train_idx])
+        with trace.span("selector.gather", bytes=len(train_idx) * row_bytes,
+                        handoff=_stream.device_view(vec_col.values) is not None):
+            Xtr = X[train_idx]
+            # device-side handoff: when the streaming transform executor
+            # produced this feature matrix, its chunks are still
+            # device-resident — gather the training rows ON DEVICE and seed
+            # the sweep's devcache under Xtr's identity, so the fused sweep
+            # finds a resident buffer instead of re-uploading the host matrix
+            # (workflow/stream.handoff_rows)
+            _stream.handoff_rows(
+                vec_col.values, Xtr,
+                train_idx if keep.all() else np.flatnonzero(keep)[train_idx])
 
         # 3. the sweep (skipped when workflow-level CV already chose a winner)
         if self.best_estimator is not None:
             best_est, best_grid, vsummary = self.best_estimator
         else:
-            best_est, best_grid, vsummary = self.find_best_estimator(Xtr, ytr, prep_w)
+            with trace.span("selector.validate", strategy=self.search_strategy):
+                best_est, best_grid, vsummary = self.find_best_estimator(
+                    Xtr, ytr, prep_w)
         self.validation_summary = vsummary
 
         # 4. final refit on the full prepared train (validationPrepare ->
         #    bestEstimator.fit, ModelSelector.scala:181)
-        refit = best_est.copy_with_params(best_grid)
-        if self.splitter is not None:
-            ridx = self.splitter.prepare_indices(ytr)
-        else:
-            ridx = np.arange(len(ytr))
-        params = refit.fit_arrays(Xtr[ridx], ytr[ridx])
+        with trace.span("selector.refit", family=type(best_est).__name__) as sp:
+            refit = best_est.copy_with_params(best_grid)
+            if self.splitter is not None:
+                ridx = self.splitter.prepare_indices(ytr)
+            else:
+                ridx = np.arange(len(ytr))
+            sp.set(rows=len(ridx), bytes=len(ridx) * row_bytes)
+            params = refit.fit_arrays(Xtr[ridx], ytr[ridx])
 
         # 5. evaluate train + holdout with every evaluator; train metrics are
         #    computed on the PREPARED training data (the reference evaluates
         #    after validationPrepare — e.g. DataCutter-dropped labels are not
         #    counted as guaranteed errors, ModelSelector.scala:181-187)
         evaluators = self.evaluators or [self.validator.evaluator]
-        pred_tr, raw_tr, prob_tr = refit.predict_arrays(params, Xtr[ridx])
-        train_eval: Dict[str, Any] = {}
-        for ev in evaluators:
-            train_eval.update(ev.evaluate_arrays(ytr[ridx], np.asarray(pred_tr),
-                                                 None if prob_tr is None
-                                                 else np.asarray(prob_tr)))
-        holdout_eval = None
-        if len(hold_idx):
-            Xho, yho = X[hold_idx], y[hold_idx]
-            pred_ho, _, prob_ho = refit.predict_arrays(params, Xho)
-            holdout_eval = {}
+        with trace.span("selector.evaluate", holdout_rows=len(hold_idx),
+                        bytes=(len(ridx) + len(hold_idx)) * row_bytes):
+            pred_tr, raw_tr, prob_tr = refit.predict_arrays(params, Xtr[ridx])
+            train_eval: Dict[str, Any] = {}
             for ev in evaluators:
-                holdout_eval.update(ev.evaluate_arrays(yho, np.asarray(pred_ho),
-                                                       None if prob_ho is None
-                                                       else np.asarray(prob_ho)))
+                train_eval.update(ev.evaluate_arrays(
+                    ytr[ridx], np.asarray(pred_tr),
+                    None if prob_tr is None else np.asarray(prob_tr)))
+            holdout_eval = None
+            if len(hold_idx):
+                Xho, yho = X[hold_idx], y[hold_idx]
+                pred_ho, _, prob_ho = refit.predict_arrays(params, Xho)
+                holdout_eval = {}
+                for ev in evaluators:
+                    holdout_eval.update(ev.evaluate_arrays(
+                        yho, np.asarray(pred_ho),
+                        None if prob_ho is None else np.asarray(prob_ho)))
 
         summary = ModelSelectorSummary(
             validation_type=vsummary.validation_type,

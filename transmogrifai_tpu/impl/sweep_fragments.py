@@ -37,6 +37,7 @@ import numpy as np
 from ..ops import trees as Tr
 from ..ops.metrics import (BINARY_METRICS, MULTICLASS_METRICS,
                            REGRESSION_METRICS)
+from ..obs import trace
 from ..utils import devcache
 from .trees_common import (DEFAULT_MAX_FRONTIER, DEFAULT_MAX_FRONTIER_BOOSTED,
                            _DYNAMIC_BOOST_KEYS, _FOREST_GRID_KEYS,
@@ -99,13 +100,16 @@ class SweepPlan:
         return spec_units(self.spec, self.n_rows, self.n_features, n_folds)
 
     def run(self, train_w: np.ndarray, val_mask: np.ndarray) -> np.ndarray:
-        """Execute; returns host metrics [F, C, M] (ONE device pull)."""
+        """Execute; returns host metrics [F, C, M].  The launch is
+        asynchronous: the one device pull, under ``sweep.gather``, is where
+        the host waits for the device to finish."""
         from ..ops.sweep import run_sweep
 
         out = run_sweep(self.spec, self.X, self.xbs, self.y,
                         np.asarray(train_w, np.float32),
                         np.asarray(val_mask, np.float32), self.blob)
-        return np.asarray(out)
+        with trace.span("sweep.gather", d2h_bytes=int(out.nbytes)):
+            return np.asarray(out)
 
     def run_sharded(self, train_w: np.ndarray, val_mask: np.ndarray,
                     devices) -> np.ndarray:

@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ...evaluators.base import OpEvaluatorBase
+from ...obs import trace
 
 log = logging.getLogger(__name__)
 
@@ -289,7 +290,10 @@ class OpValidator:
             X = Xc
             plans = []
             for chunk in chunks:
-                plan = build_sweep_plan(chunk, X, y, train_w, self.evaluator)
+                with trace.span("sweep.plan", rows=len(y), candidates=sum(
+                        len(list(g) or [{}]) for _, g in chunk)):
+                    plan = build_sweep_plan(chunk, X, y, train_w,
+                                            self.evaluator)
                 if plan is None:
                     if n_data > 1:
                         # a custom estimator (or unsupported grid) blocks

@@ -66,6 +66,11 @@ _EXACT = {
     "sweep.account": "host_prep",
     "sweep.checkpoint": "host_prep",
     "stream.chunk.upload": "host_prep",
+    "selector.split": "host_prep",
+    "selector.prepare": "host_prep",
+    "selector.gather": "host_prep",
+    "sweep.plan": "host_prep",
+    "devcache.upload": "host_prep",
     "sweep.compile": "compile",
     "serve.rebuild": "compile",
     "sweep.dispatch": "dispatch",
@@ -81,6 +86,7 @@ _EXACT = {
 #: uncovered interior is exactly the "uninstrumented glue" idle measures.
 _STRUCTURAL = frozenset({
     "sweep.launch", "sweep.shard", "stream.execute",
+    "selector.fit", "selector.validate", "stage.fit", "stage.transform",
     "profile.window", "bench.window",
 })
 

@@ -330,25 +330,33 @@ def _frag_scores(frag, X, xbs, y, train_w, blob, problem, rs=None):
     kind = frag[0]
     multiclass = isinstance(problem, tuple)
     classification = problem == "binary" or multiclass
+    # each family's device ops carry its scope name in a profiler trace
+    # (metadata only: benchmarks/program_spans.py splits _run_scores by it)
     if kind == "fista":
         if multiclass:
-            return frag[1], _softmax_scores(frag, X, y, train_w, blob,
-                                            problem[1], rs=rs)
-        return frag[1], _fista_scores(frag, X, y, train_w, blob,
-                                      classification, rs=rs)
+            with jax.named_scope("scores.softmax"):
+                return frag[1], _softmax_scores(frag, X, y, train_w, blob,
+                                                problem[1], rs=rs)
+        with jax.named_scope("scores.fista"):
+            return frag[1], _fista_scores(frag, X, y, train_w, blob,
+                                          classification, rs=rs)
     if kind == "newton":
-        return frag[1], _newton_scores(frag, X, y, train_w, blob, rs=rs)
+        with jax.named_scope("scores.newton"):
+            return frag[1], _newton_scores(frag, X, y, train_w, blob, rs=rs)
     if kind == "svc":
-        return frag[1], _svc_scores(frag, X, y, train_w, blob, rs=rs)
+        with jax.named_scope("scores.svc"):
+            return frag[1], _svc_scores(frag, X, y, train_w, blob, rs=rs)
     if kind == "mlp":
-        return frag[1], _mlp_scores(frag, X, y, train_w, blob,
-                                    full_prob=multiclass, rs=rs)
+        with jax.named_scope("scores.mlp"):
+            return frag[1], _mlp_scores(frag, X, y, train_w, blob,
+                                        full_prob=multiclass, rs=rs)
     if kind == "forest":
         _, out_c, groups = frag
         cis_all, outs = [], []
         for grp in groups:
-            dist = _forest_group_scores(grp, xbs, y, train_w, blob, out_c,
-                                        rs=rs)
+            with jax.named_scope("scores.forest"):
+                dist = _forest_group_scores(grp, xbs, y, train_w, blob,
+                                            out_c, rs=rs)
             # binary classification: 1-channel leaves ARE p(class=1);
             # regression: mean leaves are the prediction; multiclass keeps
             # the class-distribution leaves (argmax-equivalent unnormalized);
@@ -363,8 +371,9 @@ def _frag_scores(frag, X, xbs, y, train_w, blob, problem, rs=None):
         _, loss, out_c, groups = frag
         cis_all, outs = [], []
         for grp in groups:
-            Fm = _gbt_group_scores(grp, xbs, y, train_w, blob, loss, out_c,
-                                   rs=rs)
+            with jax.named_scope("scores.gbt"):
+                Fm = _gbt_group_scores(grp, xbs, y, train_w, blob, loss,
+                                       out_c, rs=rs)
             if loss == "softmax":
                 outs.append(jax.nn.softmax(Fm, axis=-1))
             elif loss == "logistic":
@@ -395,16 +404,24 @@ def _all_scores(spec, X, xbs, y, train_w, blob, rs=None):
     return scores
 
 
+def _metrics_scope(problem) -> str:
+    """The profiler-trace scope of the metric pass for this problem type."""
+    if isinstance(problem, tuple):
+        return "metrics.multiclass"
+    return "metrics.binary" if problem == "binary" else "metrics.regression"
+
+
 def _metrics_of(spec, y, scores, val_w):
     problem, _, strict = spec
-    if isinstance(problem, tuple):
-        y1 = jax.nn.one_hot(y.astype(jnp.int32), problem[1],
-                            dtype=jnp.float32)
-        return _multiclass_grid_metrics(y1, scores, val_w)
-    if problem == "binary":
-        return _binary_grid_metrics(y, scores, val_w,
-                                    jnp.asarray(strict, jnp.float32))
-    return _regression_grid_metrics(y, scores, val_w)
+    with jax.named_scope(_metrics_scope(problem)):
+        if isinstance(problem, tuple):
+            y1 = jax.nn.one_hot(y.astype(jnp.int32), problem[1],
+                                dtype=jnp.float32)
+            return _multiclass_grid_metrics(y1, scores, val_w)
+        if problem == "binary":
+            return _binary_grid_metrics(y, scores, val_w,
+                                        jnp.asarray(strict, jnp.float32))
+        return _regression_grid_metrics(y, scores, val_w)
 
 
 @functools.partial(jax.jit, static_argnames=("spec",))
@@ -515,7 +532,8 @@ def _run_rs(spec, mesh, n_orig, X, xbs, y, train_w, val_w, blob):
 
     def local(Xl, xbs_l, yl, twl, vwl, bl):
         scores = _all_scores(spec, Xl, xbs_l, yl, twl, bl, rs=rs)
-        return _metrics_of_rs(spec, yl, scores, vwl, rs)
+        with jax.named_scope(_metrics_scope(spec[0])):
+            return _metrics_of_rs(spec, yl, scores, vwl, rs)
 
     return _shard_map(
         local, mesh=mesh,
@@ -700,7 +718,7 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
                 return _dispatch(ctl)
 
             winners, hstats = _hedge.run_hedged(
-                1, 1, _attempt, [deadline], same_slot=True,
+                1, 1, trace.bind(_attempt), [deadline], same_slot=True,
                 on_hedge=lambda *a: _sweep_scope.inc("hedges_fired"),
                 on_waste=_waste)
             (out, scores, colls, _lwall), _slot, att_no, _awall = winners[0]
@@ -1268,7 +1286,7 @@ def run_sweep_partitioned(shards, X, xbs: Tuple, y, train_w, val_w,
         if not _hedge.enabled():
             # TMOG_HEDGE=0: the original dispatch, bit-identical
             with ThreadPoolExecutor(max_workers=len(shards)) as pool:
-                results = list(pool.map(worker, shards, devices,
+                results = list(pool.map(trace.bind(worker), shards, devices,
                                         range(len(shards))))
             win_devs = list(devices)
         else:
@@ -1331,7 +1349,7 @@ def run_sweep_partitioned(shards, X, xbs: Tuple, y, train_w, val_w,
                               wasted=True)
 
             winners, _hstats = _hedge.run_hedged(
-                len(shards), len(devices), _attempt, deadlines,
+                len(shards), len(devices), trace.bind(_attempt), deadlines,
                 on_hedge=_on_hedge, on_waste=_on_waste,
                 slot_ok=lambda s: tr.usable(devices[s]))
             results, win_devs = [], []
@@ -1650,7 +1668,8 @@ def run_sweep_rowsharded(shards, X, xbs: Tuple, y, train_w, val_w,
         if not _hedge.enabled():
             # TMOG_HEDGE=0: the original dispatch, bit-identical
             with ThreadPoolExecutor(max_workers=len(shards)) as pool:
-                results = list(pool.map(worker, shards, range(len(shards))))
+                results = list(pool.map(trace.bind(worker), shards,
+                                        range(len(shards))))
         else:
             # a column's program only runs on its own submesh, so hedges
             # are SAME-SLOT redundant dispatches (the duplicate re-enters
@@ -1700,7 +1719,7 @@ def run_sweep_rowsharded(shards, X, xbs: Tuple, y, train_w, val_w,
                               wasted=True)
 
             winners, _hstats = _hedge.run_hedged(
-                len(shards), len(shards), _attempt, deadlines,
+                len(shards), len(shards), trace.bind(_attempt), deadlines,
                 same_slot=True, on_hedge=_on_hedge, on_waste=_on_waste)
             results = []
             for res, _slot, att_no, _w in winners:

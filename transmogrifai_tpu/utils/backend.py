@@ -91,6 +91,11 @@ def compile_cache_dir() -> Optional[str]:
     # the sweep and stream programs are many and mostly compile in under
     # jax's 1 s default threshold; together they are the cold start
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # op metadata (the jax.named_scope paths of ops/sweep.py and
+    # ops/metrics.py, which name the device ops in a profiler trace) is not
+    # part of the cache key by default: a directory filled by a checkout
+    # without the scopes would serve executables without their names
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return cache_root()
 
 

@@ -27,6 +27,15 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from ..obs import registry as obs_registry
+from ..obs import trace
+
+#: what crossed host -> device through this cache, for ``obs.snapshot()``
+#: (``["devcache"]``): bytes and count of real uploads, and lookups served
+#: from a resident buffer
+_scope = obs_registry.scope("devcache", defaults={
+    "h2d_bytes": 0, "h2d_uploads": 0, "cache_hits": 0})
+
 
 class DevCacheMutationError(RuntimeError):
     """A host array was mutated in place after its device copy was cached."""
@@ -91,9 +100,17 @@ def device_array(arr, dtype=None, tag: str = "base", device=None):
     import jax.numpy as jnp
 
     def build():
-        a = jnp.asarray(arr) if dtype is None \
-            else jnp.asarray(np.asarray(arr, dtype))
-        return a if device is None else jax.device_put(a, device)
+        # the copy's size is known before it is made: rows x the target
+        # dtype's width
+        nbytes = int(arr.size) * (arr.dtype if dtype is None
+                                  else np.dtype(dtype)).itemsize
+        with trace.span("devcache.upload", bytes=nbytes, tag=tag):
+            a = jnp.asarray(arr) if dtype is None \
+                else jnp.asarray(np.asarray(arr, dtype))
+            a = a if device is None else jax.device_put(a, device)
+        _scope.inc("h2d_bytes", nbytes)
+        _scope.inc("h2d_uploads")
+        return a
 
     if not isinstance(arr, np.ndarray):  # jax array (or scalar): no caching
         a = jnp.asarray(arr) if dtype is None else jnp.asarray(arr, dtype)
@@ -107,6 +124,8 @@ def device_array(arr, dtype=None, tag: str = "base", device=None):
     if dev is None:
         dev = build()
         products[key] = dev
+    else:
+        _scope.inc("cache_hits")
     return dev
 
 

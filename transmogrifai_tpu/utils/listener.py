@@ -24,6 +24,8 @@ import time
 from dataclasses import asdict, dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from ..obs import trace
+
 
 class OpStep(str, enum.Enum):
     """Pipeline phases (OpStep.scala:35-45)."""
@@ -116,17 +118,22 @@ class OpListener:
     # ---- stage reporting ---------------------------------------------------
     @contextlib.contextmanager
     def time_stage(self, stage, phase: str, n_rows: int = 0):
-        start = time.perf_counter()
+        """Time one stage as the span ``stage.<phase>``: the stage's wall in
+        the metrics IS the span's duration (one pair of clock reads), and a
+        profiler capture shows the stage beside the device ops."""
+        name = getattr(stage, "operation_name", str(stage))
         started_at = int(time.time() * 1000)
+        sp = trace.timed("stage." + phase, stage=name, rows=n_rows)
         try:
-            yield
+            with sp:
+                yield
         finally:
             if self.collect_stage_metrics:
                 self.metrics.stage_metrics.append(StageMetrics(
-                    stage_name=getattr(stage, "operation_name", str(stage)),
+                    stage_name=name,
                     stage_uid=getattr(stage, "uid", ""),
                     step=self._step.value, phase=phase, started_at_ms=started_at,
-                    duration_ms=(time.perf_counter() - start) * 1000.0,
+                    duration_ms=sp.seconds * 1000.0,
                     n_rows=n_rows))
 
     # ---- lifecycle ---------------------------------------------------------

@@ -16,6 +16,7 @@ import numpy as np
 from ..columns import Dataset
 from ..features.feature import Feature
 from ..features.generator import FeatureGeneratorStage
+from ..obs import trace
 from ..readers.base import CustomReader, Reader
 from ..stages.base import Estimator, Model, PipelineStage, Transformer
 from . import dag as dag_util
@@ -159,6 +160,11 @@ class OpWorkflow(OpWorkflowCore):
 
     # ---- training (OpWorkflow.scala:347) -----------------------------------
     def train(self, params: Optional[Dict[str, Any]] = None) -> "OpWorkflowModel":
+        # one request id for every span of this train (obs/trace)
+        with trace.request():
+            return self._train(params)
+
+    def _train(self, params: Optional[Dict[str, Any]]) -> "OpWorkflowModel":
         from . import stream
 
         # per-train streaming telemetry window (ops/sweep.reset_run_stats
