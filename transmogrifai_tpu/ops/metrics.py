@@ -10,9 +10,19 @@ the sweep pulls a single [F, C] metrics block to the host.
 
 This removes the per-candidate device->host round trips that dominated the
 sweep's wall-clock (round-4 VERDICT weak #2: ~84 transfers + host sorts per
-Titanic rep): metric evaluation is a [F, C, n] sort + cumsum pipeline, tiny
-next to training, and lets XLA dispatch the training launches of successive
-model families back-to-back with no host sync between them.
+Titanic rep) and lets XLA dispatch the training launches of successive model
+families back-to-back with no host sync between them.
+
+What the pass costs (PERF.md, PR 28; one TPU v5e chip, F=5, C=64,
+n=180,224): 3.62 s of device time in a 7.3 s selector fit, against 0.86 s
+for training all 320 models -- it is NOT small next to training.  The sort's
+permutation gathers (``sv[order]``, ``y[order]``, ``vm[order]``) are 2.20 s
+of it and the AuPR curve's ``rec_c[prev]`` gather 1.29 s; the cumulative
+scans (``cumsum`` / ``cummax`` / ``cummin`` lower to ``reduce-window`` on a
+TPU) are milliseconds.  Until PR 28 AuROC's midranks came from two
+``searchsorted(ss, ss)`` calls, ~18 rounds of data-dependent gathers each:
+46.2 s of a 53.5 s fit.  A gather at computed indices is the slowest memory
+access this chip has; tests/test_device_metrics.py keeps the search loop out.
 
 Semantics notes (validated against the host evaluators in
 tests/test_device_metrics.py):
@@ -54,6 +64,25 @@ REGRESSION_METRICS = ("RootMeanSquaredError", "MeanSquaredError", "R2",
 MULTICLASS_METRICS = ("F1", "Precision", "Recall", "Error")
 
 
+def _tie_bounds(ss):
+    """Tie-group bounds of an ascending-sorted f32[n]: ``lo[i]`` the index of
+    the first element equal to ``ss[i]`` and ``hi[i]`` one past the last, both
+    i32[n] -- the integers ``searchsorted(ss, ss, "left" / "right")`` returns,
+    read off neighbouring elements with one compare and two scans instead of
+    a log-n search that gathers at data-dependent indices every round.  NaNs
+    (sorted last) form one group, as they do under ``searchsorted``."""
+    n = ss.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    edge = jnp.ones((1,), bool)
+    # NaN != NaN, but whatever follows a NaN in sorted order is a NaN too
+    differs = (ss[1:] != ss[:-1]) & ~jnp.isnan(ss[:-1])
+    first = jnp.concatenate([edge, differs])   # i opens its tie group
+    last = jnp.concatenate([differs, edge])    # i closes its tie group
+    lo = jax.lax.cummax(jnp.where(first, idx, 0))
+    hi = jax.lax.cummin(jnp.where(last, idx + 1, n), reverse=True)
+    return lo, hi
+
+
 def _binary_one(y, s, vm, strict):
     """Metrics for ONE (fold, candidate): y f32[n] in {0,1}, s f32[n] class-1
     score, vm f32[n] validation weights, strict f32 scalar."""
@@ -67,7 +96,7 @@ def _binary_one(y, s, vm, strict):
     n_exc = (1.0 - vm).sum()
 
     # the three scopes name this function's device ops in a profiler trace
-    # (metadata only): the sort, the rank pair, the curve sums
+    # (metadata only): the sort, the tie-group scans, the curve sums
     with jax.named_scope("metrics.sort"):
         order = jnp.argsort(sv)  # ascending; excluded (-inf) first
         ss = sv[order]
@@ -76,9 +105,9 @@ def _binary_one(y, s, vm, strict):
 
     # ---- AuROC: rank statistic with midrank ties --------------------------
     with jax.named_scope("metrics.rank"):
-        lo = jnp.searchsorted(ss, ss, side="left").astype(jnp.float32)
-        hi = jnp.searchsorted(ss, ss, side="right").astype(jnp.float32)
-        midrank = (lo + hi + 1.0) * 0.5      # 1-based rank in the full array
+        lo, hi = _tie_bounds(ss)
+        midrank = (lo.astype(jnp.float32) + hi.astype(jnp.float32)
+                   + 1.0) * 0.5              # 1-based rank in the full array
     with jax.named_scope("metrics.curve"):
         rank_val = midrank - n_exc           # rank among validation rows
         r_pos = (vs * ys * rank_val).sum()
