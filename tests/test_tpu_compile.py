@@ -275,36 +275,40 @@ def test_streamed_chunk_program(scale_features, one_chip):
 N_BINS, SLOTS, CHANNELS = 32, 64, 2  # 32 = the MLlib maxBins default
 
 
-def test_histogram_matmul_at_the_2gb_rule(scale_features, one_chip,
-                                          monkeypatch):
-    """The one-hot matmul at the largest n the 2 GB rule admits for phase
-    B's width (the rule counts the shared [n, c1*d*B] one-hot)."""
+def test_histogram_blocked_at_the_trees_cell_rows(scale_features, one_chip):
+    """The row-blocked one-hot GEMM (the one path a TPU takes, for every n) at
+    the ``scale-500-trees`` cell's 32,768 sweep rows and phase B's width:
+    three levels of a 17-tree chunk, several row blocks a level."""
     import jax.numpy as jnp
 
     from transmogrifai_tpu.ops import trees as Tr
 
     d = scale_features["width"]
-    n = int(2e9 // (d * N_BINS * CHANNELS * 4))
-    monkeypatch.delenv("TMOG_HIST_MATMUL")  # the rule itself, as a TPU reads it
-    monkeypatch.setattr(Tr.jax, "default_backend", lambda: "tpu")
-    assert Tr._hist_via_matmul(n, d, N_BINS, CHANNELS)
-    assert not Tr._hist_via_matmul(n + 1, d, N_BINS, CHANNELS)
+    n, T = 32768, 17
+    assert Tr._hist_via_matmul()
+    assert Tr.hist_blocks(n, T * 2, CHANNELS * d * N_BINS)[0] > 1
 
-    def level(Xb, gh, slot, w):
-        Og = Tr.grad_onehot(Xb, gh, N_BINS)
-        S = jax.nn.one_hot(slot, SLOTS, dtype=jnp.float32)
-        return Tr._level_histograms_mm(Og, S, w, SLOTS, N_BINS, d, CHANNELS)
+    def grow(Xb, y, w, fm):
+        ones = jnp.ones((T,), jnp.float32)
+        tree, node = Tr.grow_forest(
+            Xb, -y[:, None], jnp.ones_like(y), w, fm, 3, N_BINS, 256,
+            reg_lambda_t=1e-6 * ones, gamma_t=0 * ones, mcw_t=10 * ones,
+            mig_t=0 * ones, return_row_node=True)
+        return tree.leaf_val, node
 
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    compiled = jax.jit(level).lower(
-        S((n, d), np.int8), S((n, CHANNELS), np.float32), S((n,), np.int32),
-        S((n,), np.float32)).compile()
+    compiled = jax.jit(grow).lower(
+        S((n, d), np.int8), S((n,), np.float32), S((T, n), np.float32),
+        S((T, d), np.float32)).compile()
     _fits(compiled)
+    text = compiled.as_text()
+    assert "trees.hist" in text and "trees.route" in text
+    assert "segment" not in text  # histograms ride dot ops
 
 
 def test_histogram_segment_sum_at_phase_b_width(scale_features, one_chip):
-    """The scatter formulation, which the rule falls back to past 2 GB: phase
-    B's sweep rows x vector width."""
+    """The scatter formulation (what a CPU runs, and ``TMOG_HIST_MATMUL=0``
+    forces): phase B's sweep rows x vector width."""
     from transmogrifai_tpu.ops import trees as Tr
 
     d = scale_features["width"]

@@ -238,6 +238,8 @@ class SweepPlan:
 # so the boosting constant reflects that (the bench's accounting does too).
 # The boosting ROUNDS CHAIN is sequential wall-clock that no partition can
 # shrink — documented as a ROADMAP leftover, not modeled here.
+# Unchecked at scale: no constant was fitted again at the 32,768 x 760 rows
+# of the ``scale-500-trees`` cell (PERF.md, PR 29).
 # ---------------------------------------------------------------------------
 #: linear-family per-iteration constant: cost = F * iters * LIN_ITER_D2 * d^2
 #: (FISTA precomputes the fold Gram; per-iter work is O(d^2) per candidate)
@@ -476,9 +478,14 @@ def _poisson_bound(fold_sum: float, rate: float, max_w: float) -> float:
 
 def _xb_index(xbs: List, X: np.ndarray, n_bins: int) -> int:
     """Pre-binned matrix index for ``n_bins`` (cached per X identity)."""
-    dev = devcache.derived(
-        X, ("xb", n_bins),
-        lambda: devcache.device_array(Tr.quantize(X, n_bins)[0], tag=f"xb{n_bins}"))
+    def binned():
+        # host quantile sketch + device binning + the pull and upload back
+        with trace.span("sweep.quantize", rows=int(X.shape[0]),
+                        width=int(X.shape[1]), bins=int(n_bins)):
+            return devcache.device_array(Tr.quantize(X, n_bins)[0],
+                                         tag=f"xb{n_bins}")
+
+    dev = devcache.derived(X, ("xb", n_bins), binned)
     for i, a in enumerate(xbs):
         if a is dev:
             return i

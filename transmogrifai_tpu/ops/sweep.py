@@ -305,23 +305,12 @@ def _gbt_group_scores(group, xbs, y, train_w, blob, loss: str, out_c: int,
     mig_b = jnp.tile(mig, F)
     base_b = jnp.repeat(base_f, Gc)
 
-    if trees_per_round > 1:
-        # round-collapsed: one K-wide forest step per rounds/K scan steps
-        Fm = Tr._gbt_batch_impl(Xb, y, w_b, rw, fms, loss, rounds, depth,
-                                n_bins, frontier, eta_b, lam_b, gam_b, mcw_b,
-                                base_score_b=base_b, n_classes=out_c,
-                                min_info_gain_b=mig_b, exact_cap=exact_cap,
-                                axis_name=ax, trees_per_round=trees_per_round)
-        return Fm.reshape(F, Gc, n, -1)
-
-    def one(w, e, l, ga, mc, ba, mi):
-        _, Fm = Tr._gbt_impl(Xb, y, w, rw, fms, loss, rounds, depth, n_bins,
-                             frontier, e, l, ga, mc, ba, out_c,
-                             min_info_gain=mi, exact_cap=exact_cap,
-                             axis_name=ax)
-        return Fm
-
-    Fm = jax.vmap(one)(w_b, eta_b, lam_b, gam_b, mcw_b, base_b, mig_b)
+    # one batch-native scan for every K (K = 1: the exact per-round scan)
+    Fm = Tr._gbt_batch_impl(Xb, y, w_b, rw, fms, loss, rounds, depth,
+                            n_bins, frontier, eta_b, lam_b, gam_b, mcw_b,
+                            base_score_b=base_b, n_classes=out_c,
+                            min_info_gain_b=mig_b, exact_cap=exact_cap,
+                            axis_name=ax, trees_per_round=trees_per_round)
     return Fm.reshape(F, Gc, n, -1)
 
 
@@ -662,6 +651,8 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
     if chain:
         entry["gbt_chain"] = chain
     _sweep_scope.append("launches", entry)
+    for name, levels in _spec_tree_levels(spec, F).items():
+        _sweep_scope.inc(name, levels)
     with trace.span("sweep.launch", shards=1, candidates=C,
                     split=bool(split)):
         if chain:
@@ -777,7 +768,8 @@ _sweep_scope = obs_registry.scope("sweep", defaults={
     "launches": [], "fallbacks": [], "compiles": 0, "compile_s": 0.0,
     "pruned_candidates": 0, "full_candidates": 0, "checkpoint_skips": 0,
     "hedges_fired": 0, "hedge_wasted_s": 0.0, "asha_rungs": [],
-    "sweep_pack_count": 0, "launches_avoided": 0})
+    "sweep_pack_count": 0, "launches_avoided": 0,
+    "tree_level_builds": 0, "tree_beam_levels": 0})
 obs_registry.register_provider("sweep", lambda: run_stats())
 
 #: per-(name, spec, device, arg-signature) AOT executables.  jit's own cache
@@ -838,6 +830,10 @@ def run_stats() -> Dict[str, Any]:
             # since reset, and sequential dispatches avoided vs the
             # one-launch-per-candidate baseline (record_packs + the
             # row-sharded metric map)
+            # tree levels grown by the single-device launches since reset,
+            # and those that ranked a full frontier (_spec_tree_levels)
+            "tree_level_builds": _sweep_scope.get("tree_level_builds"),
+            "tree_beam_levels": _sweep_scope.get("tree_beam_levels"),
             "sweep_pack_count": _sweep_scope.get("sweep_pack_count"),
             "launches_avoided": _sweep_scope.get("launches_avoided"),
             # sequential non-overlapped GBT launch-levels on the critical
@@ -929,6 +925,26 @@ def _spec_gbt_chain(spec) -> Optional[Dict[str, int]]:
     if steps == 0:
         return None
     return {"steps": steps, "levels": levels}
+
+
+def _spec_tree_levels(spec, F: int) -> Dict[str, int]:
+    """Tree levels one launch of ``spec`` grows over ``F`` folds:
+    ``tree_level_builds`` (levels x trees: one level histogram each) and
+    ``tree_beam_levels`` (those at which a full frontier ranked its splits
+    by gain and kept half: ``frontier`` slots, not provably enough)."""
+    builds = beam = 0
+    for frag in spec[1]:
+        if frag[0] == "forest":
+            groups = [(len(g[0]) * g[2], g[1], g[9], g[10]) for g in frag[2]]
+        elif frag[0] == "gbt":
+            groups = [(len(g[0]) * g[1], g[2], g[8], g[9]) for g in frag[3]]
+        else:
+            continue
+        for trees, depth, frontier, exact_cap in groups:
+            builds += F * trees * depth
+            if not exact_cap:
+                beam += F * trees * max(depth - (frontier.bit_length() - 1), 0)
+    return {"tree_level_builds": builds, "tree_beam_levels": beam}
 
 
 def _max_gbt_chain(specs) -> Optional[Dict[str, int]]:

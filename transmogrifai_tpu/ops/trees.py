@@ -13,17 +13,24 @@ per-row control flow (SURVEY §7 "Trees/GBT/XGBoost on TPU"):
   levels run in ONE ``lax.fori_loop`` body with a fixed ``M``-slot frontier —
   so compile cost is independent of depth and per-level memory/compute is
   capped at ``M * d * B`` instead of ``2^depth * d * B``,
-- per level the (slot, feature, bin) gradient histograms are built with
-  ``segment_sum`` (one scatter per feature, vmapped) and the best split per
-  slot is a pure cumsum/argmax reduction — all VPU/MXU-friendly,
-- rows carry a frontier-slot id; the level update is a gather + compare,
+- per level the (slot, feature, bin) gradient histograms are built, on a
+  TPU, as a one-hot GEMM accumulated over row blocks (``grow_forest`` /
+  ``_grow_level_batch``: one path for every row count) and, on a CPU, with
+  ``segment_sum`` (one scatter per feature, vmapped: ``grow_tree``); the
+  best split per slot is a pure cumsum/argmax reduction,
+- rows carry a frontier-slot id; the level update is a gather + compare
+  (CPU) or a small GEMM + compare per row block (TPU),
 - second-order (g, h) statistics make the same builder serve XGBoost-style
   boosting (Newton leaves), RF regression (g = -y: variance gain, mean
   leaves), and RF classification (g = -onehot(y): gini-equivalent gain,
   class-distribution leaves),
-- a forest is ``vmap(grow_tree)`` over bootstrap row-weights and feature
-  masks; boosting is ``lax.scan`` over rounds — a whole RF trains as ONE
+- a forest grows its trees together, a chunk at a time (``grow_forest``;
+  ``vmap(grow_tree)`` over bootstrap row-weights and feature masks on a
+  CPU); boosting is ``lax.scan`` over rounds — a whole RF trains as ONE
   XLA launch and boosting compiles to a single fixed-trip loop.
+
+Speeds: ``PERF.md`` (PR 29: the default selector grid on 32,768 x 760 rows,
+TPU v5e).  A comment here that gives none says "not measured".
 
 Frontier exactness: depth-wise growth is EXACT whenever every level has at
 most ``M // 2`` valid splits.  A valid split needs hessian weight
@@ -208,8 +215,10 @@ def _hist_bf16() -> bool:
     force = os.environ.get("TMOG_HIST_BF16")
     if force is not None and force != "":
         return force == "1"
-    # measured on v5e: bf16 inputs LOSE ~2x on this matmul shape (the convert
-    # + re-layout outweighs the MXU saving at these small contractions)
+    # not measured on the chip.  Read from the program lowered for a v5e
+    # (PR 29): at the default matmul precision the compiler already rounds
+    # both float32 operands to bfloat16 inside the fusion that makes them
+    # (the bin one-hot even stays pred), so off and on run the same GEMM.
     return False
 
 
@@ -235,26 +244,57 @@ def _hist_subtract() -> bool:
     return True
 
 
-def _hist_via_matmul(n: int, d: int, n_bins: int, c1: int = 2) -> bool:
+def _hist_via_matmul() -> bool:
     """Pick the histogram formulation (static, at trace time).
 
-    TPU: scatters (segment_sum) serialize on the VPU and dominated the
-    round-2 sweep; the one-hot-matmul formulation routes the same reduction
-    through the MXU (measured ~20x faster on the Titanic sweep despite doing
-    more raw FLOPs).  It materializes a shared [n, c1*d*B] gradient one-hot,
-    so fall back to segment_sum when that exceeds ~2 GB (the 10M x 500 scale
-    config row-shards first, keeping each shard under the cap).  CPU keeps
-    segment_sum — scalar scatters are cheap there and the one-hot is pure
-    overhead.  TMOG_HIST_MATMUL=0/1 forces either path (parity tests).
+    TPU: the one-hot-matmul formulation, for every n — the (slot, feature,
+    bin) reduction as a GEMM on the MXU, accumulated over row blocks so that
+    neither one-hot is ever held whole (``hist_blocks``).  With the scatter
+    formulation (one ``segment_sum`` per feature, level and tree) the fused
+    launch of the default grid at 32,768 x 760 rows was refused by a v5e for
+    one 25.5 GB tensor, every forest of the per-family fallback likewise,
+    and the selector fit had not ended after 10 minutes (PERF.md, PR 29).
+    CPU keeps ``segment_sum`` — scalar scatters are cheap there and the
+    one-hot is pure overhead.  TMOG_HIST_MATMUL=0/1 forces either path
+    (parity tests).
     """
     import os
 
     force = os.environ.get("TMOG_HIST_MATMUL")
     if force is not None and force != "":
         return force == "1"
-    if jax.default_backend() != "tpu":
-        return False
-    return float(n) * d * n_bins * c1 * (2 if _hist_bf16() else 4) <= 2e9
+    return jax.default_backend() == "tpu"
+
+
+#: precision of the batch grower's selections by matmul (a 0/1 selector
+#: against histogram sums, split statistics or leaf values): the TPU's default
+#: rounds the selected float32 numbers to bfloat16 — a parent histogram of
+#: thousands of rows, a leaf's p(1) — which the chip runs of PR 29 read as
+#: forest fold AuPRs up to 5.3e-3 off the plain reference (PERF.md).  Exact
+#: here; the level-histogram GEMM itself keeps the default (hist_blocks).
+_EXACT = lax.Precision.HIGHEST
+
+#: bytes one chunk of trees may hold in level tensors (``forest_chunk_size``)
+#: and, a quarter of it, one row block of the level GEMM's operands
+_CHUNK_BUDGET_BYTES = 3e9
+
+
+def hist_blocks(n: int, lhs_rows: int, rhs_cols: int) -> Tuple[int, int]:
+    """(row blocks, rows a block) of the level-histogram GEMM.
+
+    The GEMM contracts over the n rows; its operands ([lhs_rows, n] weighted
+    slot one-hot, [n, rhs_cols] bin one-hot) are only ever made one row block
+    at a time, inside the scan that accumulates the [lhs_rows, rhs_cols]
+    histogram.  A block's operands get a quarter of the chunk budget; blocks
+    are equal and a multiple of 128 rows, and the rows are padded up to their
+    sum with rows that sit in no slot.  A table that fits one block (Titanic)
+    is one block of exactly n rows: the whole GEMM."""
+    cap = int(_CHUNK_BUDGET_BYTES / 4 / (4 * (lhs_rows + rhs_cols))) // 128 * 128
+    nb = -(-n // max(cap, 128))
+    if nb == 1:
+        return 1, n
+    rows = -(-n // nb)
+    return nb, -(-rows // 128) * 128
 
 
 def _bf16_hist_acc() -> bool:
@@ -300,8 +340,10 @@ def grad_onehot(Xb, gh, n_bins: int) -> jax.Array:
     tensor is shared by every tree of a vmapped forest."""
     n, d = Xb.shape
     dt = jnp.bfloat16 if _hist_bf16() else jnp.float32
-    oh = jax.nn.one_hot(Xb.astype(jnp.int32), n_bins, dtype=dt)  # [n, d, B]
-    og = gh.astype(dt)[:, :, None, None] * oh[:, None, :, :]     # [n, c1, d, B]
+    # one select, not a product with a stored one-hot: the [n, d, B] one-hot
+    # would be written and read once more than this tensor is
+    hit = Xb.astype(jnp.int32)[:, None, :, None] == jnp.arange(n_bins)
+    og = jnp.where(hit, gh.astype(dt)[:, :, None, None], 0)      # [n, c1, d, B]
     return og.reshape(n, -1)
 
 
@@ -682,200 +724,245 @@ def predict_tree(Xb, tree: Tree, max_depth: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Batch-native level grower — the whole tree chunk in ONE flat GEMM per level
 #
-# Round-5 measurement (tools/probe_hist_mm.py, v5e): the vmapped per-tree
-# histogram contraction ([m, n] @ [n, c1*d*B] batched over ~600 trees) runs
-# at ~2 TFLOP/s, while the SAME reduction flattened to a single
-# [T*m, n] @ [n, c1*d*B] GEMM runs at ~28 TFLOP/s — XLA lowers the big-M
-# 2-D GEMM onto the MXU 14x better than the small-M batched-GEMM.  So the
+# A note from before the first chip run (not chip evidence by PERF.md's
+# rule, not measured again): the per-tree contraction ([m, n] @ [n, c1*d*B]
+# batched over the trees) lowered far worse than the SAME reduction
+# flattened to a single [T*m, n] @ [n, c1*d*B] GEMM.  So the
 # forest kernels grow their whole chunk with an explicit tree axis: slot
-# one-hots are built [T, m, n] (slot axis ahead of rows: no transpose before
-# the flatten) and every level runs one flat GEMM.
+# one-hots are built [T, m, rows] (slot axis ahead of rows: no transpose
+# before the flatten) and every level runs one flat GEMM — accumulated over
+# row blocks (``hist_blocks``), so its cost in memory does not grow with n.
 # ---------------------------------------------------------------------------
-def _grow_level_batch(Xb, gh, w_t, feat_mask_t, nodes, leaf_val, slot_base,
-                      next_free, n_active, row_slot, row_node, m: int,
+def _grow_level_batch(Xk, ghk, wk, feat_mask_t, nodes, leaf_val, slot_base,
+                      next_free, n_active, slot_k, node_k, m: int,
                       next_cap: int, n_bins: int, reg_lambda_t, gamma_t,
-                      mcw_t, mig_t, Og, exact_cap: bool,
-                      gh_t=None, Obin=None, axis_name: Optional[str] = None,
+                      mcw_t, mig_t, exact_cap: bool, per_tree: bool,
+                      axis_name: Optional[str] = None,
                       pair_light=None, pair_hist=None,
                       want_pairs: bool = False):
     """One breadth-first level for a BATCH of T trees (shared Xb).
 
     Same split math as ``_grow_level`` (see its docstring for the
-    scatter/gather-free design); shapes carry a leading tree axis:
-    w_t f32[T, n], feat_mask_t f32[T, d], nodes i32[T, P, 4],
-    leaf_val f32[T, P, c], n_active i32[T], row_slot/row_node i32[T, n],
-    per-tree hyperparameters f32[T].  Two GEMM layouts:
+    scatter/gather-free design).  Everything that has a row axis arrives cut
+    into ``nb`` row blocks of ``bn`` rows (``grow_forest`` cuts them once,
+    ``hist_blocks`` sizes them): Xk i32[nb, bn, d], wk f32[nb, T, bn],
+    slot_k / node_k i32[nb, T, bn] (each row's frontier slot, -1 = resting or
+    padding, and its pool node).  Per tree: feat_mask_t f32[T, d], nodes
+    i32[T, P, 4], leaf_val f32[T, P, c], n_active i32[T], hyperparameters
+    f32[T].  Two GEMM layouts, both accumulated block by block:
 
     - SHARED gradients (forests: every tree of the sweep sees the same
-      g/h): ``gh`` f32[n, c1] + ``Og = grad_onehot(...)`` — LHS is the
-      weighted slot one-hot [T*m, n], RHS carries the gradients.
-    - PER-TREE gradients (boosting: each batch element has its own margins
-      F): ``gh_t`` f32[T, n, c1] + ``Obin = bin_onehot(...)`` — gradients
-      ride the LHS ([T*m*c1, n]), the RHS is the gradient-free bin one-hot
-      built once per LAUNCH instead of once per round.
+      g/h): ``ghk`` f32[nb, bn, c1] — LHS is the weighted slot one-hot
+      [T*m, bn], the RHS ``grad_onehot`` of the block carries the gradients.
+    - PER-TREE gradients (``per_tree``; boosting: each batch element has its
+      own margins F): ``ghk`` f32[nb, T, bn, c1] — gradients ride the LHS
+      ([T*m*c1, bn]), the RHS is the block's gradient-free ``bin_onehot``.
 
-    The segment-sum fallback stays on the vmapped ``grow_tree``.
+    Three named scopes split the level in a profiler trace: ``trees.hist``
+    (the block scan), ``trees.split`` (cumsum, gain, arg-max, beam ranking,
+    node records), ``trees.route`` (the second block scan: each row's next
+    slot and node).  The segment-sum fallback stays on the vmapped
+    ``grow_tree``.
     """
     B = n_bins
-    n, d = Xb.shape
-    c = (gh.shape[1] if gh_t is None else gh_t.shape[2]) - 1
-    T = w_t.shape[0]
+    nb, bn, d = Xk.shape
+    T = wk.shape[1]
+    c1 = ghk.shape[-1]
+    c = c1 - 1
     iota_m = jnp.arange(m)
     in_use = iota_m[None, :] < n_active[:, None]                    # [T, m]
-    # slot one-hot with slot axis BEFORE rows: flattening needs no transpose
-    S = (row_slot[:, None, :] == iota_m[None, :, None]).astype(jnp.float32)
     subtract = pair_hist is not None
     pairs = m // 2
     if subtract:
         # histogram subtraction: the level GEMM's LHS covers only the LIGHT
         # child of each sibling pair (half the slot rows); the heavy sibling
         # is parent - light after the data-axis psum (see _grow_level)
-        light_sel = jnp.stack([pair_light, 1.0 - pair_light], axis=-1)
-        S_hist = (S.reshape(T, pairs, 2, n) * light_sel[..., None]).sum(2)
         mh = pairs
+        hist_slot = (2 * jnp.arange(pairs)[None, :]
+                     + (pair_light < 0.5).astype(jnp.int32))        # [T, mh]
         record_trace_event("hist_subtracted", "mm_batch",
-                           2 * T * pairs * n * (c + 1) * d * B)
+                           2 * T * pairs * nb * bn * c1 * d * B)
     else:
-        S_hist = S
         mh = m
-    Sw = S_hist * w_t[:, None, :]                                   # [T, mh, n]
+        hist_slot = jnp.broadcast_to(iota_m[None, :], (T, m))
     acc_dt = jnp.bfloat16 if _bf16_hist_acc() else jnp.float32
     if acc_dt == jnp.bfloat16:
-        record_trace_event("bf16_hist", "mm_batch",
-                           2 * T * mh * (c + 1) * d * B)
-    if gh_t is None:
-        GH = lax.dot_general(Sw.reshape(T * mh, n).astype(Og.dtype), Og,
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=acc_dt)
-    else:
-        # [T, mh, c1, n]: slot one-hot x per-tree weighted gradients
-        L = Sw[:, :, None, :] * gh_t.transpose(0, 2, 1)[:, None, :, :]
-        GH = lax.dot_general(L.reshape(T * mh * (c + 1), n).astype(Obin.dtype),
-                             Obin, (((1,), (0,)), ((), ())),
-                             preferred_element_type=acc_dt)
-    # bf16 accumulation ends HERE: psum and split gains stay f32
-    GH = GH.astype(jnp.float32).reshape(T, mh, c + 1, d, B)
-    # global per-bin stats under a row-sharded launch (see _grow_level);
-    # subtracted levels psum only the light half of the payload
-    GH = mesh_psum(GH, axis_name)
-    if subtract:
-        GH_h = pair_hist - GH
-        lp = (pair_light > 0.5)[:, :, None, None, None]
-        GH = jnp.stack([jnp.where(lp, GH, GH_h),
-                        jnp.where(lp, GH_h, GH)],
-                       axis=2).reshape(T, m, c + 1, d, B)
-    G, H = GH[:, :, :c], GH[:, :, c]                # [T,m,c,d,B], [T,m,d,B]
-    GT = G[:, :, :, 0, :].sum(axis=-1)              # [T, m, c]
-    HT = H[:, :, 0, :].sum(axis=-1)                 # [T, m]
+        record_trace_event("bf16_hist", "mm_batch", 2 * T * mh * c1 * d * B)
 
-    GL = jnp.cumsum(G, axis=-1)
-    HL = jnp.cumsum(H, axis=-1)
-    GR = GT[:, :, :, None, None] - GL
-    HR = HT[:, :, None, None] - HL
+    def hist_block(acc, xs):
+        xb, ghb, wb, sb = xs
+        # weighted slot one-hot of the block, slot axis BEFORE rows:
+        # flattening needs no transpose
+        Sw = (sb[:, None, :] == hist_slot[:, :, None]).astype(jnp.float32) \
+            * wb[:, None, :]                                        # [T, mh, bn]
+        if per_tree:
+            rhs = bin_onehot(xb, B)                                 # [bn, d*B]
+            lhs = (Sw[:, :, None, :]
+                   * ghb.transpose(0, 2, 1)[:, None, :, :]).reshape(-1, bn)
+        else:
+            rhs = grad_onehot(xb, ghb, B)                        # [bn, c1*d*B]
+            lhs = Sw.reshape(-1, bn)
+        return acc + lax.dot_general(lhs.astype(rhs.dtype), rhs,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=acc_dt), None
 
-    lam = reg_lambda_t[:, None, None, None]
+    with jax.named_scope("trees.hist"):
+        gemm = (T * mh * c1, d * B) if per_tree else (T * mh, c1 * d * B)
+        GH, _ = lax.scan(hist_block, jnp.zeros(gemm, acc_dt),
+                         (Xk, ghk, wk, slot_k))
+        # bf16 accumulation ends HERE: psum and split gains stay f32
+        GH = GH.astype(jnp.float32).reshape(T, mh, c1, d, B)
+        # global per-bin stats under a row-sharded launch (see _grow_level);
+        # subtracted levels psum only the light half of the payload
+        GH = mesh_psum(GH, axis_name)
+        if subtract:
+            GH_h = pair_hist - GH
+            lp = (pair_light > 0.5)[:, :, None, None, None]
+            GH = jnp.stack([jnp.where(lp, GH, GH_h),
+                            jnp.where(lp, GH_h, GH)],
+                           axis=2).reshape(T, m, c1, d, B)
+    with jax.named_scope("trees.split"):
+        G, H = GH[:, :, :c], GH[:, :, c]            # [T,m,c,d,B], [T,m,d,B]
+        GT = G[:, :, :, 0, :].sum(axis=-1)          # [T, m, c]
+        HT = H[:, :, 0, :].sum(axis=-1)             # [T, m]
 
-    def score(Gp, Hp):
-        return (Gp * Gp).sum(axis=2) / (Hp + lam)
+        GL = jnp.cumsum(G, axis=-1)
+        HL = jnp.cumsum(H, axis=-1)
+        GR = GT[:, :, :, None, None] - GL
+        HR = HT[:, :, None, None] - HL
 
-    gain = score(GL, HL) + score(GR, HR) \
-        - ((GT * GT).sum(axis=2) / (HT + reg_lambda_t[:, None]))[:, :, None, None]
-    valid = (HL >= mcw_t[:, None, None, None]) & (HR >= mcw_t[:, None, None, None])
-    valid &= feat_mask_t[:, None, :, None] > 0.0
-    valid &= jnp.arange(B)[None, None, None, :] < B - 1
-    gain = jnp.where(valid, gain, -jnp.inf)
-    flat = gain.reshape(T, m, d * B)
-    best = jnp.argmax(flat, axis=-1)                                # [T, m]
-    best_gain = jnp.max(flat, axis=-1)
-    bf = (best // B).astype(jnp.int32)
-    bb = (best % B).astype(jnp.int32)
-    do_split = (best_gain > gamma_t[:, None]) \
-        & (best_gain >= mig_t[:, None] * HT) & in_use
-    half = next_cap // 2
-    if next_cap < 2 * m and not exact_cap:
-        key = jnp.where(do_split, -best_gain, jnp.inf)
-        rank = jnp.argsort(jnp.argsort(key, axis=1), axis=1)
-        do_split &= rank < half
-        k = jnp.cumsum(do_split.astype(jnp.int32), axis=1)
-    else:
-        k = jnp.cumsum(do_split.astype(jnp.int32), axis=1)
-        if next_cap < 2 * m:
-            do_split &= k <= half
-            k = jnp.minimum(k, half)
-    n_split = k[:, -1]
-    child_idx = (k - 1) * 2
-    left_pool = next_free + child_idx
-    right_pool = left_pool + 1
-    rec = jnp.stack([jnp.where(do_split, bf, -1),
-                     jnp.where(do_split, bb, 0),
-                     jnp.where(do_split, left_pool, 0),
-                     jnp.where(do_split, right_pool, 0)], axis=-1)  # [T, m, 4]
-    nodes = lax.dynamic_update_slice(nodes, rec, (0, slot_base, 0))
-    onehot_best = jax.nn.one_hot(best, d * B, dtype=GL.dtype)       # [T, m, dB]
-    GL_best = jnp.einsum("tmcx,tmx->tmc", GL.reshape(T, m, c, d * B),
-                         onehot_best)
-    HL_best = jnp.einsum("tmx,tmx->tm", HL.reshape(T, m, d * B), onehot_best)
-    GR_best = GT - GL_best
-    HR_best = HT - HL_best
-    lval = jnp.where(do_split[:, :, None],
-                     -GL_best / (HL_best + reg_lambda_t[:, None])[:, :, None], 0.0)
-    rval = jnp.where(do_split[:, :, None],
-                     -GR_best / (HR_best + reg_lambda_t[:, None])[:, :, None], 0.0)
-    iota_cap = jnp.arange(next_cap)
-    pos_l = jnp.where(do_split, child_idx, -1)
-    pos_r = jnp.where(do_split, child_idx + 1, -1)
-    L_eq = (iota_cap[None, :, None] == pos_l[:, None, :]).astype(leaf_val.dtype)
-    R_eq = (iota_cap[None, :, None] == pos_r[:, None, :]).astype(leaf_val.dtype)
-    child_vals = jnp.einsum("tpm,tmc->tpc", L_eq, lval) \
-        + jnp.einsum("tpm,tmc->tpc", R_eq, rval)          # [T, next_cap, c]
-    leaf_val = lax.dynamic_update_slice(leaf_val, child_vals, (0, next_free, 0))
-    # route rows: per-row slot data via the S matmul (gathers serialize)
-    pack = jnp.concatenate(
-        [do_split.astype(jnp.float32)[:, :, None],
-         bb.astype(jnp.float32)[:, :, None],
-         child_idx.astype(jnp.float32)[:, :, None],
-         jax.nn.one_hot(bf, d, dtype=jnp.float32)], axis=-1)        # [T, m, 3+d]
-    routed = jnp.einsum("tmn,tmp->tnp", S, pack)                    # [T, n, 3+d]
-    splits_here = routed[:, :, 0] > 0.5
-    child_r = routed[:, :, 2].astype(jnp.int32)
-    row_bin = (routed[:, :, 3:] * Xb[None, :, :]).sum(axis=-1)
-    go_right = (row_bin > routed[:, :, 1]).astype(jnp.int32)
-    new_row_slot = jnp.where(splits_here, child_r + go_right, -1)
-    row_node = jnp.where(splits_here, next_free + child_r + go_right, row_node)
+        lam = reg_lambda_t[:, None, None, None]
+
+        def score(Gp, Hp):
+            return (Gp * Gp).sum(axis=2) / (Hp + lam)
+
+        gain = score(GL, HL) + score(GR, HR) \
+            - ((GT * GT).sum(axis=2)
+               / (HT + reg_lambda_t[:, None]))[:, :, None, None]
+        valid = (HL >= mcw_t[:, None, None, None]) \
+            & (HR >= mcw_t[:, None, None, None])
+        valid &= feat_mask_t[:, None, :, None] > 0.0
+        valid &= jnp.arange(B)[None, None, None, :] < B - 1
+        gain = jnp.where(valid, gain, -jnp.inf)
+        flat = gain.reshape(T, m, d * B)
+        best = jnp.argmax(flat, axis=-1)                            # [T, m]
+        best_gain = jnp.max(flat, axis=-1)
+        bf = (best // B).astype(jnp.int32)
+        bb = (best % B).astype(jnp.int32)
+        do_split = (best_gain > gamma_t[:, None]) \
+            & (best_gain >= mig_t[:, None] * HT) & in_use
+        half = next_cap // 2
+        if next_cap < 2 * m and not exact_cap:
+            key = jnp.where(do_split, -best_gain, jnp.inf)
+            rank = jnp.argsort(jnp.argsort(key, axis=1), axis=1)
+            do_split &= rank < half
+            k = jnp.cumsum(do_split.astype(jnp.int32), axis=1)
+        else:
+            k = jnp.cumsum(do_split.astype(jnp.int32), axis=1)
+            if next_cap < 2 * m:
+                do_split &= k <= half
+                k = jnp.minimum(k, half)
+        n_split = k[:, -1]
+        child_idx = (k - 1) * 2
+        left_pool = next_free + child_idx
+        right_pool = left_pool + 1
+        rec = jnp.stack([jnp.where(do_split, bf, -1),
+                         jnp.where(do_split, bb, 0),
+                         jnp.where(do_split, left_pool, 0),
+                         jnp.where(do_split, right_pool, 0)], axis=-1)
+        nodes = lax.dynamic_update_slice(nodes, rec, (0, slot_base, 0))
+        onehot_best = jax.nn.one_hot(best, d * B, dtype=GL.dtype)   # [T,m,dB]
+        GL_best = jnp.einsum("tmcx,tmx->tmc", GL.reshape(T, m, c, d * B),
+                             onehot_best, precision=_EXACT)
+        HL_best = jnp.einsum("tmx,tmx->tm", HL.reshape(T, m, d * B),
+                             onehot_best, precision=_EXACT)
+        GR_best = GT - GL_best
+        HR_best = HT - HL_best
+        lval = jnp.where(
+            do_split[:, :, None],
+            -GL_best / (HL_best + reg_lambda_t[:, None])[:, :, None], 0.0)
+        rval = jnp.where(
+            do_split[:, :, None],
+            -GR_best / (HR_best + reg_lambda_t[:, None])[:, :, None], 0.0)
+        iota_cap = jnp.arange(next_cap)
+        pos_l = jnp.where(do_split, child_idx, -1)
+        pos_r = jnp.where(do_split, child_idx + 1, -1)
+        L_eq = (iota_cap[None, :, None]
+                == pos_l[:, None, :]).astype(leaf_val.dtype)
+        R_eq = (iota_cap[None, :, None]
+                == pos_r[:, None, :]).astype(leaf_val.dtype)
+        child_vals = jnp.einsum("tpm,tmc->tpc", L_eq, lval, precision=_EXACT) \
+            + jnp.einsum("tpm,tmc->tpc", R_eq, rval,
+                         precision=_EXACT)                # [T, next_cap, c]
+        leaf_val = lax.dynamic_update_slice(leaf_val, child_vals,
+                                            (0, next_free, 0))
+        if want_pairs:
+            # parent histograms packed at next-level pair positions (see
+            # _grow_level): every other row of the child-packing selector
+            GH_all = jnp.concatenate([G, H[:, :, None]],
+                                     axis=2).reshape(T, m, -1)
+            P_pair = L_eq[:, 0::2, :]                # [T, next_cap // 2, m]
+            new_pair_hist = jnp.einsum(
+                "tpm,tmx->tpx", P_pair, GH_all, precision=_EXACT).reshape(
+                T, next_cap // 2, c1, d, B)
+            new_pair_light = jnp.einsum(
+                "tpm,tm->tp", P_pair, (HL_best <= HR_best).astype(jnp.float32))
+    # route rows, a row block at a time.  Each row needs its slot's
+    # (do_split, split bin, child slot) and its own bin of the slot's split
+    # feature; per-element gathers serialize on the TPU, so the bin comes
+    # from ONE flat GEMM per block, one-hot(split feature) [T*m, d] against
+    # the block's bins [bn, d] -> [T, m, bn], and the row's slot picks its
+    # entry.  Every number is a small integer: exact in one bf16 pass up to
+    # 256 bins.
+    feat_sel = jax.nn.one_hot(bf, d, dtype=jnp.float32).reshape(T * m, d)
+
+    def route_block(_, xs):
+        xb, sb, nk = xs
+        S = sb[:, None, :] == iota_m[None, :, None]                # [T, m, bn]
+        slot_bin = lax.dot_general(
+            feat_sel, xb.astype(jnp.float32),
+            (((1,), (1,)), ((), ()))).reshape(T, m, bn)
+
+        def pick(per_slot):                                     # -> [T, bn]
+            return jnp.where(S, per_slot[:, :, None], 0).sum(axis=1)
+
+        splits_here = pick(do_split.astype(jnp.int32)) > 0
+        go_right = (jnp.where(S, slot_bin, 0.0).sum(axis=1)
+                    > pick(bb).astype(jnp.float32)).astype(jnp.int32)
+        child = pick(child_idx) + go_right
+        return None, (jnp.where(splits_here, child, -1),
+                      jnp.where(splits_here, next_free + child, nk))
+
+    with jax.named_scope("trees.route"):
+        _, (slot_k, node_k) = lax.scan(route_block, None, (Xk, slot_k, node_k))
     if want_pairs:
-        # parent histograms packed at next-level pair positions (see
-        # _grow_level): every other row of the child-packing selector L_eq
-        GH_all = jnp.concatenate([G, H[:, :, None]], axis=2).reshape(T, m, -1)
-        P_pair = L_eq[:, 0::2, :]                    # [T, next_cap // 2, m]
-        new_pair_hist = jnp.einsum("tpm,tmx->tpx", P_pair, GH_all).reshape(
-            T, next_cap // 2, c + 1, d, B)
-        new_pair_light = jnp.einsum(
-            "tpm,tm->tp", P_pair, (HL_best <= HR_best).astype(jnp.float32))
-        return (nodes, leaf_val, 2 * n_split, new_row_slot, row_node,
+        return (nodes, leaf_val, 2 * n_split, slot_k, node_k,
                 new_pair_light, new_pair_hist)
-    return nodes, leaf_val, 2 * n_split, new_row_slot, row_node
+    return nodes, leaf_val, 2 * n_split, slot_k, node_k
 
 
 def grow_forest(Xb, g, h, w_t, feat_mask_t, max_depth: int, n_bins: int,
                 frontier: int, reg_lambda_t, gamma_t, mcw_t, mig_t,
                 exact_cap: bool = False, return_row_node: bool = False,
-                gh_t=None, Obin=None, axis_name: Optional[str] = None):
+                gh_t=None, axis_name: Optional[str] = None):
     """Grow T trees together; ONE flat GEMM per level (see header note).
 
     Shared: Xb int[n, d].  Gradients either SHARED (g f32[n, c], h f32[n] —
-    forests) or PER TREE (``gh_t`` f32[T, n, c1] with ``Obin =
-    bin_onehot(Xb, n_bins)``; pass g/h as None — boosting).  Per tree:
-    w_t f32[T, n], feat_mask_t f32[T, d], reg_lambda/gamma/mcw/mig f32[T].
-    Falls back to ``vmap(grow_tree)`` when the matmul histogram path is off
-    (CPU).  Returns Tree with leading [T] axis (+ row_node on request).
+    forests) or PER TREE (``gh_t`` f32[T, n, c1]; pass g/h as None —
+    boosting).  Per tree: w_t f32[T, n], feat_mask_t f32[T, d],
+    reg_lambda/gamma/mcw/mig f32[T].  The rows are cut into blocks once,
+    here, sized for the widest level (``hist_blocks``).  Falls back to
+    ``vmap(grow_tree)`` when the matmul histogram path is off (CPU).
+    Returns Tree with leading [T] axis (+ row_node on request).
     """
     Xb = Xb.astype(jnp.int32)
     n, d = Xb.shape
-    c = (g.shape[1] if gh_t is None else gh_t.shape[2] - 1)
+    per_tree = gh_t is not None
+    c = gh_t.shape[2] - 1 if per_tree else g.shape[1]
     c1 = c + 1
     T = w_t.shape[0]
-    if not _hist_via_matmul(n, d, n_bins, c1):
-        if gh_t is None:
+    if not _hist_via_matmul():
+        if not per_tree:
             def one(wt, fm, lam, gam, mcw, mig):
                 return grow_tree(Xb, g, h, wt, fm, max_depth, n_bins,
                                  frontier, reg_lambda=lam, gamma=gam,
@@ -895,26 +982,18 @@ def grow_forest(Xb, g, h, w_t, feat_mask_t, max_depth: int, n_bins: int,
 
         return jax.vmap(one)(gh_t, w_t, feat_mask_t, reg_lambda_t, gamma_t,
                              mcw_t, mig_t)
-    if gh_t is None:
-        gh = jnp.concatenate([g, h[:, None]], axis=1)
-        Og = grad_onehot(Xb, gh, n_bins)
-        Obin = None
-        gw_sum = (g[None, :, :] * w_t[:, :, None]).sum(axis=1)      # [T, c]
-        hw_sum = (h[None, :] * w_t).sum(axis=1)                     # [T]
-    else:
-        gh = None
-        Og = None
-        if Obin is None:
-            Obin = bin_onehot(Xb, n_bins)
+    if per_tree:
         gw_sum = (gh_t[:, :, :c] * w_t[:, :, None]).sum(axis=1)
         hw_sum = (gh_t[:, :, c] * w_t).sum(axis=1)
+    else:
+        gw_sum = (g[None, :, :] * w_t[:, :, None]).sum(axis=1)      # [T, c]
+        hw_sum = (h[None, :] * w_t).sum(axis=1)                     # [T]
     gw_sum = mesh_psum(gw_sum, axis_name)
     hw_sum = mesh_psum(hw_sum, axis_name)
     P = _pool_size(max_depth, frontier)
     root_val = -gw_sum / (hw_sum + reg_lambda_t)[:, None]
     nodes = jnp.tile(jnp.asarray([-1, 0, 0, 0], jnp.int32), (T, P, 1))
     leaf_val = jnp.zeros((T, P, c), jnp.float32).at[:, 0].set(root_val)
-    row_node = jnp.zeros((T, n), jnp.int32)
 
     def as_tree(nodes, leaf_val):
         return Tree(split_feat=nodes[:, :, 0], split_bin=nodes[:, :, 1],
@@ -923,55 +1002,58 @@ def grow_forest(Xb, g, h, w_t, feat_mask_t, max_depth: int, n_bins: int,
 
     if max_depth <= 0:
         tree = as_tree(nodes, leaf_val)
-        return (tree, row_node) if return_row_node else tree
+        return (tree, jnp.zeros((T, n), jnp.int32)) if return_row_node else tree
 
     M = frontier
     L = M.bit_length() - 1
     sub = _hist_subtract() and max_depth > 1
+    # row blocks, sized for the widest level's operands
+    mh = min(M, 1 << (max_depth - 1))
+    mh = max(mh // 2, 1) if sub else mh
+    nb, bn = (hist_blocks(n, T * mh * c1, d * n_bins) if per_tree
+              else hist_blocks(n, T * mh, c1 * d * n_bins))
+
+    def blocks(a, axis: int, fill=0):
+        """[.., n, ..] -> [nb, .., bn, ..]: pad the row axis, cut it, and
+        put the block axis first (what ``lax.scan`` walks)."""
+        widths = [(0, 0)] * a.ndim
+        widths[axis] = (0, nb * bn - n)
+        a = jnp.pad(a, widths, constant_values=fill)
+        a = a.reshape(a.shape[:axis] + (nb, bn) + a.shape[axis + 1:])
+        return jnp.moveaxis(a, axis, 0)
+
+    Xk = blocks(Xb, 0)
+    wk = blocks(w_t, 1)
+    ghk = (blocks(gh_t, 1) if per_tree
+           else blocks(jnp.concatenate([g, h[:, None]], axis=1), 0))
     carry = (nodes, leaf_val, jnp.ones((T,), jnp.int32),
-             jnp.zeros((T, n), jnp.int32), row_node)
-    pl = ph = None
-    u = min(max_depth, L)
-    for t in range(u):
-        out = _grow_level_batch(
-            Xb, gh, w_t, feat_mask_t, carry[0], carry[1], (1 << t) - 1,
-            (1 << (t + 1)) - 1, *carry[2:], m=1 << t, next_cap=1 << (t + 1),
-            n_bins=n_bins, reg_lambda_t=reg_lambda_t, gamma_t=gamma_t,
-            mcw_t=mcw_t, mig_t=mig_t, Og=Og, exact_cap=exact_cap,
-            gh_t=gh_t, Obin=Obin, axis_name=axis_name,
-            pair_light=pl, pair_hist=ph, want_pairs=sub)
-        if sub:
-            carry, pl, ph = out[:5], out[5], out[6]
-        else:
-            carry = out
+             blocks(jnp.zeros((T, n), jnp.int32), 1, fill=-1),      # row slot
+             jnp.zeros((nb, T, bn), jnp.int32))                     # row node
+
+    def level(state, slot_base, next_free, m, next_cap, want_pairs):
+        return _grow_level_batch(
+            Xk, ghk, wk, feat_mask_t, state[0], state[1], slot_base,
+            next_free, *state[2:5], m=m, next_cap=next_cap, n_bins=n_bins,
+            reg_lambda_t=reg_lambda_t, gamma_t=gamma_t, mcw_t=mcw_t,
+            mig_t=mig_t, exact_cap=exact_cap, per_tree=per_tree,
+            axis_name=axis_name,
+            pair_light=state[5] if len(state) > 5 else None,
+            pair_hist=state[6] if len(state) > 5 else None,
+            want_pairs=want_pairs)
+
+    for t in range(min(max_depth, L)):
+        carry = level(carry, (1 << t) - 1, (1 << (t + 1)) - 1, 1 << t,
+                      1 << (t + 1), sub)
     if max_depth > L:
-        if sub:
-            def body(t, state):
-                sb = M - 1 + (t - L) * M
-                return _grow_level_batch(
-                    Xb, gh, w_t, feat_mask_t, state[0], state[1], sb, sb + M,
-                    *state[2:5], m=M, next_cap=M, n_bins=n_bins,
-                    reg_lambda_t=reg_lambda_t, gamma_t=gamma_t, mcw_t=mcw_t,
-                    mig_t=mig_t, Og=Og, exact_cap=exact_cap,
-                    gh_t=gh_t, Obin=Obin, axis_name=axis_name,
-                    pair_light=state[5], pair_hist=state[6], want_pairs=True)
+        def body(t, state):
+            sb = M - 1 + (t - L) * M
+            return level(state, sb, sb + M, M, M, sub)
 
-            carry = lax.fori_loop(L, max_depth, body,
-                                  tuple(carry) + (pl, ph))[:5]
-        else:
-            def body(t, carry):
-                sb = M - 1 + (t - L) * M
-                return _grow_level_batch(
-                    Xb, gh, w_t, feat_mask_t, carry[0], carry[1], sb, sb + M,
-                    *carry[2:], m=M, next_cap=M, n_bins=n_bins,
-                    reg_lambda_t=reg_lambda_t, gamma_t=gamma_t, mcw_t=mcw_t,
-                    mig_t=mig_t, Og=Og, exact_cap=exact_cap,
-                    gh_t=gh_t, Obin=Obin, axis_name=axis_name)
-
-            carry = lax.fori_loop(L, max_depth, body, carry)
-    nodes, leaf_val, row_node = carry[0], carry[1], carry[4]
-    tree = as_tree(nodes, leaf_val)
-    return (tree, row_node) if return_row_node else tree
+        carry = lax.fori_loop(L, max_depth, body, tuple(carry))
+    tree = as_tree(carry[0], carry[1])
+    if not return_row_node:
+        return tree
+    return tree, jnp.moveaxis(carry[4], 0, 1).reshape(T, nb * bn)[:, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -1007,17 +1089,18 @@ def predict_forest(Xb, forest: Tree, max_depth: int) -> jax.Array:
 
 
 def forest_chunk_size(max_depth: int, n_bins: int, d: int, c: int,
-                      frontier: int, budget_bytes: float = 3e9,
+                      frontier: int, budget_bytes: float = _CHUNK_BUDGET_BYTES,
                       n_rows: int = 0) -> int:
     """Trees per chunk so one chunk's level tensors fit the budget.
 
     A level materializes G [M, d, B, c] + cumsums per tree (x3 covers the
-    cumsum/gain temporaries) plus, on the batch-GEMM path, the slot one-hot
-    [M, n] and its weighted flattening (the ``2 * n_rows`` term).  With
-    histogram subtraction on, the carried parent pair histograms add about
-    half a level's histograms (the 0.5 bump)."""
+    cumsum/gain temporaries); with histogram subtraction on, the carried
+    parent pair histograms add about half a level's histograms (the 0.5
+    bump).  Of the rows a tree keeps its weights, slots and nodes (the
+    ``3 * n_rows`` term): the [M, rows] slot one-hot exists one row block at
+    a time and has its own quarter of the budget (``hist_blocks``)."""
     hist_factor = 3.5 if _hist_subtract() else 3.0
-    per_tree = frontier * (n_bins * d * (c + 1) * hist_factor + 2 * n_rows) * 4
+    per_tree = (frontier * n_bins * d * (c + 1) * hist_factor + 3 * n_rows) * 4
     return max(1, int(budget_bytes / max(per_tree, 1)))
 
 
@@ -1150,11 +1233,12 @@ def _gbt_impl(Xb, y, w, row_w_rounds, feat_mask_rounds, loss: str, n_rounds: int
         if loss == "softmax" else jnp.zeros((n, 2), jnp.float32)
     F0 = (jnp.asarray(init_margins, jnp.float32) if init_margins is not None
           else jnp.full((n, c), base_score, jnp.float32))
-    use_mm = _hist_via_matmul(n, Xb.shape[1], n_bins, c + 1)
-    K = int(trees_per_round)
+    K = max(int(trees_per_round), 1)
 
-    record_trace_event("gbt_chain", loss, n_rounds // max(K, 1))
-    if K > 1:
+    record_trace_event("gbt_chain", loss, n_rounds // K)
+    # the matmul histogram lives in the batch grower alone, so on that path
+    # K = 1 is a forest of one tree a round (the same scan, no collapse)
+    if K > 1 or _hist_via_matmul():
         if n_rounds % K:
             raise ValueError(
                 f"trees_per_round={K} must divide n_rounds={n_rounds}")
@@ -1188,14 +1272,11 @@ def _gbt_impl(Xb, y, w, row_w_rounds, feat_mask_rounds, loss: str, n_rounds: int
     def round_fn(F, xs):
         rw, fm = xs
         g, hh = _grad_hess(loss, F, y, Y)
-        # gradients change per round, so the shared one-hot is per-round too
-        Og = (grad_onehot(Xb, jnp.concatenate([g, hh[:, None]], axis=1),
-                          n_bins) if use_mm else None)
         tree, row_node = grow_tree(
             Xb, g, hh, w * rw, fm, max_depth, n_bins, frontier,
             reg_lambda=reg_lambda, gamma=gamma,
             min_child_weight=min_child_weight,
-            min_info_gain=min_info_gain, Og=Og, return_row_node=True,
+            min_info_gain=min_info_gain, return_row_node=True,
             exact_cap=exact_cap, axis_name=axis_name)
         # row_node is each row's resting node — no predict walk needed
         F = F + eta * tree.leaf_val[row_node]
@@ -1266,7 +1347,7 @@ def _gbt_batch_impl(Xb, y, w_batch, row_w_rounds, feat_mask_rounds, loss: str,
     if n_rounds % max(K, 1):
         raise ValueError(
             f"trees_per_round={K} must divide n_rounds={n_rounds}")
-    if not _hist_via_matmul(n, d, n_bins, c + 1):
+    if not _hist_via_matmul():
         # segment-sum backends keep the per-element vmap formulation
         def one(w, eta, lam, gam, mcw, base, mig):
             _, F = _gbt_impl(Xb, y, w, row_w_rounds, feat_mask_rounds, loss,
@@ -1280,12 +1361,10 @@ def _gbt_batch_impl(Xb, y, w_batch, row_w_rounds, feat_mask_rounds, loss: str,
                              min_child_weight_b, base_score_b, min_info_gain_b)
 
     # batch-native boosting: every step grows its B * K trees as ONE
-    # flat-GEMM forest (per-tree gradients ride the LHS); the gradient-free
-    # bin one-hot RHS is built ONCE for the whole launch instead of per
-    # round (see bin_onehot / _grow_level_batch)
+    # flat-GEMM forest: per-tree gradients ride the LHS, the RHS is the
+    # gradient-free bin one-hot of a row block (see _grow_level_batch)
     Y = jax.nn.one_hot(y.astype(jnp.int32), max(c, 2), dtype=jnp.float32) \
         if loss == "softmax" else jnp.zeros((n, 2), jnp.float32)
-    Obin = bin_onehot(Xb, n_bins)
     F0 = jnp.broadcast_to(base_score_b[:, None, None], (B, n, c)).astype(jnp.float32)
     steps = n_rounds // K
     record_trace_event("gbt_chain", loss, steps)
@@ -1319,7 +1398,7 @@ def _gbt_batch_impl(Xb, y, w_batch, row_w_rounds, feat_mask_rounds, loss: str,
             mcw_t=jnp.repeat(min_child_weight_b, K),
             mig_t=jnp.repeat(min_info_gain_b, K),
             exact_cap=exact_cap, return_row_node=True,
-            gh_t=gh_T, Obin=Obin, axis_name=axis_name)
+            gh_t=gh_T, axis_name=axis_name)
         # leaf lookup via one gather per step (row_node tracks leaves)
         leaves = jnp.take_along_axis(
             tree.leaf_val, row_node[:, :, None].repeat(c, axis=2), axis=1)
