@@ -306,6 +306,39 @@ def test_histogram_blocked_at_the_trees_cell_rows(scale_features, one_chip):
     assert "segment" not in text  # histograms ride dot ops
 
 
+def test_histogram_compacted_at_the_trees_cell_rows(scale_features, one_chip):
+    """A forest chunk on its kept features (28 of the vector's columns a
+    tree, as ``kept_features`` draws them): 300 trees at the trees
+    cell's 32,768 rows — the column gather, the tree-batched GEMM over row
+    blocks and the k-wide routing, three levels of them."""
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.ops import trees as Tr
+
+    d = scale_features["width"]
+    n, T = 32768, 300
+    k = Tr.n_kept(d, np.sqrt(d) / d)
+    assert k < d and Tr.hist_blocks(n, T * 2 * CHANNELS, T * k * N_BINS)[0] > 1
+
+    def grow(Xb, y, w, kept):
+        ones = jnp.ones((T,), jnp.float32)
+        tree, node = Tr.grow_forest(
+            Xb, -y[:, None], jnp.ones_like(y), w, kept, 3, N_BINS, 256,
+            reg_lambda_t=1e-6 * ones, gamma_t=0 * ones, mcw_t=10 * ones,
+            mig_t=0 * ones, return_row_node=True)
+        return tree.split_feat, tree.leaf_val, node
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(grow).lower(
+        S((n, d), np.int8), S((n,), np.float32), S((T, n), np.float32),
+        S((T, k), np.int32)).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert "trees.hist" in text and "trees.route" in text
+    assert "segment" not in text  # histograms ride dot ops
+    assert f"s8[{T},{k}," in text  # the kept columns stay one byte a cell
+
+
 def test_histogram_segment_sum_at_phase_b_width(scale_features, one_chip):
     """The scatter formulation (what a CPU runs, and ``TMOG_HIST_MATMUL=0``
     forces): phase B's sweep rows x vector width."""

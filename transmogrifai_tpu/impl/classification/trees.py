@@ -102,7 +102,7 @@ class OpRandomForestClassifier(_TreeClassifierBase):
         wt = Tr.bootstrap_weights(
             kb, n, n_trees,
             rate=float(self.get_param("subsampling_rate", 1.0))) * _as_f32(sw)[None, :]
-        fms = Tr.feature_masks(kf, d, n_trees, self._subset_frac(d))
+        fms = Tr.kept_features(kf, d, n_trees, self._subset_frac(d))
         mcw = float(self.get_param("min_instances_per_node", 1))
         forest = Tr.fit_forest(jnp.asarray(Xb), jnp.asarray(G), _as_f32(np.ones(n)),
                                jnp.asarray(wt), jnp.asarray(fms),

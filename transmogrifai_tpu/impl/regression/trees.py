@@ -65,7 +65,7 @@ class OpRandomForestRegressor(_TreeRegressorBase):
             kb, n, n_trees,
             rate=float(self.get_param("subsampling_rate", 1.0))
         ) * jnp.asarray(sw)[None, :]
-        fms = Tr.feature_masks(kf, d, n_trees, self._subset_frac(d))
+        fms = Tr.kept_features(kf, d, n_trees, self._subset_frac(d))
         g = jnp.asarray(-np.asarray(y, np.float32)[:, None])
         mcw = float(self.get_param("min_instances_per_node", 1))
         forest = Tr.fit_forest(jnp.asarray(Xb), g, jnp.ones(n, jnp.float32),

@@ -636,7 +636,8 @@ def _forest_fragment(est, grids, pos: int, blob: _Blob, xbs, X, train_w,
         F = train_w.shape[0]
         TT = F * len(idxs) * ntrees
         chunk = Tr.balanced_chunk(
-            TT, Tr.forest_chunk_size(depth, n_bins, d, c, frontier, n_rows=n))
+            TT, Tr.forest_chunk_size(depth, n_bins, d, c, frontier, n_rows=n,
+                                     n_kept=Tr.n_kept(d, frac)))
         out_groups.append((
             tuple(int(pos + i) for i in idxs), depth, ntrees,
             _xb_index(xbs, X, n_bins), n_bins, frac,

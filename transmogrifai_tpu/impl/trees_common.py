@@ -258,9 +258,13 @@ def forest_grid_folds(est, X, y, train_w, grids, n_classes: int, convert) -> lis
     out = [[None] * len(grids) for _ in range(n_folds)]
     groups: Dict[tuple, list] = {}
     for ci, cand in enumerate(candidates):
+        # the kept-feature count is static too: it is the width a group's
+        # trees are grown at (ops/trees.grow_forest)
+        bag = bool(getattr(cand, "_grid_bootstrap", True))
         static = (int(cand.get_param("max_depth", 5)),
                   int(cand.get_param("num_trees", 20)),
-                  int(cand.get_param("max_bins", 32)))
+                  int(cand.get_param("max_bins", 32)),
+                  Tr.n_kept(d, cand._subset_frac(d) if bag else 1.0))
         groups.setdefault(static, []).append(ci)
 
     # Binary classification uses the 1-channel variance kernel: for 0/1
@@ -274,14 +278,14 @@ def forest_grid_folds(est, X, y, train_w, grids, n_classes: int, convert) -> lis
         G = -np.asarray(y, np.float32)[:, None]
     H = np.ones(n, np.float32)
 
-    for (max_depth, n_trees, n_bins), cis in groups.items():
+    for (max_depth, n_trees, n_bins, n_kept), cis in groups.items():
         Xb, _ = Tr.quantize(X, n_bins)
         mcw_min = min(float(candidates[ci].get_param("min_instances_per_node", 1))
                       for ci in cis)
         pairs = [(f, ci) for f in range(n_folds) for ci in cis]
         TT = len(pairs) * n_trees
         w_trees = np.empty((TT, n), np.float32)
-        fms = np.empty((TT, d), np.float32)
+        fis = np.empty((TT, n_kept), np.int32)
         mcw = np.empty(TT, np.float32)
         mig = np.zeros(TT, np.float32)
         draw_cache: Dict[tuple, tuple] = {}
@@ -296,11 +300,11 @@ def forest_grid_folds(est, X, y, train_w, grids, n_classes: int, convert) -> lis
                 kb, kfm = Tr.rng_keys(seed)
                 draw_cache[dkey] = (
                     np.asarray(Tr.bootstrap_weights(kb, n, n_trees, bag, rate)),
-                    np.asarray(Tr.feature_masks(kfm, d, n_trees,
+                    np.asarray(Tr.kept_features(kfm, d, n_trees,
                                                 frac if bag else 1.0)))
-            boot, fm = draw_cache[dkey]
+            boot, fi = draw_cache[dkey]
             w_trees[gi * n_trees:(gi + 1) * n_trees] = boot * train_w[f][None, :]
-            fms[gi * n_trees:(gi + 1) * n_trees] = fm
+            fis[gi * n_trees:(gi + 1) * n_trees] = fi
             mcw[gi * n_trees:(gi + 1) * n_trees] = float(
                 cand.get_param("min_instances_per_node", 1))
             mig[gi * n_trees:(gi + 1) * n_trees] = float(
@@ -320,17 +324,18 @@ def forest_grid_folds(est, X, y, train_w, grids, n_classes: int, convert) -> lis
         n_shard = model_shards()
         chunk = Tr.balanced_chunk(
             max(TT // n_shard, 1),
-            Tr.forest_chunk_size(max_depth, n_bins, d, c, frontier, n_rows=n))
+            Tr.forest_chunk_size(max_depth, n_bins, d, c, frontier, n_rows=n,
+                                 n_kept=n_kept))
         pad = (-TT) % (chunk * n_shard)
         if pad:  # zero-weight padding trees grow no splits and are dropped
             w_trees = np.concatenate([w_trees, np.zeros((pad, n), np.float32)])
-            fms = np.concatenate([fms, np.ones((pad, d), np.float32)])
+            fis = np.concatenate([fis, np.tile(fis[:1], (pad, 1))])
             mcw = np.concatenate([mcw, np.ones(pad, np.float32)])
             mig = np.concatenate([mig, np.zeros(pad, np.float32)])
         if n_shard > 1:  # tree axis spread over the mesh model axis
             forest = Tr.fit_forest_sharded(
                 active_mesh(), MODEL_AXIS, jnp.asarray(Xb), jnp.asarray(G),
-                jnp.asarray(H), jnp.asarray(w_trees), jnp.asarray(fms),
+                jnp.asarray(H), jnp.asarray(w_trees), jnp.asarray(fis),
                 jnp.asarray(mcw), max_depth=max_depth, n_bins=n_bins,
                 chunk=chunk, frontier=frontier, mig_trees=jnp.asarray(mig),
                 exact_cap=exact_cap)
@@ -338,7 +343,7 @@ def forest_grid_folds(est, X, y, train_w, grids, n_classes: int, convert) -> lis
         else:
             forest = Tr.fit_forest_chunked(
                 jnp.asarray(Xb), jnp.asarray(G), jnp.asarray(H), jnp.asarray(w_trees),
-                jnp.asarray(fms), jnp.asarray(mcw), max_depth=max_depth,
+                jnp.asarray(fis), jnp.asarray(mcw), max_depth=max_depth,
                 n_bins=n_bins, chunk=chunk, frontier=frontier,
                 mig_trees=jnp.asarray(mig), exact_cap=exact_cap)
         if pad:

@@ -220,7 +220,9 @@ def _forest_group_scores(group, xbs, y, train_w, blob, out_c: int, rs=None):
         boot = _local_rows(
             Tr.bootstrap_weights(kb, rs[1], n_trees, bootstrap, rate),
             n, rs, axis=1)
-    fm = Tr.feature_masks(kf, d, n_trees, frac)                   # [T, d]
+    # the draw's kept features as an index table: its static width k is what
+    # grow_forest grows the chunk at (k < d: on the kept columns alone)
+    fi = Tr.kept_features(kf, d, n_trees, frac)                   # [T, k]
     g = -y[:, None] if out_c == 1 else -jax.nn.one_hot(
         y.astype(jnp.int32), out_c, dtype=jnp.float32)
     h = jnp.ones_like(y)
@@ -232,21 +234,21 @@ def _forest_group_scores(group, xbs, y, train_w, blob, out_c: int, rs=None):
                           (F, Gc, n_trees, n)).reshape(F * Gc * n_trees, n)
     mcw_t = jnp.tile(jnp.repeat(mcw, n_trees), F)
     mig_t = jnp.tile(jnp.repeat(mig, n_trees), F)
-    fm_t = jnp.tile(fm, (F * Gc, 1))
+    fi_t = jnp.tile(fi, (F * Gc, 1))
     TT = F * Gc * n_trees
     pad = (-TT) % chunk
     if pad:  # zero-weight padding trees grow nothing and are sliced off
         wt = jnp.concatenate([wt, jnp.zeros((pad, n), jnp.float32)])
-        fm_t = jnp.concatenate([fm_t, jnp.ones((pad, d), jnp.float32)])
+        fi_t = jnp.concatenate([fi_t, jnp.tile(fi[:1], (pad, 1))])
         mcw_t = jnp.concatenate([mcw_t, jnp.ones(pad, jnp.float32)])
         mig_t = jnp.concatenate([mig_t, jnp.zeros(pad, jnp.float32)])
 
     def one_chunk(args):
-        wts, fms, mcws, migs = args
+        wts, fis, mcws, migs = args
         lam = jnp.full(wts.shape[0], 1e-6, jnp.float32)
         gam = jnp.zeros(wts.shape[0], jnp.float32)
         tree, row_node = Tr.grow_forest(
-            Xb, g, h, wts, fms, depth, n_bins, frontier,
+            Xb, g, h, wts, fis, depth, n_bins, frontier,
             reg_lambda_t=lam, gamma_t=gam, mcw_t=mcws, mig_t=migs,
             exact_cap=exact_cap, return_row_node=True,
             axis_name=_rs_axis(rs))
@@ -259,7 +261,7 @@ def _forest_group_scores(group, xbs, y, train_w, blob, out_c: int, rs=None):
             tree.leaf_val, row_node[:, :, None].repeat(c, axis=2), axis=1)
 
     preds = lax.map(one_chunk, (wt.reshape(-1, chunk, n),
-                                fm_t.reshape(-1, chunk, d),
+                                fi_t.reshape(-1, chunk, fi.shape[1]),
                                 mcw_t.reshape(-1, chunk),
                                 mig_t.reshape(-1, chunk)))
     preds = preds.reshape((-1,) + preds.shape[2:])[:TT]       # [TT, n, c]
@@ -769,7 +771,7 @@ _sweep_scope = obs_registry.scope("sweep", defaults={
     "pruned_candidates": 0, "full_candidates": 0, "checkpoint_skips": 0,
     "hedges_fired": 0, "hedge_wasted_s": 0.0, "asha_rungs": [],
     "sweep_pack_count": 0, "launches_avoided": 0,
-    "tree_level_builds": 0, "tree_beam_levels": 0})
+    "tree_level_builds": 0, "tree_beam_levels": 0, "tree_kept_levels": 0})
 obs_registry.register_provider("sweep", lambda: run_stats())
 
 #: per-(name, spec, device, arg-signature) AOT executables.  jit's own cache
@@ -831,9 +833,11 @@ def run_stats() -> Dict[str, Any]:
             # one-launch-per-candidate baseline (record_packs + the
             # row-sharded metric map)
             # tree levels grown by the single-device launches since reset,
-            # and those that ranked a full frontier (_spec_tree_levels)
+            # those that ranked a full frontier, and those grown on a
+            # tree's kept features alone (_spec_tree_levels)
             "tree_level_builds": _sweep_scope.get("tree_level_builds"),
             "tree_beam_levels": _sweep_scope.get("tree_beam_levels"),
+            "tree_kept_levels": _sweep_scope.get("tree_kept_levels"),
             "sweep_pack_count": _sweep_scope.get("sweep_pack_count"),
             "launches_avoided": _sweep_scope.get("launches_avoided"),
             # sequential non-overlapped GBT launch-levels on the critical
@@ -929,22 +933,30 @@ def _spec_gbt_chain(spec) -> Optional[Dict[str, int]]:
 
 def _spec_tree_levels(spec, F: int) -> Dict[str, int]:
     """Tree levels one launch of ``spec`` grows over ``F`` folds:
-    ``tree_level_builds`` (levels x trees: one level histogram each) and
+    ``tree_level_builds`` (levels x trees: one level histogram each),
     ``tree_beam_levels`` (those at which a full frontier ranked its splits
-    by gain and kept half: ``frontier`` slots, not provably enough)."""
-    builds = beam = 0
+    by gain and kept half: ``frontier`` slots, not provably enough) and
+    ``tree_kept_levels`` (those built on a compacted feature axis, the
+    tree's kept features alone: forests with a subset fraction under 1, on
+    the matmul histogram path; boosting builds full width)."""
+    builds = beam = kept = 0
     for frag in spec[1]:
         if frag[0] == "forest":
-            groups = [(len(g[0]) * g[2], g[1], g[9], g[10]) for g in frag[2]]
+            groups = [(len(g[0]) * g[2], g[1], g[9], g[10], g[5])
+                      for g in frag[2]]
         elif frag[0] == "gbt":
-            groups = [(len(g[0]) * g[1], g[2], g[8], g[9]) for g in frag[3]]
+            groups = [(len(g[0]) * g[1], g[2], g[8], g[9], 1.0)
+                      for g in frag[3]]
         else:
             continue
-        for trees, depth, frontier, exact_cap in groups:
+        for trees, depth, frontier, exact_cap, frac in groups:
             builds += F * trees * depth
             if not exact_cap:
                 beam += F * trees * max(depth - (frontier.bit_length() - 1), 0)
-    return {"tree_level_builds": builds, "tree_beam_levels": beam}
+            if frac < 1.0 and Tr._hist_via_matmul():
+                kept += F * trees * depth
+    return {"tree_level_builds": builds, "tree_beam_levels": beam,
+            "tree_kept_levels": kept}
 
 
 def _max_gbt_chain(specs) -> Optional[Dict[str, int]]:

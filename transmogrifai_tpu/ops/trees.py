@@ -26,11 +26,13 @@ per-row control flow (SURVEY §7 "Trees/GBT/XGBoost on TPU"):
   class-distribution leaves),
 - a forest grows its trees together, a chunk at a time (``grow_forest``;
   ``vmap(grow_tree)`` over bootstrap row-weights and feature masks on a
-  CPU); boosting is ``lax.scan`` over rounds — a whole RF trains as ONE
-  XLA launch and boosting compiles to a single fixed-trip loop.
+  CPU), each tree on its own kept features alone where it keeps fewer than
+  all (``kept_features``: the level tensors are k wide, not d); boosting is
+  ``lax.scan`` over rounds — a whole RF trains as ONE XLA launch and
+  boosting compiles to a single fixed-trip loop.
 
-Speeds: ``PERF.md`` (PR 29: the default selector grid on 32,768 x 760 rows,
-TPU v5e).  A comment here that gives none says "not measured".
+Speeds: ``PERF.md`` (PRs 29, 30: the default selector grid on 32,768 x 760
+rows, TPU v5e).  A comment here that gives none says "not measured".
 
 Frontier exactness: depth-wise growth is EXACT whenever every level has at
 most ``M // 2`` valid splits.  A valid split needs hessian weight
@@ -285,10 +287,12 @@ def hist_blocks(n: int, lhs_rows: int, rhs_cols: int) -> Tuple[int, int]:
     The GEMM contracts over the n rows; its operands ([lhs_rows, n] weighted
     slot one-hot, [n, rhs_cols] bin one-hot) are only ever made one row block
     at a time, inside the scan that accumulates the [lhs_rows, rhs_cols]
-    histogram.  A block's operands get a quarter of the chunk budget; blocks
-    are equal and a multiple of 128 rows, and the rows are padded up to their
-    sum with rows that sit in no slot.  A table that fits one block (Titanic)
-    is one block of exactly n rows: the whole GEMM."""
+    histogram.  Where every tree has a bin one-hot of its own (its kept
+    columns: ``_grow_level_batch``'s compacted layout) ``rhs_cols`` counts
+    the trees too, T * k * B.  A block's operands get a quarter of the chunk
+    budget; blocks are equal and a multiple of 128 rows, and the rows are
+    padded up to their sum with rows that sit in no slot.  A table that fits
+    one block (Titanic) is one block of exactly n rows: the whole GEMM."""
     cap = int(_CHUNK_BUDGET_BYTES / 4 / (4 * (lhs_rows + rhs_cols))) // 128 * 128
     nb = -(-n // max(cap, 128))
     if nb == 1:
@@ -732,40 +736,57 @@ def predict_tree(Xb, tree: Tree, max_depth: int) -> jax.Array:
 # one-hots are built [T, m, rows] (slot axis ahead of rows: no transpose
 # before the flatten) and every level runs one flat GEMM — accumulated over
 # row blocks (``hist_blocks``), so its cost in memory does not grow with n.
+# The flat GEMM needs one RHS for all trees.  A forest whose trees keep k < d
+# features of their own has none, and pays d / k times the contraction to
+# pretend it has: there the level is a tree-batched GEMM over each tree's own
+# kept columns (chip numbers: PERF.md, PR 30).
 # ---------------------------------------------------------------------------
-def _grow_level_batch(Xk, ghk, wk, feat_mask_t, nodes, leaf_val, slot_base,
+def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
                       next_free, n_active, slot_k, node_k, m: int,
                       next_cap: int, n_bins: int, reg_lambda_t, gamma_t,
                       mcw_t, mig_t, exact_cap: bool, per_tree: bool,
                       axis_name: Optional[str] = None,
                       pair_light=None, pair_hist=None,
                       want_pairs: bool = False):
-    """One breadth-first level for a BATCH of T trees (shared Xb).
+    """One breadth-first level for a BATCH of T trees.
 
     Same split math as ``_grow_level`` (see its docstring for the
     scatter/gather-free design).  Everything that has a row axis arrives cut
     into ``nb`` row blocks of ``bn`` rows (``grow_forest`` cuts them once,
-    ``hist_blocks`` sizes them): Xk i32[nb, bn, d], wk f32[nb, T, bn],
-    slot_k / node_k i32[nb, T, bn] (each row's frontier slot, -1 = resting or
-    padding, and its pool node).  Per tree: feat_mask_t f32[T, d], nodes
-    i32[T, P, 4], leaf_val f32[T, P, c], n_active i32[T], hyperparameters
-    f32[T].  Two GEMM layouts, both accumulated block by block:
+    ``hist_blocks`` sizes them): wk f32[nb, T, bn], slot_k / node_k
+    i32[nb, T, bn] (each row's frontier slot, -1 = resting or padding, and
+    its pool node), ``ghk`` f32[nb, bn, c1] where every tree sees the same
+    g/h (forests) or, ``per_tree``, f32[nb, T, bn, c1] (boosting: each batch
+    element has its own margins F).  Per tree: nodes i32[T, P, 4], leaf_val
+    f32[T, P, c], n_active i32[T], hyperparameters f32[T].
 
-    - SHARED gradients (forests: every tree of the sweep sees the same
-      g/h): ``ghk`` f32[nb, bn, c1] — LHS is the weighted slot one-hot
-      [T*m, bn], the RHS ``grad_onehot`` of the block carries the gradients.
-    - PER-TREE gradients (``per_tree``; boosting: each batch element has its
-      own margins F): ``ghk`` f32[nb, T, bn, c1] — gradients ride the LHS
-      ([T*m*c1, bn]), the RHS is the block's gradient-free ``bin_onehot``.
+    The binned matrix sets the width every level tensor has:
 
-    Three named scopes split the level in a profiler trace: ``trees.hist``
-    (the block scan), ``trees.split`` (cumsum, gain, arg-max, beam ranking,
-    node records), ``trees.route`` (the second block scan: each row's next
-    slot and node).  The segment-sum fallback stays on the vmapped
-    ``grow_tree``.
+    - SHARED, Xk int[nb, bn, d]: all d features, ``feat_t`` f32[T, d] masks
+      the ones a tree may split on (None: all).  Shared gradients ride the
+      RHS (``grad_onehot`` of the block) against the weighted slot one-hot
+      [T*m, bn]; per-tree gradients ride the LHS ([T*m*c1, bn]) against the
+      block's gradient-free ``bin_onehot``: one flat GEMM either way.
+    - COMPACTED, Xk int[nb, T, k, bn]: each tree's k kept columns, rows
+      minor, ``feat_t`` i32[T, k] their original indices, ascending.  One
+      tree-batched GEMM, [T, m*c1, bn] x [T, k*B, bn]: gradients ride the
+      LHS whoever owns them, the RHS is the bin one-hot of the tree's own
+      columns.  Histograms, split scan, carried pair histograms and routing
+      are k wide; the arg-max walks (kept column, bin) in the original
+      order, so ties still go to the lower feature and bin, and node
+      records hold ``feat_t``'s original index.
+
+    Every layout accumulates block by block.  Three named scopes split the
+    level in a profiler trace: ``trees.hist`` (the block scan),
+    ``trees.split`` (cumsum, gain, arg-max, beam ranking, node records),
+    ``trees.route`` (the second block scan: each row's next slot and node).
+    The segment-sum fallback stays on the vmapped ``grow_tree``.
     """
     B = n_bins
-    nb, bn, d = Xk.shape
+    compact = Xk.ndim == 4
+    # d: the width the level is built at (all features, or the k kept)
+    nb, bn, d = (Xk.shape[0], Xk.shape[3], Xk.shape[2]) if compact \
+        else Xk.shape
     T = wk.shape[1]
     c1 = ghk.shape[-1]
     c = c1 - 1
@@ -795,6 +816,15 @@ def _grow_level_batch(Xk, ghk, wk, feat_mask_t, nodes, leaf_val, slot_base,
         # flattening needs no transpose
         Sw = (sb[:, None, :] == hist_slot[:, :, None]).astype(jnp.float32) \
             * wb[:, None, :]                                        # [T, mh, bn]
+        if compact:
+            ghb = ghb.transpose(0, 2, 1) if per_tree else ghb.T[None]
+            lhs = (Sw[:, :, None, :] * ghb[:, None, :, :]).reshape(T, -1, bn)
+            dt = jnp.bfloat16 if _hist_bf16() else jnp.float32
+            rhs = (xb[:, :, None, :] == jnp.arange(B, dtype=xb.dtype)[
+                None, None, :, None]).astype(dt).reshape(T, d * B, bn)
+            return acc + lax.dot_general(
+                lhs.astype(dt), rhs, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=acc_dt), None    # [T, mh*c1, d*B]
         if per_tree:
             rhs = bin_onehot(xb, B)                                 # [bn, d*B]
             lhs = (Sw[:, :, None, :]
@@ -807,7 +837,8 @@ def _grow_level_batch(Xk, ghk, wk, feat_mask_t, nodes, leaf_val, slot_base,
                                      preferred_element_type=acc_dt), None
 
     with jax.named_scope("trees.hist"):
-        gemm = (T * mh * c1, d * B) if per_tree else (T * mh, c1 * d * B)
+        gemm = (T, mh * c1, d * B) if compact else \
+            (T * mh * c1, d * B) if per_tree else (T * mh, c1 * d * B)
         GH, _ = lax.scan(hist_block, jnp.zeros(gemm, acc_dt),
                          (Xk, ghk, wk, slot_k))
         # bf16 accumulation ends HERE: psum and split gains stay f32
@@ -841,7 +872,8 @@ def _grow_level_batch(Xk, ghk, wk, feat_mask_t, nodes, leaf_val, slot_base,
                / (HT + reg_lambda_t[:, None]))[:, :, None, None]
         valid = (HL >= mcw_t[:, None, None, None]) \
             & (HR >= mcw_t[:, None, None, None])
-        valid &= feat_mask_t[:, None, :, None] > 0.0
+        if not compact and feat_t is not None:
+            valid &= feat_t[:, None, :, None] > 0.0
         valid &= jnp.arange(B)[None, None, None, :] < B - 1
         gain = jnp.where(valid, gain, -jnp.inf)
         flat = gain.reshape(T, m, d * B)
@@ -866,7 +898,10 @@ def _grow_level_batch(Xk, ghk, wk, feat_mask_t, nodes, leaf_val, slot_base,
         child_idx = (k - 1) * 2
         left_pool = next_free + child_idx
         right_pool = left_pool + 1
-        rec = jnp.stack([jnp.where(do_split, bf, -1),
+        # a compacted tree records the ORIGINAL index of its kept column
+        feat = bf if not compact else jnp.where(
+            bf[:, :, None] == jnp.arange(d), feat_t[:, None, :], 0).sum(-1)
+        rec = jnp.stack([jnp.where(do_split, feat, -1),
                          jnp.where(do_split, bb, 0),
                          jnp.where(do_split, left_pool, 0),
                          jnp.where(do_split, right_pool, 0)], axis=-1)
@@ -913,22 +948,30 @@ def _grow_level_batch(Xk, ghk, wk, feat_mask_t, nodes, leaf_val, slot_base,
     # from ONE flat GEMM per block, one-hot(split feature) [T*m, d] against
     # the block's bins [bn, d] -> [T, m, bn], and the row's slot picks its
     # entry.  Every number is a small integer: exact in one bf16 pass up to
-    # 256 bins.
-    feat_sel = jax.nn.one_hot(bf, d, dtype=jnp.float32).reshape(T * m, d)
+    # 256 bins.  A compacted tree has its own k columns: the row's slot picks
+    # the split column, and a select over the k picks its bin (integers).
+    if not compact:
+        feat_sel = jax.nn.one_hot(bf, d, dtype=jnp.float32).reshape(T * m, d)
 
     def route_block(_, xs):
         xb, sb, nk = xs
         S = sb[:, None, :] == iota_m[None, :, None]                # [T, m, bn]
-        slot_bin = lax.dot_general(
-            feat_sel, xb.astype(jnp.float32),
-            (((1,), (1,)), ((), ()))).reshape(T, m, bn)
 
         def pick(per_slot):                                     # -> [T, bn]
             return jnp.where(S, per_slot[:, :, None], 0).sum(axis=1)
 
         splits_here = pick(do_split.astype(jnp.int32)) > 0
-        go_right = (jnp.where(S, slot_bin, 0.0).sum(axis=1)
-                    > pick(bb).astype(jnp.float32)).astype(jnp.int32)
+        if compact:
+            row_bin = jnp.where(
+                jnp.arange(d)[None, :, None] == pick(bf)[:, None, :],
+                xb.astype(jnp.int32), 0).sum(axis=1)
+            go_right = (row_bin > pick(bb)).astype(jnp.int32)
+        else:
+            slot_bin = lax.dot_general(
+                feat_sel, xb.astype(jnp.float32),
+                (((1,), (1,)), ((), ()))).reshape(T, m, bn)
+            go_right = (jnp.where(S, slot_bin, 0.0).sum(axis=1)
+                        > pick(bb).astype(jnp.float32)).astype(jnp.int32)
         child = pick(child_idx) + go_right
         return None, (jnp.where(splits_here, child, -1),
                       jnp.where(splits_here, next_free + child, nk))
@@ -941,27 +984,42 @@ def _grow_level_batch(Xk, ghk, wk, feat_mask_t, nodes, leaf_val, slot_base,
     return nodes, leaf_val, 2 * n_split, slot_k, node_k
 
 
-def grow_forest(Xb, g, h, w_t, feat_mask_t, max_depth: int, n_bins: int,
+def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
                 frontier: int, reg_lambda_t, gamma_t, mcw_t, mig_t,
                 exact_cap: bool = False, return_row_node: bool = False,
                 gh_t=None, axis_name: Optional[str] = None):
-    """Grow T trees together; ONE flat GEMM per level (see header note).
+    """Grow T trees together; ONE GEMM per level (see header note).
 
     Shared: Xb int[n, d].  Gradients either SHARED (g f32[n, c], h f32[n] —
     forests) or PER TREE (``gh_t`` f32[T, n, c1]; pass g/h as None —
-    boosting).  Per tree: w_t f32[T, n], feat_mask_t f32[T, d],
-    reg_lambda/gamma/mcw/mig f32[T].  The rows are cut into blocks once,
-    here, sized for the widest level (``hist_blocks``).  Falls back to
-    ``vmap(grow_tree)`` when the matmul histogram path is off (CPU).
+    boosting).  Per tree: w_t f32[T, n], reg_lambda/gamma/mcw/mig f32[T],
+    and ``feat_t``, the features a tree may split on: a mask f32[T, d], or
+    the kept features' indices i32[T, k] (``kept_features``: ascending).
+    The table's static width is what adapts the program: with k < d each
+    tree's k columns are gathered once, here, and every level is built k
+    wide (``_grow_level_batch``'s compacted layout: a forest that keeps 28
+    of 760 features never builds the other 732); with k == d, or a mask,
+    the levels are d wide over the shared matrix and nothing is gathered.
+    The rows are cut into blocks once, here, sized for the widest level
+    (``hist_blocks``).  Falls back to ``vmap(grow_tree)`` when the matmul
+    histogram path is off (CPU).  Node records hold original feature
+    indices on every path.
     Returns Tree with leading [T] axis (+ row_node on request).
     """
-    Xb = Xb.astype(jnp.int32)
     n, d = Xb.shape
     per_tree = gh_t is not None
     c = gh_t.shape[2] - 1 if per_tree else g.shape[1]
     c1 = c + 1
     T = w_t.shape[0]
+    if jnp.issubdtype(feat_t.dtype, jnp.integer):
+        feat_idx_t, feat_mask_t = feat_t, None
+    else:
+        feat_idx_t, feat_mask_t = None, feat_t
+    compact = feat_idx_t is not None and feat_idx_t.shape[1] < d
     if not _hist_via_matmul():
+        if feat_mask_t is None:  # the segment-sum grower reads a mask
+            feat_mask_t = (feat_idx_t[:, :, None] == jnp.arange(d)).any(
+                axis=1).astype(jnp.float32)
         if not per_tree:
             def one(wt, fm, lam, gam, mcw, mig):
                 return grow_tree(Xb, g, h, wt, fm, max_depth, n_bins,
@@ -1010,8 +1068,12 @@ def grow_forest(Xb, g, h, w_t, feat_mask_t, max_depth: int, n_bins: int,
     # row blocks, sized for the widest level's operands
     mh = min(M, 1 << (max_depth - 1))
     mh = max(mh // 2, 1) if sub else mh
-    nb, bn = (hist_blocks(n, T * mh * c1, d * n_bins) if per_tree
-              else hist_blocks(n, T * mh, c1 * d * n_bins))
+    if compact:
+        k = feat_idx_t.shape[1]
+        nb, bn = hist_blocks(n, T * mh * c1, T * k * n_bins)
+    else:
+        nb, bn = (hist_blocks(n, T * mh * c1, d * n_bins) if per_tree
+                  else hist_blocks(n, T * mh, c1 * d * n_bins))
 
     def blocks(a, axis: int, fill=0):
         """[.., n, ..] -> [nb, .., bn, ..]: pad the row axis, cut it, and
@@ -1022,7 +1084,10 @@ def grow_forest(Xb, g, h, w_t, feat_mask_t, max_depth: int, n_bins: int,
         a = a.reshape(a.shape[:axis] + (nb, bn) + a.shape[axis + 1:])
         return jnp.moveaxis(a, axis, 0)
 
-    Xk = blocks(Xb, 0)
+    # each tree's kept columns, gathered once for all its levels: whole rows
+    # of the transposed matrix, [T, k, n] at the binned dtype's width
+    Xk = blocks(jnp.take(Xb.T, feat_idx_t, axis=0), 2) if compact \
+        else blocks(Xb.astype(jnp.int32), 0)
     wk = blocks(w_t, 1)
     ghk = (blocks(gh_t, 1) if per_tree
            else blocks(jnp.concatenate([g, h[:, None]], axis=1), 0))
@@ -1032,8 +1097,9 @@ def grow_forest(Xb, g, h, w_t, feat_mask_t, max_depth: int, n_bins: int,
 
     def level(state, slot_base, next_free, m, next_cap, want_pairs):
         return _grow_level_batch(
-            Xk, ghk, wk, feat_mask_t, state[0], state[1], slot_base,
-            next_free, *state[2:5], m=m, next_cap=next_cap, n_bins=n_bins,
+            Xk, ghk, wk, feat_idx_t if compact else feat_mask_t, state[0],
+            state[1], slot_base, next_free, *state[2:5], m=m,
+            next_cap=next_cap, n_bins=n_bins,
             reg_lambda_t=reg_lambda_t, gamma_t=gamma_t, mcw_t=mcw_t,
             mig_t=mig_t, exact_cap=exact_cap, per_tree=per_tree,
             axis_name=axis_name,
@@ -1067,8 +1133,9 @@ def fit_forest(Xb, g, h, w_trees, feat_masks, max_depth: int, n_bins: int,
                exact_cap: bool = False) -> Tree:
     """Train all trees of a forest in one launch.
 
-    w_trees: f32[T, n] bootstrap weights; feat_masks: f32[T, d].
-    Returns Tree with leading tree axis.
+    w_trees: f32[T, n] bootstrap weights; feat_masks: f32[T, d], or the
+    kept features' indices i32[T, k] (``kept_features``; see
+    ``grow_forest``).  Returns Tree with leading tree axis.
     """
 
     T = w_trees.shape[0]
@@ -1090,17 +1157,27 @@ def predict_forest(Xb, forest: Tree, max_depth: int) -> jax.Array:
 
 def forest_chunk_size(max_depth: int, n_bins: int, d: int, c: int,
                       frontier: int, budget_bytes: float = _CHUNK_BUDGET_BYTES,
-                      n_rows: int = 0) -> int:
+                      n_rows: int = 0, n_kept: Optional[int] = None) -> int:
     """Trees per chunk so one chunk's level tensors fit the budget.
 
-    A level materializes G [M, d, B, c] + cumsums per tree (x3 covers the
+    A level materializes G [M, k, B, c] + cumsums per tree (x3 covers the
     cumsum/gain temporaries); with histogram subtraction on, the carried
     parent pair histograms add about half a level's histograms (the 0.5
-    bump).  Of the rows a tree keeps its weights, slots and nodes (the
-    ``3 * n_rows`` term): the [M, rows] slot one-hot exists one row block at
-    a time and has its own quarter of the budget (``hist_blocks``)."""
+    bump).  ``k`` is the width the levels are built at: ``n_kept`` where the
+    forest is grown on its kept features (``grow_forest`` with an index
+    table, on the matmul path), else all ``d`` — the segment-sum grower
+    builds every tree's histograms full width whatever it keeps.  Of the
+    rows a tree keeps its weights, slots and nodes (the ``3 * n_rows`` term)
+    and, compacted, its k columns of the binned matrix: the [M, rows] slot
+    one-hot exists one row block at a time and has its own quarter of the
+    budget (``hist_blocks``)."""
     hist_factor = 3.5 if _hist_subtract() else 3.0
-    per_tree = (frontier * n_bins * d * (c + 1) * hist_factor + 3 * n_rows) * 4
+    k = d
+    if n_kept is not None and n_kept < d and _hist_via_matmul():
+        k = n_kept
+    per_tree = (frontier * n_bins * k * (c + 1) * hist_factor + 3 * n_rows) * 4
+    if k < d:
+        per_tree += n_rows * k * np.dtype(_bin_dtype(n_bins)).itemsize
     return max(1, int(budget_bytes / max(per_tree, 1)))
 
 
@@ -1124,14 +1201,14 @@ def fit_forest_chunked(Xb, g, h, w_trees, feat_masks, mcw_trees, max_depth: int,
     """Train an arbitrary tree population with bounded memory: ``lax.map``
     over chunks of ``chunk`` vmapped trees — one compile, sequential chunks.
 
-    The tree axis TT (a multiple of ``chunk``; callers pad with zero-weight
-    trees) may interleave folds x grid candidates x bootstrap replicas —
-    per-tree ``mcw_trees``/``mig_trees`` carry the grid's min-child-weight
-    and min-info-gain, so a whole RF fold x grid sweep is a single launch
-    (SURVEY §2.7 axis 2).
+    ``feat_masks`` is f32[TT, d] masks or i32[TT, k] kept-feature indices
+    (``grow_forest``).  The tree axis TT (a multiple of ``chunk``; callers
+    pad with zero-weight trees) may interleave folds x grid candidates x
+    bootstrap replicas — per-tree ``mcw_trees``/``mig_trees`` carry the
+    grid's min-child-weight and min-info-gain, so a whole RF fold x grid
+    sweep is a single launch (SURVEY §2.7 axis 2).
     """
     n = Xb.shape[0]
-    d = Xb.shape[1]
     if mig_trees is None:
         mig_trees = jnp.zeros_like(mcw_trees)
 
@@ -1144,7 +1221,8 @@ def fit_forest_chunked(Xb, g, h, w_trees, feat_masks, mcw_trees, max_depth: int,
                            mig_t=migs, exact_cap=exact_cap)
 
     trees = lax.map(one_chunk, (w_trees.reshape(-1, chunk, n),
-                                feat_masks.reshape(-1, chunk, d),
+                                feat_masks.reshape(-1, chunk,
+                                                   feat_masks.shape[1]),
                                 mcw_trees.reshape(-1, chunk),
                                 mig_trees.reshape(-1, chunk)))
     return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), trees)
@@ -1471,15 +1549,31 @@ def bootstrap_weights(key, n: int, n_trees: int, bootstrap: bool = True,
     return jax.random.poisson(key, rate, (n_trees, n)).astype(jnp.float32)
 
 
+def n_kept(d: int, frac: float) -> int:
+    """Features a tree keeps of ``d`` at subset fraction ``frac`` (static)."""
+    return d if frac >= 1.0 else min(d, max(1, int(round(frac * d))))
+
+
 def feature_masks(key, d: int, n_trees: int, frac: float) -> jax.Array:
     """Per-tree feature-subset masks (featureSubsetStrategy / colsample):
-    exactly k features per tree via a random-key threshold.  Traceable."""
+    exactly ``n_kept(d, frac)`` features per tree, those with the k smallest
+    of one uniform draw per feature; of two draws that tie at the k-th place
+    the lower feature index is kept (never k + 1).  Traceable."""
     if frac >= 1.0:
         return jnp.ones((n_trees, d), jnp.float32)
-    k = max(1, int(round(frac * d)))
     r = jax.random.uniform(key, (n_trees, d))
-    thresh = jnp.sort(r, axis=1)[:, k - 1: k]
-    return (r <= thresh).astype(jnp.float32)
+    rank = jnp.argsort(jnp.argsort(r, axis=1, stable=True), axis=1)
+    return (rank < n_kept(d, frac)).astype(jnp.float32)
+
+
+def kept_features(key, d: int, n_trees: int, frac: float) -> jax.Array:
+    """i32[T, k], k = ``n_kept(d, frac)``: the original indices of the
+    features each tree's ``feature_masks`` draw keeps, ascending — the table
+    ``grow_forest`` grows a tree's compacted feature axis from; its static
+    width is the width the levels are built at.  Traceable."""
+    masks = feature_masks(key, d, n_trees, frac)
+    cols = jnp.where(masks > 0, jnp.arange(d, dtype=jnp.int32), d)
+    return jnp.sort(cols, axis=1)[:, :n_kept(d, frac)]
 
 
 def subsample_weights(key, n: int, n_rounds: int, frac: float) -> jax.Array:
