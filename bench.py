@@ -820,11 +820,6 @@ def main():
             or sweep_stats["gbt_chain_levels"])
         out["gbt_chain_levels"] = sweep_stats["gbt_chain_levels"]
         out["gbt_chain_steps"] = sweep_stats["gbt_chain_steps"]
-    bf = acct.get("bf16_hist") or {}
-    if bf.get("levels"):
-        out["bf16_hist_per_rep"] = {
-            "levels": round(bf["levels"] / reps),
-            "bytes_saved": round(bf["bytes_saved"] / reps)}
     hs = acct.get("hist_subtracted") or {}
     if hs.get("levels"):
         out["hist_subtracted_per_rep"] = {

@@ -48,6 +48,31 @@ def pytest_sessionfinish(session, exitstatus):
         pass  # telemetry must never fail the suite
 
 
+def _cut_binary_space(lr=3, rf_every=6, xgb=2, trees=10, rounds=20,
+                      max_depth=12):
+    """The default binary space (LR 8 + RF 18 + XGB 2) cut for a test that
+    RUNS a sweep on a few hundred rows: the first ``lr`` logistic points,
+    every ``rf_every``-th forest point (6: one of each depth 3 / 6 / 12; 3:
+    two) up to ``max_depth``, the first ``xgb`` boosted points — every
+    family, depth and fragment of the full grid — with ``trees`` trees a
+    forest and ``rounds`` boosting rounds.  What launching, sharding,
+    hedging or checkpointing does with a candidate does not depend on how
+    long it trains; the whole grid is walked by ``chip_smoke.py``."""
+    from transmogrifai_tpu.impl.selector.defaults import default_binary_space
+
+    (lr_est, lr_grid), (rf_est, rf_grid), (xgb_est, xgb_grid) = \
+        default_binary_space()
+    return [(lr_est, lr_grid[:lr]),
+            (rf_est, [dict(g, num_trees=trees) for g in rf_grid[::rf_every]
+                      if g["max_depth"] <= max_depth]),
+            (xgb_est, [dict(g, num_round=rounds) for g in xgb_grid[:xgb]])]
+
+
+@pytest.fixture(scope="session")
+def cut_binary_space():
+    return _cut_binary_space
+
+
 @pytest.fixture(scope="session")
 def titanic_df():
     if os.path.exists(TITANIC_CSV):

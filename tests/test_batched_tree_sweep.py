@@ -111,6 +111,7 @@ def test_frontier_bound_uses_actual_weight_sum():
 def test_zero_reg_lambda_leaves_finite(data):
     """reg_lambda=0 used to 0/0-NaN dead frontier slots and poison every
     child leaf through the packing matmul (round-4 ADVICE)."""
+    import jax
     import jax.numpy as jnp
 
     from transmogrifai_tpu.ops import trees as Tr
@@ -119,8 +120,8 @@ def test_zero_reg_lambda_leaves_finite(data):
     n, d = X.shape
     Xb, _ = Tr.quantize(X, 16)
     g = -np.asarray(y, np.float32)[:, None]
-    tree = Tr.grow_tree(jnp.asarray(Xb), jnp.asarray(g),
-                        jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32),
-                        jnp.ones(d, jnp.float32), max_depth=4, n_bins=16,
-                        frontier=16, reg_lambda=0.0)
+    tree = jax.jit(lambda xb, gg: Tr.grow_tree(
+        xb, gg, jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32),
+        jnp.ones(d, jnp.float32), max_depth=4, n_bins=16, frontier=16,
+        reg_lambda=0.0))(jnp.asarray(Xb), jnp.asarray(g))
     assert bool(jnp.isfinite(tree.leaf_val).all())

@@ -44,24 +44,32 @@ def _build(n, shift):
                                  ("y", T.RealNN, ys), response="y")
 
 
-def _workflow(ds, features):
+def _workflow(ds, features, grid):
     x, cat, y = features
     feats = VectorsCombiner().set_input(
         RealVectorizer().set_input(x).get_output(),
         OneHotVectorizer(top_k=5, min_support=1).set_input(cat).get_output(),
     ).get_output()
     sel = BinaryClassificationModelSelector.with_cross_validation(
-        num_folds=2, splitter=None)
+        num_folds=2, splitter=None, models_and_parameters=grid)
     pred = sel.set_input(y, feats).get_output()
     return OpWorkflow().set_input_dataset(ds).set_result_features(pred)
 
 
 @pytest.fixture(scope="module")
-def champion():
+def grid(cut_binary_space):
+    """The default binary space cut where pruning does not look: every
+    family and every axis of its grid (LR 8, RF 12, XGB 2), with fewer
+    trees and rounds and without the depth-12 forests."""
+    return cut_binary_space(lr=8, rf_every=1, max_depth=6)
+
+
+@pytest.fixture(scope="module")
+def champion(grid):
     """(model, full_grid_size): one cold full-sweep champion on era A,
     shared by the pruning / rollback / closed-loop tests."""
     ds, feats = _build(N, 0.0)
-    wf = _workflow(ds, feats)
+    wf = _workflow(ds, feats, grid)
     sel = next(s for s in wf.stages if getattr(s, "is_model_selector", False))
     full = sum(len(g) for _, g in sel.models)
     return wf.train(), full
@@ -219,12 +227,12 @@ def test_gate_counts_land_in_the_continual_scope():
 # ---------------------------------------------------------------------------
 # warm-start pruning parity
 # ---------------------------------------------------------------------------
-def test_warm_start_pruning_parity(champion):
+def test_warm_start_pruning_parity(champion, grid):
     model, full = champion
     summary = incumbent_summary(model)
     assert summary is not None and summary.best_model_type
     ds, feats = _build(N, 0.0)
-    wf = _workflow(ds, feats)
+    wf = _workflow(ds, feats, grid)
     sel = next(s for s in wf.stages if getattr(s, "is_model_selector", False))
     sel.warm_start(summary, explore=1)
     pruned, full2 = sel.validator.warm_start_counts
@@ -271,7 +279,7 @@ def test_rollback_policy_thresholds(champion):
 # ---------------------------------------------------------------------------
 # the closed loop, end to end
 # ---------------------------------------------------------------------------
-def test_e2e_closed_loop(champion, tmp_path, monkeypatch):
+def test_e2e_closed_loop(champion, grid, tmp_path, monkeypatch):
     model, full = champion
     tele = tmp_path / "telemetry.jsonl"
     monkeypatch.setenv("TMOG_TELEMETRY", str(tele))
@@ -301,7 +309,7 @@ def test_e2e_closed_loop(champion, tmp_path, monkeypatch):
     ds_b, feats_b = _build(N, shift)
     loop = ContinualLoop(
         registry, metrics,
-        workflow_factory=lambda ds: _workflow(ds, feats_b),
+        workflow_factory=lambda ds: _workflow(ds, feats_b, grid),
         window_provider=lambda: ds_b,
         evaluator=Evaluators.BinaryClassification.auPR(),
         controller=RetrainController(ControllerConfig(

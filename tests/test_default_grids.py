@@ -63,6 +63,7 @@ def test_grid_axes_match_reference_values():
 def test_min_info_gain_prunes_weak_splits():
     """A huge per-row info-gain threshold must yield a stump-free tree while
     threshold 0 splits; and the default fit path must accept the param."""
+    import jax
     import jax.numpy as jnp
 
     from transmogrifai_tpu.ops import trees as Tr
@@ -78,11 +79,13 @@ def test_min_info_gain_prunes_weak_splits():
     w = np.ones(n, np.float32)
     fm = np.ones(d, np.float32)
 
+    grow = jax.jit(lambda mig: Tr.grow_tree(
+        jnp.asarray(Xb), jnp.asarray(g), jnp.asarray(h), jnp.asarray(w),
+        jnp.asarray(fm), max_depth=3, n_bins=32, frontier=8,
+        min_info_gain=mig))
+
     def n_splits(mig):
-        tree = Tr.grow_tree(jnp.asarray(Xb), jnp.asarray(g), jnp.asarray(h),
-                            jnp.asarray(w), jnp.asarray(fm), max_depth=3,
-                            n_bins=32, frontier=8, min_info_gain=mig)
-        return int((np.asarray(tree.split_feat) >= 0).sum())
+        return int((np.asarray(grow(mig).split_feat) >= 0).sum())
 
     assert n_splits(0.0) > 0
     assert n_splits(1e9) == 0

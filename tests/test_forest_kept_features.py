@@ -1,11 +1,11 @@
 """A forest grown on its kept features alone (``ops/trees.grow_forest`` handed
-``kept_features``' index table: the matmul path's compacted layout) against
-the masked full-width ``segment_sum`` growth the CPU keeps: the same trees,
-node for node, with original feature indices in every record.  A forest's
-histograms are sums of integers (0/-1 gradients, unit hessians, Poisson
-weights), so the leaves are compared bit for bit.  Then the sizes that follow
-from the kept width (``forest_chunk_size``, ``hist_blocks``) and the sweep's
-``tree_kept_levels`` counter."""
+``kept_features``' index table: the compacted layout, a tree-batched GEMM k
+wide) against the same forest grown full width under the draw's masks (the
+flat GEMM, d wide): the same trees, node for node, with original feature
+indices in every record.  A forest's histograms are sums of integers (0/-1
+gradients, unit hessians, Poisson weights), so the leaves are compared bit for
+bit.  Then the sizes that follow from the kept width (``forest_chunk_size``,
+``hist_blocks``) and the sweep's ``tree_kept_levels`` counter."""
 import numpy as np
 import pytest
 
@@ -35,10 +35,14 @@ def _draws():
 
 
 def _grow(Xb, g, w, feat, depth: int, frontier: int):
-    tree, node = Tr.grow_forest(
-        Xb, g, jnp.ones(N), w, feat, depth, BINS, frontier,
+    # a new jit a call: traced again, so TMOG_HIST_SUBTRACT and a patched
+    # ``hist_blocks`` apply, and compiled whole (eager growth dispatches, and
+    # compiles, every op of every level by itself)
+    tree, node = jax.jit(lambda xb, gg, ww, ft: Tr.grow_forest(
+        xb, gg, jnp.ones(N), ww, ft, depth, BINS, frontier,
         reg_lambda_t=jnp.full(T, 1e-6), gamma_t=jnp.zeros(T),
-        mcw_t=jnp.full(T, 4.0), mig_t=jnp.full(T, 1e-3), return_row_node=True)
+        mcw_t=jnp.full(T, 4.0), mig_t=jnp.full(T, 1e-3),
+        return_row_node=True))(Xb, g, w, feat)
     return jax.tree.map(np.asarray, tree), np.asarray(node)
 
 
@@ -47,15 +51,13 @@ def _grow(Xb, g, w, feat, depth: int, frontier: int):
 @pytest.mark.parametrize("classes", [2, 3])
 @pytest.mark.parametrize("depth,frontier", [(3, 8), (6, 64), (6, 8)],
                          ids=["depth3", "depth6", "beam"])
-def test_compacted_growth_equals_masked_segment_sum(monkeypatch, depth, frontier,
-                                                    classes, blocks, subtract):
+def test_compacted_growth_equals_masked_full_width(monkeypatch, depth, frontier,
+                                                   classes, blocks, subtract):
     monkeypatch.setenv("TMOG_HIST_SUBTRACT", subtract)
     Xb, g = _table(classes)
     w, masks, kept = _draws()
     assert kept.shape == (T, 5)
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "0")
     want, want_node = _grow(Xb, g, w, masks, depth, frontier)
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "1")
     if blocks == "four_blocks":  # 512 > 421: the last block is mostly padding
         monkeypatch.setattr(Tr, "hist_blocks", lambda n, lhs, rhs: (4, 128))
     got, got_node = _grow(Xb, g, w, kept, depth, frontier)
@@ -70,8 +72,7 @@ def test_compacted_growth_equals_masked_segment_sum(monkeypatch, depth, frontier
         assert set(used) <= set(kept[t])
 
 
-def test_tie_of_two_identical_kept_columns_goes_to_the_lower_index(monkeypatch):
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "1")
+def test_tie_of_two_identical_kept_columns_goes_to_the_lower_index():
     Xb, g = _table(2)
     Xb = Xb.at[:, 9].set(Xb[:, 0])      # column 9 is column 0 again
     w, _, _ = _draws()
@@ -129,19 +130,17 @@ def _gathers_of_the_binned_matrix(width: int) -> int:
     return count(closed.jaxpr)
 
 
-def test_every_feature_kept_lowers_with_no_gather(monkeypatch):
-    """k == d is today's full-width program: nothing gathers from the binned
-    matrix.  k < d gathers each tree's columns once, outside the levels."""
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "1")
+def test_every_feature_kept_lowers_with_no_gather():
+    """k == d is the full-width program, on the CPU too: nothing gathers from
+    the binned matrix.  k < d gathers each tree's columns once, outside the
+    levels."""
     assert _gathers_of_the_binned_matrix(D) == 0
     assert _gathers_of_the_binned_matrix(5) == 1
 
 
-def test_chunks_are_sized_from_the_kept_width(monkeypatch):
+def test_chunks_are_sized_from_the_kept_width():
     """The trees cell's depth-12 forests (frontier 256, 32 bins, 32,768 x 760,
-    28 kept): 17 trees a chunk full width, >= 300 on the kept features — and
-    full width still where ``segment_sum`` builds the histograms."""
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "1")
+    28 kept): 17 trees a chunk full width, >= 300 on the kept features."""
     shape = dict(max_depth=12, n_bins=32, d=760, c=1, frontier=256, n_rows=32768)
     assert Tr.forest_chunk_size(**shape) == 17
     assert Tr.forest_chunk_size(**shape, n_kept=760) == 17
@@ -150,15 +149,12 @@ def test_chunks_are_sized_from_the_kept_width(monkeypatch):
     assert Tr.balanced_chunk(900, chunk) == 300       # 3 chunks, not 53
     assert Tr.forest_chunk_size(**dict(shape, max_depth=6, frontier=64),
                                 n_kept=28) >= 900     # depth 6: one chunk
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "0")
-    assert Tr.forest_chunk_size(**shape, n_kept=28) == 17
 
 
 def test_row_blocks_of_the_tree_batched_gemm_fit_their_budget(monkeypatch):
     """``grow_forest`` hands ``hist_blocks`` operand sizes that hold the tree
     axis on both sides, T * m * c1 and T * k * B; at the cell's sizes a
     block's operands stay within a quarter of the chunk budget."""
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "1")
     seen = []
     whole = Tr.hist_blocks
     monkeypatch.setattr(Tr, "hist_blocks",
@@ -193,31 +189,27 @@ def _plan(space, n: int = 240, d: int = 16):
     return plan, train_w
 
 
-def test_default_grid_counts_its_forest_levels_as_kept(monkeypatch):
+def test_default_grid_counts_its_forest_levels_as_kept():
     """LR 8 + RF 18 + XGB 2 at 3 folds: 900 forest trees of each of depth 3,
     6, 12 on their kept features, boosting's 12,000 levels full width."""
     from transmogrifai_tpu.impl.selector.defaults import default_binary_space
     from transmogrifai_tpu.ops import sweep
 
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "1")
     plan, _ = _plan(default_binary_space())
     levels = sweep._spec_tree_levels(plan.spec, 3)
     assert levels["tree_level_builds"] == 30_900
     assert levels["tree_kept_levels"] == 900 * (3 + 6 + 12) == 18_900
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "0")   # segment_sum compacts nothing
-    assert sweep._spec_tree_levels(plan.spec, 3)["tree_kept_levels"] == 0
 
 
 @pytest.mark.parametrize("strategy,kept", [("auto", 3 * 2 * 3 * (2 + 3)), ("all", 0)])
-def test_run_stats_counts_kept_levels_of_a_launch(monkeypatch, strategy, kept):
-    """A launch through the fused sweep on the matmul path: two candidates of
+def test_run_stats_counts_kept_levels_of_a_launch(strategy, kept):
+    """A launch through the fused sweep: two candidates of
     3 trees, depth 2 and 3, 3 folds — every level kept with sqrt(d) features
     a tree, none with all of them; the scores are finite either way."""
     from transmogrifai_tpu.impl.classification.trees import (
         OpRandomForestClassifier)
     from transmogrifai_tpu.ops import sweep
 
-    monkeypatch.setenv("TMOG_HIST_MATMUL", "1")
     rf = OpRandomForestClassifier(num_trees=3, feature_subset_strategy=strategy)
     grid = [{"max_depth": 2, "min_instances_per_node": 1},
             {"max_depth": 2, "min_instances_per_node": 5},

@@ -1,16 +1,16 @@
 """Parity of histogram subtraction (TMOG_HIST_SUBTRACT) vs direct builds.
 
 Subtraction derives each heavy sibling's histogram as ``parent - light``
-instead of rebuilding it from rows (ops/trees._grow_level).  The sums are
+instead of rebuilding it from rows (ops/trees._grow_level_batch).  The sums are
 mathematically identical; f32 rounding differs (a subtraction rounds once
 where the direct build rounds per row), so split decisions must match
 everywhere except exactly-tied gains, and sweep METRICS must match to
 float tolerance.  These tests pin both directions of the flag.
 
 jit caching caveat: the env flag is read at TRACE time, so flag-flip
-tests either go through the unjitted entry points (``grow_tree``,
-``_gbt_impl`` — retraced per call) or clear jax + sweep AOT caches
-between runs.  Flipping the env without that would silently compare a
+tests either wrap the unjitted entry points (``grow_tree``, ``_gbt_impl``)
+in a new ``jax.jit`` a call (retraced per call) or clear jax + sweep AOT
+caches between runs.  Flipping the env without that would silently compare a
 cached program against itself.
 """
 import numpy as np
@@ -31,22 +31,23 @@ def _fixture(seed=0, n=400, d=6):
 
 
 def _grow(Xb, y, wt, fm):
-    # grow_tree is unjitted: every call re-traces, so the env flag applies
-    return Tr.grow_tree(jnp.asarray(Xb), jnp.asarray(-y[:, None]),
-                        jnp.ones(len(y)), jnp.asarray(wt), jnp.asarray(fm),
-                        max_depth=5, n_bins=16, frontier=16,
-                        min_child_weight=5.0)
+    # a new jit a call: traced again, so the env flag applies
+    return jax.jit(lambda xb, g, w: Tr.grow_tree(
+        xb, g, jnp.ones(len(y)), w, jnp.asarray(fm), max_depth=5, n_bins=16,
+        frontier=16, min_child_weight=5.0))(
+            jnp.asarray(Xb), jnp.asarray(-y[:, None]), jnp.asarray(wt))
 
 
-@pytest.mark.parametrize("matmul", ["0", "1"],
-                         ids=["segment_path", "matmul_path"])
-def test_grow_tree_subtract_parity(monkeypatch, matmul):
+@pytest.mark.parametrize("layout", ["shared", "compacted"])
+def test_grow_tree_subtract_parity(monkeypatch, layout):
+    """Both layouts of the level: all d features under a mask, and the
+    tree's k < d kept columns alone (carried pair histograms k wide)."""
     Xb, y = _fixture()
     n, d = Xb.shape
     kb, _ = Tr.rng_keys(0)
     wt = np.asarray(Tr.bootstrap_weights(kb, n, 1))[0]
-    fm = np.ones(d, np.float32)
-    monkeypatch.setenv("TMOG_HIST_MATMUL", matmul)
+    fm = np.ones(d, np.float32) if layout == "shared" \
+        else np.asarray([0, 2, 3, 5], np.int32)
 
     monkeypatch.setenv("TMOG_HIST_SUBTRACT", "0")
     t0 = _grow(Xb, y, wt, fm)
@@ -69,10 +70,10 @@ def test_gbt_margins_parity(monkeypatch):
     fms = Tr.feature_masks(kf, d, R, 1.0)
 
     def fit():
-        # unjitted impl: re-traced per call so the env flip is honored
-        _, F = Tr._gbt_impl(jnp.asarray(Xb), jnp.asarray(y), jnp.ones(n),
-                            rw, fms, "logistic", R, 3, 16, 8,
-                            0.3, 1.0, 0.0, 1.0, 0.0, 1)
+        # a new jit a call: re-traced so the env flip is honored
+        _, F = jax.jit(lambda xb: Tr._gbt_impl(
+            xb, jnp.asarray(y), jnp.ones(n), rw, fms, "logistic", R, 3, 16,
+            8, 0.3, 1.0, 0.0, 1.0, 0.0, 1))(jnp.asarray(Xb))
         return np.asarray(F)
 
     monkeypatch.setenv("TMOG_HIST_SUBTRACT", "0")

@@ -195,8 +195,14 @@ class TestSelectedModelCombiner:
 
         s1 = BinaryClassificationModelSelector.with_cross_validation(
             num_folds=2, seed=1, model_types=["OpLogisticRegression"])
+        # a forest selector over two shallow candidates: what is combined is
+        # two selectors' winners, whatever grid each searched
+        from transmogrifai_tpu.impl.classification.trees import (
+            OpRandomForestClassifier)
         s2 = BinaryClassificationModelSelector.with_cross_validation(
-            num_folds=2, seed=2, model_types=["OpRandomForestClassifier"])
+            num_folds=2, seed=2, models_and_parameters=[
+                (OpRandomForestClassifier(num_trees=10),
+                 [{"max_depth": 3}, {"max_depth": 5}])])
         p1 = s1.set_input(lbl, vec).get_output()
         p2 = s2.set_input(lbl, vec).get_output()
         combined = SelectedModelCombiner(

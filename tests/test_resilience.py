@@ -304,14 +304,9 @@ def test_gbt_kill_and_resume_bit_identical(tmp_path):
 # ---------------------------------------------------------------------------
 # sweep resume: second run skips the completed work, metrics identical
 # ---------------------------------------------------------------------------
-def _tiny_sweep_plan():
+def _tiny_sweep_plan(cut_binary_space):
     from transmogrifai_tpu.evaluators.classification import \
         OpBinaryClassificationEvaluator
-    from transmogrifai_tpu.impl.classification.logistic import \
-        OpLogisticRegression
-    from transmogrifai_tpu.impl.classification.trees import (
-        OpRandomForestClassifier, OpXGBoostClassifier)
-    from transmogrifai_tpu.impl.selector import defaults as D
     from transmogrifai_tpu.impl.sweep_fragments import build_sweep_plan
     from transmogrifai_tpu.impl.tuning.validators import OpCrossValidation
 
@@ -323,20 +318,19 @@ def _tiny_sweep_plan():
     ev = OpBinaryClassificationEvaluator()
     cv = OpCrossValidation(ev, num_folds=F, seed=7, mesh=None)
     train_w, val_mask = cv.make_folds(n, None)
-    plan = build_sweep_plan([
-        (OpLogisticRegression(max_iter=50), D.logistic_regression_grid()),
-        (OpRandomForestClassifier(), D.random_forest_grid()),
-        (OpXGBoostClassifier(), D.xgboost_grid()),
-    ], X, y, train_w, ev)
-    assert plan is not None
+    # one candidate of each family and depth of the default grid, fewer
+    # trees and rounds: a checkpointed launch skips whole, whatever its size
+    plan = build_sweep_plan(cut_binary_space(lr=2, xgb=1), X, y, train_w, ev)
+    assert plan is not None and len(plan.spec[2]) == 6
     return plan, train_w, val_mask
 
 
-def test_sweep_checkpoint_resume_identical_metrics(tmp_path, monkeypatch):
+def test_sweep_checkpoint_resume_identical_metrics(tmp_path, monkeypatch,
+                                                   cut_binary_space):
     from transmogrifai_tpu.ops import sweep as sweep_ops
 
     monkeypatch.setenv("TMOG_CHECKPOINT_DIR", str(tmp_path))
-    plan, train_w, val_mask = _tiny_sweep_plan()
+    plan, train_w, val_mask = _tiny_sweep_plan(cut_binary_space)
     sweep_ops.reset_run_stats()
     m1 = np.asarray(plan.run(train_w, val_mask))
     st1 = sweep_ops.run_stats()

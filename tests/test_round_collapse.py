@@ -7,7 +7,11 @@ K=1 is exactly the reference scan; K>1 trades per-tree gradient freshness
 for a K-times-shorter sequential chain, so parity vs K=1 is pinned at
 METRIC level with a documented tolerance, while everything K does NOT
 touch (LR/RF candidates, the stored-tree/predict contract, the batch
-kernel vs the single kernel) is pinned exactly.
+kernel vs the single kernel) is pinned at what float32 allows: the level
+histogram is a GEMM, whose order of summation follows its shape and its
+fusion, so two differently shaped launches of one fit (eager against
+jitted, B x K trees a step against K) agree node for node and to 1e-6 in
+the margins, not bit for bit.
 """
 import numpy as np
 import pytest
@@ -68,20 +72,23 @@ def test_collapse_one_is_exactly_the_reference_scan():
     n = len(y)
 
     def fit(k):
-        _, F = Tr._gbt_impl(jnp.asarray(Xb), jnp.asarray(y), jnp.ones(n),
+        return Tr._gbt_impl(jnp.asarray(Xb), jnp.asarray(y), jnp.ones(n),
                             rw, fms, "logistic", 8, 3, 16, 8,
                             0.3, 1.0, 0.0, 1.0, 0.0, 1, trees_per_round=k)
-        return np.asarray(F)
 
-    np.testing.assert_array_equal(fit(1), fit(1))  # determinism baseline
-    # K=1 goes through the same generalized code path; it must be the
-    # identical program, not a close one
-    np.testing.assert_array_equal(
-        fit(1),
-        np.asarray(Tr.fit_gbt(jnp.asarray(Xb), jnp.asarray(y), jnp.ones(n),
+    trees, F = fit(1)
+    np.testing.assert_array_equal(np.asarray(F), np.asarray(fit(1)[1]))
+    # K=1 goes through the same generalized code path (eager here, one
+    # jitted program there): the same trees, node for node
+    trees_j, F_j = Tr.fit_gbt(jnp.asarray(Xb), jnp.asarray(y), jnp.ones(n),
                               rw, fms, loss="logistic", n_rounds=8,
-                              max_depth=3, n_bins=16, frontier=8,
-                              eta=0.3)[1]))
+                              max_depth=3, n_bins=16, frontier=8, eta=0.3)
+    assert (np.asarray(trees.split_feat) >= 0).sum() > 8
+    for field in ("split_feat", "split_bin", "left", "right"):
+        np.testing.assert_array_equal(np.asarray(getattr(trees, field)),
+                                      np.asarray(getattr(trees_j, field)))
+    np.testing.assert_allclose(np.asarray(F), np.asarray(F_j), rtol=0,
+                               atol=1e-6)
 
 
 def test_batch_kernel_matches_single_kernel_at_k4():
@@ -97,9 +104,11 @@ def test_batch_kernel_matches_single_kernel_at_k4():
         jnp.asarray(Xb), jnp.asarray(y), jnp.ones((B, n)), rw, fms,
         "logistic", 8, 3, 16, 8, 0.3 * ones, ones, 0.0 * ones, ones,
         base_score_b=0.0 * ones, trees_per_round=K)
-    for b in range(B):
-        np.testing.assert_array_equal(np.asarray(F_batch[b]),
-                                      np.asarray(F_single))
+    # two candidates with the same hyperparameters, one launch: identical
+    np.testing.assert_array_equal(np.asarray(F_batch[0]),
+                                  np.asarray(F_batch[1]))
+    np.testing.assert_allclose(np.asarray(F_batch[0]), np.asarray(F_single),
+                               rtol=0, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -4,8 +4,9 @@ Acceptance contract of the row-sharded path (ops/sweep.run_sweep_rowsharded
 + parallel/mesh collectives + validator routing):
 
 - row-sharded metrics match the single-device fused launch to <= 1e-6 on
-  the FULL 28-candidate default grid at (2,1), (2,4) and (4,2) virtual-CPU
-  meshes (conftest forces ``--xla_force_host_platform_device_count=8``) —
+  the default grid's families and depths (one candidate of each: the
+  whole-grid walk is ``chip_smoke.py --chips 4``'s) at (2,1), (2,4) and
+  (4,2) virtual-CPU meshes (conftest forces ``--xla_force_host_platform_device_count=8``) —
   on-device RNG draws happen at the ORIGINAL row count and are sliced per
   shard, so bootstrap/subsample streams match the replicated launch
   draw-for-draw.  Histogram subtraction (an orthogonal approximation) is
@@ -34,7 +35,6 @@ from transmogrifai_tpu.impl.classification.trees import (
     OpRandomForestClassifier, OpXGBoostClassifier)
 from transmogrifai_tpu.impl.regression.linear import OpLinearRegression
 from transmogrifai_tpu.impl.regression.trees import OpRandomForestRegressor
-from transmogrifai_tpu.impl.selector import defaults as D
 from transmogrifai_tpu.impl.sweep_fragments import build_sweep_plan
 from transmogrifai_tpu.impl.tuning.validators import OpCrossValidation
 from transmogrifai_tpu.ops import sweep as sweep_ops
@@ -71,17 +71,14 @@ def _direct_histograms():
     jax.clear_caches()
 
 
-def _default_candidates():
-    """The reference default sweep: LR 8 + RF 18 + XGB 2 = 28 candidates."""
-    return [
-        (OpLogisticRegression(max_iter=50), D.logistic_regression_grid()),
-        (OpRandomForestClassifier(), D.random_forest_grid()),
-        (OpXGBoostClassifier(), D.xgboost_grid()),
-    ]
+N_CANDIDATES = 8
 
 
 @pytest.fixture(scope="module")
-def default_plan():
+def default_plan(cut_binary_space):
+    """The reference default sweep cut to one candidate of each family and
+    depth (LR 3 + RF depth 3 / 6 / 12 + XGB 2), with fewer trees and rounds:
+    the same fragments, psums and RNG slices as the 28-candidate grid."""
     rng = np.random.default_rng(0)
     n, d, F = 240, 12, 3
     X = np.ascontiguousarray(rng.normal(size=(n, d)).astype(np.float32))
@@ -90,8 +87,10 @@ def default_plan():
     ev = OpBinaryClassificationEvaluator()
     cv = OpCrossValidation(ev, num_folds=F, seed=7, mesh=None)
     train_w, val_mask = cv.make_folds(n, None)
-    plan = build_sweep_plan(_default_candidates(), X, y, train_w, ev)
-    assert plan is not None and len(plan.spec[2]) == 28
+    candidates = cut_binary_space(rounds=40)
+    assert sorted({g["max_depth"] for g in candidates[1][1]}) == [3, 6, 12]
+    plan = build_sweep_plan(candidates, X, y, train_w, ev)
+    assert plan is not None and len(plan.spec[2]) == N_CANDIDATES
     return plan, train_w, val_mask
 
 
@@ -106,7 +105,8 @@ def single_ref(default_plan):
 def test_rowsharded_parity_full_default_grid(default_plan, single_ref,
                                              n_data, n_model):
     """The acceptance bar: row-sharded == single-device fused to 1e-6 on
-    the full default grid, with honest launch telemetry."""
+    every family and depth of the default grid, with honest launch
+    telemetry."""
     plan, train_w, val_mask = default_plan
     assert len(jax.devices()) >= n_data * n_model, \
         "conftest must force 8 virtual CPU devices"
@@ -120,7 +120,7 @@ def test_rowsharded_parity_full_default_grid(default_plan, single_ref,
     launch = stats["launches"][-1]
     assert launch["rowsharded"] is True
     assert launch["shards"] == n_model
-    assert sum(s["candidates"] for s in launch["per_shard"]) == 28
+    assert sum(s["candidates"] for s in launch["per_shard"]) == N_CANDIDATES
     # one row shard per chip: every model column spans n_data devices
     for s in launch["per_shard"]:
         assert len(s["devices"]) == n_data

@@ -8,8 +8,8 @@ ops/sweep.run_sweep_partitioned):
   impl/sweep_fragments.spec_units),
 - an 8-shard sweep over the virtual CPU devices (conftest forces
   ``--xla_force_host_platform_device_count=8``) returns metrics identical
-  to the 1-shard fused launch to 1e-6 for the FULL 28-candidate default
-  grid — candidate-granular splits reuse the same device RNG draws
+  to the 1-shard fused launch to 1e-6 for every family and depth of the
+  default grid (two candidates of each) — candidate-granular splits reuse the same device RNG draws
   (ops/trees.rng_keys is keyed by seed, not group width), so the split is
   numerically invisible,
 - ``_fused_sweep`` no longer bails out when ``model_shards() > 1``: a
@@ -43,8 +43,7 @@ def _default_candidates():
     ]
 
 
-@pytest.fixture(scope="module")
-def default_plan():
+def _plan(candidates, n_candidates):
     rng = np.random.default_rng(0)
     n, d, F = 240, 12, 3
     X = np.ascontiguousarray(rng.normal(size=(n, d)).astype(np.float32))
@@ -53,9 +52,23 @@ def default_plan():
     ev = OpBinaryClassificationEvaluator()
     cv = OpCrossValidation(ev, num_folds=F, seed=7, mesh=None)
     train_w, val_mask = cv.make_folds(n, None)
-    plan = build_sweep_plan(_default_candidates(), X, y, train_w, ev)
-    assert plan is not None and len(plan.spec[2]) == 28
+    plan = build_sweep_plan(candidates, X, y, train_w, ev)
+    assert plan is not None and len(plan.spec[2]) == n_candidates
     return plan, train_w, val_mask, F
+
+
+@pytest.fixture(scope="module")
+def default_plan():
+    """The 28-candidate grid: what the static partition tests balance."""
+    return _plan(_default_candidates(), 28)
+
+
+@pytest.fixture(scope="module")
+def parity_plan(cut_binary_space):
+    """What runs: two candidates of each family and depth of the default
+    grid (LR 4 + RF depth 3 / 6 / 12 x 2 + XGB 2 = 12, so the 8 shards
+    split forest and boosted groups between them), fewer trees and rounds."""
+    return _plan(cut_binary_space(lr=4, rf_every=3, rounds=40), 12)
 
 
 def test_balance_bound_default_grid(default_plan):
@@ -101,9 +114,9 @@ def test_tiny_grid_drops_empty_shards():
     assert sorted(ci for s in shards for ci in s.cis) == [0, 1]
 
 
-def test_8_shard_parity_full_default_grid(default_plan):
+def test_8_shard_parity_full_default_grid(parity_plan):
     """The acceptance bar: 8-shard partitioned == 1-shard fused to 1e-6."""
-    plan, train_w, val_mask, _F = default_plan
+    plan, train_w, val_mask, _F = parity_plan
     devs = jax.devices()
     assert len(devs) >= 8, "conftest must force 8 virtual CPU devices"
     m1 = plan.run(train_w, val_mask)
@@ -115,7 +128,7 @@ def test_8_shard_parity_full_default_grid(default_plan):
     assert stats["sweep_shards"] == 8
     launch = stats["launches"][-1]
     assert len(launch["per_shard"]) == 8
-    assert sum(s["candidates"] for s in launch["per_shard"]) == 28
+    assert sum(s["candidates"] for s in launch["per_shard"]) == 12
     # steady state: every per-shard program must come from the AOT cache
     sweep_ops.reset_run_stats()
     m8b = plan.run_sharded(train_w, val_mask, devs[:8])

@@ -1,6 +1,5 @@
 """MFU-gap levers: candidate-packed launches + cross-device GBT pipelining
-+ bf16 histogram accumulation (TMOG_SWEEP_PACK / TMOG_GBT_PIPELINE /
-TMOG_BF16_HIST).
+(TMOG_SWEEP_PACK / TMOG_GBT_PIPELINE).
 
 Acceptance contract:
 
@@ -15,9 +14,6 @@ Acceptance contract:
   a WARM pipelined launch reports ``gbt_chain_eff`` with strictly fewer
   effective sequential levels than the full dependency chain (floored at
   ``ceil(levels / n_shards)``);
-- bf16 G/H accumulation moves tree metrics only within a pinned
-  tolerance and leaves non-histogram families (LR) bit-identical, with
-  the halved histogram traffic booked under ``flops.bf16_hist_totals``;
 - launch-count telemetry is honest: ``sweep_pack_count`` equals the
   launches the FLOP ledger saw dispatched, ``launches_avoided`` counts
   against the one-launch-per-candidate baseline;
@@ -54,12 +50,8 @@ from transmogrifai_tpu.parallel.spec_partition import (launch_packs,
                                                        set_cost_provider)
 from transmogrifai_tpu.utils import flops
 
-KNOBS = ("TMOG_SWEEP_PACK", "TMOG_GBT_PIPELINE", "TMOG_BF16_HIST",
+KNOBS = ("TMOG_SWEEP_PACK", "TMOG_GBT_PIPELINE",
          "TMOG_PACK_HBM_MB", "TMOG_PACK_COST_BUDGET")
-
-#: bf16 G/H accumulation moves boosted/forest metrics by rounding only —
-#: measured ~2e-3 max on the fixture grid; LR stays bit-identical
-BF16_METRIC_ATOL = 0.05
 
 
 def _clear():
@@ -109,31 +101,6 @@ def small_plan():
     train_w, val_mask = cv.make_folds(n, None)
     plan = build_sweep_plan(_candidates(), X, y, train_w, ev)
     assert plan is not None and len(plan.spec[2]) == 8
-    return plan, train_w, val_mask, F
-
-
-@pytest.fixture(scope="module")
-def bf16_plan():
-    """Separate fixture for the bf16 parity test: on the tiny n=200 grid a
-    bf16-rounded split gain flips a tree split (a discrete metric jump, not
-    accumulation noise); this n=256 grid keeps every split decision stable
-    so the diff measures rounding only (~2e-3 max)."""
-    rng = np.random.default_rng(7)
-    n, d, F = 256, 8, 3
-    X = np.ascontiguousarray(rng.normal(size=(n, d)).astype(np.float32))
-    y = (X @ rng.normal(size=d) + 0.5 * rng.normal(size=n) > 0
-         ).astype(np.float32)
-    ev = OpBinaryClassificationEvaluator()
-    cv = OpCrossValidation(ev, num_folds=F, seed=7, mesh=None)
-    train_w, val_mask = cv.make_folds(n, None)
-    plan = build_sweep_plan([
-        (OpLogisticRegression(max_iter=30),
-         [{"reg_param": 0.01}, {"reg_param": 0.1}]),
-        (OpRandomForestClassifier(), [{"num_trees": 6, "max_depth": 4}]),
-        (OpXGBoostClassifier(),
-         [{"num_round": 8, "max_depth": 3, "eta": 0.3}]),
-    ], X, y, train_w, ev)
-    assert plan is not None and len(plan.spec[2]) == 4
     return plan, train_w, val_mask, F
 
 
@@ -343,33 +310,6 @@ def test_rowsharded_pack_bit_exact(small_plan, monkeypatch):
     assert st["launches_avoided"] >= 1        # P>1 map beats one-per-cand
     feats = [s["feat"] for s in entry["per_shard"] if s.get("feat")]
     assert feats and any(f["pack_size"] > 1.0 for f in feats)
-
-
-# ---------------------------------------------------------------------------
-# bf16 histogram accumulation: pinned parity + bytes accounting
-# ---------------------------------------------------------------------------
-def test_bf16_hist_parity_and_accounting(bf16_plan, monkeypatch):
-    plan, tw, vm, _ = bf16_plan
-    _clear()
-    flops.enable()
-    flops.reset()
-    try:
-        m32 = np.asarray(plan.run(tw, vm))
-        assert flops.bf16_hist_totals()["levels"] == 0.0   # knob off: no rows
-        monkeypatch.setenv("TMOG_BF16_HIST", "1")
-        _clear()
-        flops.reset()
-        m16 = np.asarray(plan.run(tw, vm))
-        bf = flops.bf16_hist_totals()
-    finally:
-        flops.disable()
-    # LR has no histograms: bf16 accumulation must not touch it
-    np.testing.assert_array_equal(m16[:, :2], m32[:, :2])
-    # forest/boosting metrics move by accumulation rounding only
-    np.testing.assert_allclose(m16, m32, atol=BF16_METRIC_ATOL)
-    assert bf["levels"] > 0                    # histogram builds ran bf16
-    assert bf["bytes_saved"] > 0               # halved G/H traffic booked
-    assert flops.totals()["bf16_hist"] == bf
 
 
 # ---------------------------------------------------------------------------

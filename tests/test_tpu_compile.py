@@ -9,8 +9,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only the test worker that is handed this file loads the TPU library, and it
 compiles in its own process with jax's persistent cache switched off (an
 entry compiled for an absent chip cannot be read back).  ``jax.devices()``
-is still the CPU here, so the TPU-only histogram branch is steered with the
-repo's ``TMOG_HIST_MATMUL`` knob, read at trace time.
+is still the CPU here; the tree kernels have one formulation for every
+backend, so what is lowered here is what a TPU traces.
 
 Time: about two minutes in all on this sandbox's 8 cores, not the one
 minute asked for — the programs of this repo are whole sweeps, not
@@ -23,7 +23,7 @@ metric kernel again, which costs ~30 s at any shape and is inside phase A's
 program; the row-sharded program keeps the linear and boosting fragments of
 a model column (a full column of the default grid compiles for ~50 s) — the
 same ``mesh_psum`` / ``mesh_all_gather`` call sites, the histogram psum
-inside ``_grow_level`` included — and leaves the forests to phase A.
+inside ``_grow_level_batch`` included — and leaves the forests to phase A.
 """
 import os
 import re
@@ -56,16 +56,11 @@ def topo():
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    mp = pytest.MonkeyPatch()
-    mp.setenv("TMOG_HIST_MATMUL", "1")  # the branch a TPU takes by itself
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    jax.clear_caches()  # the knob is read at trace time
     yield desc
-    mp.undo()
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
-    jax.clear_caches()
 
 
 @pytest.fixture(scope="module")
@@ -270,13 +265,13 @@ def test_streamed_chunk_program(scale_features, one_chip):
 
 
 # ---------------------------------------------------------------------------
-# The level-histogram build of ops/trees.py, both formulations
+# The level-histogram build of ops/trees.py, both layouts
 # ---------------------------------------------------------------------------
-N_BINS, SLOTS, CHANNELS = 32, 64, 2  # 32 = the MLlib maxBins default
+N_BINS, CHANNELS = 32, 2  # 32 = the MLlib maxBins default
 
 
 def test_histogram_blocked_at_the_trees_cell_rows(scale_features, one_chip):
-    """The row-blocked one-hot GEMM (the one path a TPU takes, for every n) at
+    """The row-blocked one-hot GEMM (the one path there is, for every n) at
     the ``scale-500-trees`` cell's 32,768 sweep rows and phase B's width:
     three levels of a 17-tree chunk, several row blocks a level."""
     import jax.numpy as jnp
@@ -285,7 +280,6 @@ def test_histogram_blocked_at_the_trees_cell_rows(scale_features, one_chip):
 
     d = scale_features["width"]
     n, T = 32768, 17
-    assert Tr._hist_via_matmul()
     assert Tr.hist_blocks(n, T * 2, CHANNELS * d * N_BINS)[0] > 1
 
     def grow(Xb, y, w, fm):
@@ -337,19 +331,3 @@ def test_histogram_compacted_at_the_trees_cell_rows(scale_features, one_chip):
     assert "trees.hist" in text and "trees.route" in text
     assert "segment" not in text  # histograms ride dot ops
     assert f"s8[{T},{k}," in text  # the kept columns stay one byte a cell
-
-
-def test_histogram_segment_sum_at_phase_b_width(scale_features, one_chip):
-    """The scatter formulation (what a CPU runs, and ``TMOG_HIST_MATMUL=0``
-    forces): phase B's sweep rows x vector width."""
-    from transmogrifai_tpu.ops import trees as Tr
-
-    d = scale_features["width"]
-    n = chip_smoke.phase_b_sweep_rows(chip_smoke.PHASE_B_ROWS)
-    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    compiled = jax.jit(
-        lambda Xb, ghw, slot: Tr._level_histograms(Xb, ghw, slot, SLOTS,
-                                                   N_BINS)).lower(
-        S((n, d), np.int8), S((n, CHANNELS), np.float32),
-        S((n,), np.int32)).compile()
-    _fits(compiled)

@@ -5,8 +5,7 @@ Consolidates the former ``profile_trees.py`` / ``profile_trees2.py`` /
 
 - ``trees``        — the RF depth/frontier/chunk matrix + GBT batch cases at
   the Titanic hot shapes (n=891, d=24, 32 bins), mean-of-reps timing;
-- ``trees-beam``   — the histogram-precision (TMOG_HIST_BF16) and frontier-
-  beam variants at depth 12;
+- ``trees-beam``   — the frontier-beam width variants at depth 12;
 - ``trees-stats``  — min/median timing of the three sweep-representative RF
   cases + the GBT batch case (noise-robust numbers for before/after diffs);
 - ``trace``        — one warmed depth-12 forest build under
@@ -106,16 +105,8 @@ def rf_runner(TT, depth, frontier, chunk):
     return run
 
 
-def rf_case(timer, TT, depth, frontier, chunk, label, reps, env=None):
-    if env:
-        for k, v in env.items():
-            os.environ[k] = v
-    try:
-        return timer(rf_runner(TT, depth, frontier, chunk), label, reps)
-    finally:
-        if env:
-            for k in env:
-                os.environ.pop(k)
+def rf_case(timer, TT, depth, frontier, chunk, label, reps):
+    return timer(rf_runner(TT, depth, frontier, chunk), label, reps)
 
 
 def gbt_runner(n_rounds=200, max_depth=10, frontier=64, B=6):
@@ -149,8 +140,6 @@ def cmd_trees(reps):
             reps)
     rf_case(timed_mean, 900, 12, 128, 300, "RF d=12 M=128 chunk=300", reps)
     rf_case(timed_mean, 896, 12, 128, 128, "RF d=12 M=128 chunk=128", reps)
-    rf_case(timed_mean, 900, 12, 128, 900, "RF d=12 segsum one chunk", reps,
-            env={"TMOG_HIST_MATMUL": "0"})
     timed_mean(gbt_runner(n_rounds=200),
                "XGB batch=6 rounds=200 d=10 M=64", reps)
     timed_mean(gbt_runner(n_rounds=20),
@@ -158,10 +147,8 @@ def cmd_trees(reps):
 
 
 def cmd_trees_beam(reps):
-    """Histogram precision (bf16 vs f32) and frontier-beam width variants."""
-    rf_case(timed_mean, 900, 12, 128, 900, "RF d=12 M=128 (bf16 mm)", reps)
-    rf_case(timed_mean, 900, 12, 128, 900, "RF d=12 M=128 f32 mm", reps,
-            env={"TMOG_HIST_BF16": "0"})
+    """Frontier-beam width variants at depth 12."""
+    rf_case(timed_mean, 900, 12, 128, 900, "RF d=12 M=128", reps)
     rf_case(timed_mean, 900, 12, 64, 900, "RF d=12 M=64 beam", reps)
     rf_case(timed_mean, 900, 12, 32, 900, "RF d=12 M=32 beam", reps)
     rf_case(timed_mean, 900, 8, 128, 900, "RF d=8 M=128", reps)

@@ -130,13 +130,6 @@ def _print_pack_telemetry(sweep_ops) -> dict:
         print(f"gbt pipeline: {eff['levels']} effective sequential levels "
               f"(overlap~{out['gbt_overlap_fraction']:.0%}; "
               "TMOG_GBT_PIPELINE=0 disables)")
-    from transmogrifai_tpu.utils import flops
-    bf = flops.bf16_hist_totals()
-    if bf.get("levels"):
-        print(f"bf16 hist: {int(bf['levels'])} accumulations halved, "
-              f"~{int(bf['bytes_saved']):,} hist bytes avoided "
-              "(TMOG_BF16_HIST=1 enables)")
-        out["bf16_hist_bytes_saved"] = int(bf["bytes_saved"])
     return out
 
 

@@ -594,8 +594,7 @@ def _trace_knobs() -> Tuple:
     of silently reusing the other configuration's executable (the jit
     paths still need ``jax.clear_caches()``; see
     tests/test_hist_subtract_parity.py)."""
-    return (Tr._hist_subtract(), Tr._hist_bf16(), Tr._bf16_hist_acc(),
-            _sweep_pack())
+    return (Tr._hist_subtract(), _sweep_pack())
 
 
 #: kernel trace events (hist-subtraction savings) per (spec, n_rows).  jit
@@ -609,12 +608,11 @@ _TRACE_EVENT_CACHE: Dict[Tuple, Tuple] = {}
 
 
 def _replay_trace_events(spec, n: int, colls) -> None:
-    # keyed on the trace-shaping flags too: flipping TMOG_HIST_SUBTRACT /
-    # TMOG_BF16_HIST mid-process must not replay the other
-    # configuration's savings
-    key = (spec, int(n), Tr._hist_subtract(), Tr._bf16_hist_acc())
+    # keyed on the trace-shaping flag too: flipping TMOG_HIST_SUBTRACT
+    # mid-process must not replay the other configuration's savings
+    key = (spec, int(n), Tr._hist_subtract())
     events = tuple(c for c in colls
-                   if c[0] in ("hist_subtracted", "gbt_chain", "bf16_hist"))
+                   if c[0] in ("hist_subtracted", "gbt_chain"))
     if events:
         _TRACE_EVENT_CACHE[key] = events
     else:
@@ -937,8 +935,8 @@ def _spec_tree_levels(spec, F: int) -> Dict[str, int]:
     ``tree_beam_levels`` (those at which a full frontier ranked its splits
     by gain and kept half: ``frontier`` slots, not provably enough) and
     ``tree_kept_levels`` (those built on a compacted feature axis, the
-    tree's kept features alone: forests with a subset fraction under 1, on
-    the matmul histogram path; boosting builds full width)."""
+    tree's kept features alone: forests with a subset fraction under 1;
+    boosting builds full width)."""
     builds = beam = kept = 0
     for frag in spec[1]:
         if frag[0] == "forest":
@@ -953,7 +951,7 @@ def _spec_tree_levels(spec, F: int) -> Dict[str, int]:
             builds += F * trees * depth
             if not exact_cap:
                 beam += F * trees * max(depth - (frontier.bit_length() - 1), 0)
-            if frac < 1.0 and Tr._hist_via_matmul():
+            if frac < 1.0:
                 kept += F * trees * depth
     return {"tree_level_builds": builds, "tree_beam_levels": beam,
             "tree_kept_levels": kept}
@@ -1791,7 +1789,7 @@ def run_sweep_rowsharded(shards, X, xbs: Tuple, y, train_w, val_w,
                                                  F),
                        shard=j, device=label)
         for kind, axis, nbytes in colls:
-            if kind in ("hist_subtracted", "gbt_chain", "bf16_hist"):
+            if kind in ("hist_subtracted", "gbt_chain"):
                 continue  # kernel trace events, not mesh traffic
             agg = coll_agg.setdefault(axis, {"count": 0.0, "bytes": 0.0})
             agg["count"] += 1

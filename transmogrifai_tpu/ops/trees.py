@@ -13,22 +13,22 @@ per-row control flow (SURVEY §7 "Trees/GBT/XGBoost on TPU"):
   levels run in ONE ``lax.fori_loop`` body with a fixed ``M``-slot frontier —
   so compile cost is independent of depth and per-level memory/compute is
   capped at ``M * d * B`` instead of ``2^depth * d * B``,
-- per level the (slot, feature, bin) gradient histograms are built, on a
-  TPU, as a one-hot GEMM accumulated over row blocks (``grow_forest`` /
-  ``_grow_level_batch``: one path for every row count) and, on a CPU, with
-  ``segment_sum`` (one scatter per feature, vmapped: ``grow_tree``); the
-  best split per slot is a pure cumsum/argmax reduction,
-- rows carry a frontier-slot id; the level update is a gather + compare
-  (CPU) or a small GEMM + compare per row block (TPU),
+- per level the (slot, feature, bin) gradient histograms are built as a
+  one-hot GEMM accumulated over row blocks (``grow_forest`` /
+  ``_grow_level_batch``: one program for every row count and backend, so
+  the program the tests run is the one the chip runs); the best split per
+  slot is a pure cumsum/argmax reduction,
+- rows carry a frontier-slot id; the level update is a small GEMM or a
+  select, and a compare, per row block,
 - second-order (g, h) statistics make the same builder serve XGBoost-style
   boosting (Newton leaves), RF regression (g = -y: variance gain, mean
   leaves), and RF classification (g = -onehot(y): gini-equivalent gain,
   class-distribution leaves),
 - a forest grows its trees together, a chunk at a time (``grow_forest``;
-  ``vmap(grow_tree)`` over bootstrap row-weights and feature masks on a
-  CPU), each tree on its own kept features alone where it keeps fewer than
-  all (``kept_features``: the level tensors are k wide, not d); boosting is
-  ``lax.scan`` over rounds — a whole RF trains as ONE XLA launch and
+  ``grow_tree`` is its chunk of one), each tree on its own kept features
+  alone where it keeps fewer than all (``kept_features``: the level tensors
+  are k wide, not d); boosting is ``lax.scan`` over rounds, each round a
+  forest of its candidates' trees — a whole RF trains as ONE XLA launch and
   boosting compiles to a single fixed-trip loop.
 
 Speeds: ``PERF.md`` (PRs 29, 30: the default selector grid on 32,768 x 760
@@ -146,7 +146,7 @@ def _next_pow2(x: int) -> int:
 def frontier_cap(n: int, max_depth: int, min_child_weight: float = 1.0,
                  h_max: float = 1.0, max_frontier: int = 512,
                  total_weight: float = None) -> int:
-    """Frontier slots M for ``grow_tree`` (static; power of two).
+    """Frontier slots M for ``grow_forest`` (static; power of two).
 
     At most ``H_total / (2 * mcw)`` nodes can validly split per level
     (children need hessian weight >= mcw each), so a frontier of
@@ -176,7 +176,7 @@ def _pool_size(max_depth: int, frontier: int) -> int:
     loop level t >= log2(M) occupies [M - 1 + (t - L)*M, ...+M).  Every level
     claims its full block whether or not all slots split — offsets are then
     independent of the tree, so the batched node/leaf writes stay single
-    vectorized ops under vmap instead of serializing per tree.
+    vectorized ops over the tree axis instead of serializing per tree.
     """
     if max_depth <= 0:
         return 1
@@ -204,26 +204,6 @@ def frontier_is_exact(n: int, max_depth: int, min_child_weight: float,
 # ---------------------------------------------------------------------------
 # Tree growth
 # ---------------------------------------------------------------------------
-def _hist_bf16() -> bool:
-    """bf16 inputs for the histogram matmul (f32 accumulation).
-
-    Exact for RF (one-hot entries, 0/-1 gradients and small-int bootstrap
-    weights are all bf16-representable); boosted gradients round to ~3
-    decimal digits, which only perturbs near-tie split choices.
-    TMOG_HIST_BF16=0/1 forces either way (parity tests force 0).
-    """
-    import os
-
-    force = os.environ.get("TMOG_HIST_BF16")
-    if force is not None and force != "":
-        return force == "1"
-    # not measured on the chip.  Read from the program lowered for a v5e
-    # (PR 29): at the default matmul precision the compiler already rounds
-    # both float32 operands to bfloat16 inside the fusion that makes them
-    # (the bin one-hot even stays pred), so off and on run the same GEMM.
-    return False
-
-
 def _hist_subtract() -> bool:
     """Parent-minus-child histogram subtraction (the XGBoost/LightGBM trick).
 
@@ -246,29 +226,7 @@ def _hist_subtract() -> bool:
     return True
 
 
-def _hist_via_matmul() -> bool:
-    """Pick the histogram formulation (static, at trace time).
-
-    TPU: the one-hot-matmul formulation, for every n — the (slot, feature,
-    bin) reduction as a GEMM on the MXU, accumulated over row blocks so that
-    neither one-hot is ever held whole (``hist_blocks``).  With the scatter
-    formulation (one ``segment_sum`` per feature, level and tree) the fused
-    launch of the default grid at 32,768 x 760 rows was refused by a v5e for
-    one 25.5 GB tensor, every forest of the per-family fallback likewise,
-    and the selector fit had not ended after 10 minutes (PERF.md, PR 29).
-    CPU keeps ``segment_sum`` — scalar scatters are cheap there and the
-    one-hot is pure overhead.  TMOG_HIST_MATMUL=0/1 forces either path
-    (parity tests).
-    """
-    import os
-
-    force = os.environ.get("TMOG_HIST_MATMUL")
-    if force is not None and force != "":
-        return force == "1"
-    return jax.default_backend() == "tpu"
-
-
-#: precision of the batch grower's selections by matmul (a 0/1 selector
+#: precision of the level grower's selections by matmul (a 0/1 selector
 #: against histogram sums, split statistics or leaf values): the TPU's default
 #: rounds the selected float32 numbers to bfloat16 — a parent histogram of
 #: thousands of rows, a leaf's p(1) — which the chip runs of PR 29 read as
@@ -301,411 +259,26 @@ def hist_blocks(n: int, lhs_rows: int, rhs_cols: int) -> Tuple[int, int]:
     return nb, -(-rows // 128) * 128
 
 
-def _bf16_hist_acc() -> bool:
-    """bf16 G/H histogram ACCUMULATION (``TMOG_BF16_HIST``, default off).
-
-    Distinct from ``_hist_bf16`` (TMOG_HIST_BF16), which casts the matmul
-    INPUTS to bf16 while still accumulating in f32: this knob makes the
-    accumulator itself bf16 (``preferred_element_type=bfloat16`` on the
-    level GEMMs / bf16 ``segment_sum``), halving the histogram HBM traffic
-    — the dominant memory stream of a level build.  Histograms are cast
-    back to f32 IMMEDIATELY after the build, before the data-axis psum and
-    all split-gain arithmetic, so cross-device reductions and gain math
-    stay f32; only the per-bin accumulation rounds (~8-bit mantissa).
-    Split choices can flip on near-ties; sweep-metric parity is pinned in
-    tests/test_sweep_pack.py.  Each level build emits a ``bf16_hist``
-    trace event carrying the bytes saved vs f32 (utils/flops bucket).
-    """
-    from ..utils.env import env_flag
-
-    return env_flag("TMOG_BF16_HIST", False)
-
-
 def bin_onehot(Xb, n_bins: int) -> jax.Array:
-    """Gradient-FREE histogram RHS: [n, d*B] with entry (r, j*B + b) =
-    1[bin(r, j) == b].  Depends only on the binned matrix, so boosting
-    builds it ONCE per launch (the gradient-carrying ``grad_onehot`` must be
-    rebuilt every round); per-tree gradients then ride the LHS of the level
-    GEMM (see ``_grow_level_batch``'s gh_t path).  Honors the same
-    ``_hist_bf16`` knob as ``grad_onehot`` (0/1 entries are bf16-exact)."""
+    """Gradient-FREE histogram RHS of one row block: [n, d*B] with entry
+    (r, j*B + b) = 1[bin(r, j) == b].  Per-tree gradients (boosting) ride
+    the LHS of the level GEMM against it (``_hist_gemm``)."""
     n, d = Xb.shape
-    dt = jnp.bfloat16 if _hist_bf16() else jnp.float32
-    oh = jax.nn.one_hot(Xb.astype(jnp.int32), n_bins, dtype=dt)
+    oh = jax.nn.one_hot(Xb.astype(jnp.int32), n_bins, dtype=jnp.float32)
     return oh.reshape(n, -1)
 
 
 def grad_onehot(Xb, gh, n_bins: int) -> jax.Array:
-    """Shared RHS of the level-histogram matmul: [n, c1*d*B] where entry
-    (r, c*d*B + j*B + b) = gh[r, c] * 1[bin(r, j) == b].
-
-    Built ONCE per launch (gradients are constant across a forest's levels;
-    per boosting round for GBT) and contracted against the per-level
-    weighted slot one-hot — row weights live on the slot side, so this
-    tensor is shared by every tree of a vmapped forest."""
+    """Shared RHS of the level-histogram GEMM for one row block:
+    [n, c1*d*B] where entry (r, c*d*B + j*B + b) = gh[r, c] * 1[bin(r, j)
+    == b], contracted against the weighted slot one-hot — row weights live
+    on the slot side, so this tensor is shared by every tree of a forest."""
     n, d = Xb.shape
-    dt = jnp.bfloat16 if _hist_bf16() else jnp.float32
     # one select, not a product with a stored one-hot: the [n, d, B] one-hot
     # would be written and read once more than this tensor is
     hit = Xb.astype(jnp.int32)[:, None, :, None] == jnp.arange(n_bins)
-    og = jnp.where(hit, gh.astype(dt)[:, :, None, None], 0)      # [n, c1, d, B]
+    og = jnp.where(hit, gh.astype(jnp.float32)[:, :, None, None], 0)  # [n,c1,d,B]
     return og.reshape(n, -1)
-
-
-def _level_histograms_mm(Og, S, w, m: int, n_bins: int, d: int, c1: int):
-    """MXU histogram build: G [m, c, d, B], H [m, d, B] via ONE matmul.
-
-    S = one_hot(row_slot) [n, m] (slot -1 -> all-zero row, i.e. resting
-    rows drop out); row weights fold into S here so ``Og`` stays shared;
-    GH = (S*w)^T @ Og — a single [m, n] x [n, c1*d*B] contraction instead
-    of d scatters.  Accumulation is always f32 (preferred_element_type);
-    the bins axis stays minor so no tensor has a 2-wide lane dimension.
-    """
-    Sw = S * w.astype(S.dtype)[:, None]
-    acc_dt = jnp.bfloat16 if _bf16_hist_acc() else jnp.float32
-    if acc_dt == jnp.bfloat16:
-        record_trace_event("bf16_hist", "mm", 2 * m * c1 * d * n_bins)
-    GH = lax.dot_general(Sw.astype(Og.dtype), Og, (((0,), (0,)), ((), ())),
-                         preferred_element_type=acc_dt)          # [m, c1*d*B]
-    GH = GH.astype(jnp.float32).reshape(m, c1, d, n_bins)
-    return GH[:, :c1 - 1], GH[:, c1 - 1]
-
-
-def _level_histograms(Xb, ghw, row_slot, m: int, n_bins: int):
-    """Per-(slot, feature, bin) stats: G [m, c, d, B], H [m, d, B].
-
-    ghw: f32[n, c+1] — weighted gradients with the weighted hessian as the
-    last channel, so G and H come out of ONE scatter per feature.
-    row_slot: i32[n] in [0, m) or -1 (resting at a leaf -> overflow bucket).
-    """
-    B = n_bins
-    d = Xb.shape[1]
-    dead = row_slot < 0
-    base = jnp.where(dead, m * B, row_slot * B)
-    if _bf16_hist_acc():
-        record_trace_event("bf16_hist", "segment",
-                           2 * m * ghw.shape[1] * d * B)
-        ghw = ghw.astype(jnp.bfloat16)
-
-    def per_feature(bins_j):
-        seg = base + jnp.where(dead, 0, bins_j)
-        return jax.ops.segment_sum(ghw, seg, num_segments=m * B + 1)[:-1]
-
-    GH = jax.vmap(per_feature, in_axes=1,
-                  out_axes=0)(Xb).astype(jnp.float32)      # [d, m*B, c+1]
-    c = ghw.shape[1] - 1
-    GH = GH.reshape(d, m, B, c + 1).transpose(1, 3, 0, 2)  # [m, c1, d, B]
-    return GH[:, :c], GH[:, c]
-
-
-def _grow_level(Xb, gh, w, feat_mask, nodes, leaf_val, slot_base, next_free,
-                n_active, row_slot, row_node, m: int, next_cap: int,
-                n_bins: int, reg_lambda, gamma, min_child_weight,
-                min_info_gain=0.0, Og=None, exact_cap: bool = False,
-                axis_name: Optional[str] = None, pair_light=None,
-                pair_hist=None, want_pairs: bool = False):
-    """One breadth-first level over an ``m``-slot frontier.
-
-    SCATTER/GATHER-FREE by design: XLA TPU lowers batched scatters and
-    per-element gathers to near-serial loops (~10 ms per level at 900 trees
-    x 891 rows, measured), so every per-row lookup of per-slot data rides an
-    MXU matmul against the slot one-hot ``S``, node records land with ONE
-    ``dynamic_update_slice`` per level (the frontier occupies the static
-    pool block ``[slot_base, slot_base + m)`` — see ``_pool_size``; offsets
-    are tree-independent so the batched write stays one vectorized op),
-    children pack into ``[next_free, next_free + 2k)`` via tiny selection
-    matmuls (no argsort), and the next frontier needs no materialized map —
-    slot j of the next level IS pool id ``next_free + j``.
-
-    ``nodes`` is the packed i32[P, 4] pool (feat, bin, left, right);
-    ``leaf_val`` f32[P, c]; ``n_active`` the live width of the frontier
-    (slots beyond it are dead); ``slot_base``/``next_free`` are scalars
-    uniform across a vmapped batch (python ints or loop-index affine).
-    Returns (nodes', leaf_val', n_active', row_slot', row_node').  ``m`` and
-    ``next_cap`` are static; when ``next_cap < 2*m`` the level keeps only
-    the top ``next_cap // 2`` splits by gain — unless ``exact_cap`` says the
-    frontier provably cannot overflow, where a count clamp replaces the
-    sorts.  ``Og`` (shared gradient one-hot) selects the MXU matmul
-    histogram build.  A node's leaf value is written once, when the node is
-    created (root at init).  ``row_node`` tracks each row's current pool
-    node so boosting can read final leaf values without a predict walk.
-
-    Histogram subtraction (``_hist_subtract``): with ``pair_hist``
-    f32[m/2, c+1, d, B] (the parent slots' histograms, packed at sibling-
-    pair positions by the PREVIOUS level) and ``pair_light`` f32[m/2]
-    (1.0 = the lighter child sits in the even/left slot), histograms are
-    built only for the light child of each pair; the heavy sibling is
-    ``parent - light`` AFTER the data-axis psum.  ``want_pairs`` appends
-    (pair_light', pair_hist') for the NEXT level to the return tuple.
-    """
-    B = n_bins
-    d = Xb.shape[1]
-    c = gh.shape[1] - 1
-    iota_m = jnp.arange(m)
-    in_use = iota_m < n_active
-    subtract = pair_hist is not None
-    pairs = m // 2
-    if Og is not None:
-        S = jax.nn.one_hot(row_slot, m, dtype=jnp.float32)       # [n, m]
-        if subtract:
-            # light-child membership from the full slot one-hot: select the
-            # light column of each sibling pair (no gathers)
-            light_sel = jnp.stack([pair_light, 1.0 - pair_light], axis=-1)
-            S_light = (S.reshape(-1, pairs, 2) * light_sel[None]).sum(-1)
-            record_trace_event("hist_subtracted", "mm",
-                               2 * pairs * S.shape[0] * (c + 1) * d * B)
-            Gl, Hl = _level_histograms_mm(Og, S_light, w, pairs, B, d, c + 1)
-        else:
-            G, H = _level_histograms_mm(Og, S, w, m, B, d, c + 1)
-    else:
-        S = None
-        if subtract:
-            # CPU segment-sum path: gathers are cheap here, so route light
-            # rows straight to their pair id and rest everything else
-            lp_slot = pair_light > 0.5
-            light_slot = jnp.stack([lp_slot, ~lp_slot], axis=-1).reshape(-1)
-            s_safe = jnp.maximum(row_slot, 0)
-            is_light = light_slot[s_safe] & (row_slot >= 0)
-            pair_ids = jnp.where(is_light, row_slot >> 1, -1)
-            record_trace_event("hist_subtracted", "segment",
-                               row_slot.shape[0] * (c + 1) * d // 2)
-            Gl, Hl = _level_histograms(Xb, gh * w[:, None], pair_ids, pairs, B)
-        else:
-            G, H = _level_histograms(Xb, gh * w[:, None], row_slot, m, B)
-    # row-sharded launch: local-rows histograms psum to the GLOBAL per-bin
-    # stats, so every shard picks identical splits (distributed-XGBoost
-    # histogram aggregation); row routing below stays local.  On the
-    # subtracted path only the LIGHT histograms cross the wire (half the
-    # payload); parents are already post-psum globals from the prior level.
-    if subtract:
-        Gl = mesh_psum(Gl, axis_name)                # [pairs, c, d, B]
-        Hl = mesh_psum(Hl, axis_name)                # [pairs, d, B]
-        Gh = pair_hist[:, :c] - Gl
-        Hh = pair_hist[:, c] - Hl
-        lp = pair_light > 0.5                        # light child is LEFT
-        lpg = lp[:, None, None, None]
-        lph = lp[:, None, None]
-        G = jnp.stack([jnp.where(lpg, Gl, Gh),
-                       jnp.where(lpg, Gh, Gl)], axis=1).reshape(m, c, d, B)
-        H = jnp.stack([jnp.where(lph, Hl, Hh),
-                       jnp.where(lph, Hh, Hl)], axis=1).reshape(m, d, B)
-    else:
-        G = mesh_psum(G, axis_name)
-        H = mesh_psum(H, axis_name)
-    # G: [m, c, d, B]; H: [m, d, B] — bins minor, no 2-wide lane dims
-    GT = G[:, :, 0, :].sum(axis=-1)   # [m, c] — node totals (same per feature)
-    HT = H[:, 0, :].sum(axis=-1)      # [m]
-
-    GL = jnp.cumsum(G, axis=-1)                  # [m, c, d, B]
-    HL = jnp.cumsum(H, axis=-1)                  # [m, d, B]
-    GR = GT[:, :, None, None] - GL
-    HR = HT[:, None, None] - HL
-
-    def score(Gp, Hp):
-        return (Gp * Gp).sum(axis=1) / (Hp + reg_lambda)
-
-    gain = score(GL, HL) + score(GR, HR) - score(GT, HT)[:, None, None]  # [m,d,B]
-    valid = (HL >= min_child_weight) & (HR >= min_child_weight)
-    valid &= feat_mask[None, :, None] > 0.0
-    valid &= jnp.arange(B)[None, None, :] < B - 1  # last bin: empty right side
-    gain = jnp.where(valid, gain, -jnp.inf)
-    flat = gain.reshape(m, d * B)
-    best = jnp.argmax(flat, axis=1)              # [m]
-    best_gain = jnp.max(flat, axis=1)
-    bf = (best // B).astype(jnp.int32)
-    bb = (best % B).astype(jnp.int32)
-    # Spark minInfoGain parity: our gain is the total-sum-of-squares drop,
-    # which equals node_weight * Spark's per-row impurity decrease for both
-    # gini (g=-onehot) and variance (g=-y) trees — so the per-row threshold
-    # scales by the node's hessian total (DefaultSelectorParams.MinInfoGain).
-    do_split = (best_gain > gamma) & (best_gain >= min_info_gain * HT) & in_use
-    half = next_cap // 2
-    if next_cap < 2 * m and not exact_cap:
-        # beam cap: keep top half splits by gain (scatter-free inverse perm)
-        key = jnp.where(do_split, -best_gain, jnp.inf)
-        rank = jnp.argsort(jnp.argsort(key))
-        do_split &= rank < half
-        k = jnp.cumsum(do_split.astype(jnp.int32))
-    else:
-        k = jnp.cumsum(do_split.astype(jnp.int32))
-        if next_cap < 2 * m:  # provably non-binding; clamp guards anyway
-            do_split &= k <= half
-            k = jnp.minimum(k, half)
-    n_split = k[-1]
-    child_idx = (k - 1) * 2                      # left child's next-level slot
-    left_pool = next_free + child_idx
-    right_pool = left_pool + 1
-    # node records for the whole frontier, ONE dynamic_update_slice.  Slots
-    # past the live frontier get the leaf default — which is exactly the
-    # correct initial record for the children this level allocates there.
-    rec = jnp.stack([jnp.where(do_split, bf, -1),
-                     jnp.where(do_split, bb, 0),
-                     jnp.where(do_split, left_pool, 0),
-                     jnp.where(do_split, right_pool, 0)], axis=-1)   # [m, 4]
-    nodes = lax.dynamic_update_slice(nodes, rec, (slot_base, 0))
-    # children's leaf values straight from the winning split's stats; the
-    # best-split slice is a one-hot reduction, not a take_along_axis gather
-    onehot_best = jax.nn.one_hot(best, d * B, dtype=GL.dtype)        # [m, dB]
-    GL_best = (GL.reshape(m, c, d * B) * onehot_best[:, None, :]).sum(-1)
-    HL_best = (HL.reshape(m, d * B) * onehot_best).sum(-1)
-    GR_best = GT - GL_best
-    HR_best = HT - HL_best
-    # dead slots have HL_best = 0; with reg_lambda = 0 the ratio is 0/0 = NaN
-    # and 0 * NaN would poison the child-packing matmul below — zero them
-    lval = jnp.where(do_split[:, None],
-                     -GL_best / (HL_best + reg_lambda)[:, None], 0.0)
-    rval = jnp.where(do_split[:, None],
-                     -GR_best / (HR_best + reg_lambda)[:, None], 0.0)
-    # pack (lval, rval) of the k split slots into the contiguous child block
-    # [next_free, next_free + 2k) with two tiny selection matmuls (slot s's
-    # left child lands at position child_idx[s], right at +1); the tail
-    # beyond 2k stays zero in not-yet-allocated pool slots, which later
-    # levels overwrite or leave unreachable (no pointer ever reaches them)
-    iota_cap = jnp.arange(next_cap)
-    pos_l = jnp.where(do_split, child_idx, -1)
-    pos_r = jnp.where(do_split, child_idx + 1, -1)
-    L_eq = (iota_cap[:, None] == pos_l[None, :]).astype(leaf_val.dtype)
-    R_eq = (iota_cap[:, None] == pos_r[None, :]).astype(leaf_val.dtype)
-    child_vals = L_eq @ lval + R_eq @ rval                   # [next_cap, c]
-    leaf_val = lax.dynamic_update_slice(leaf_val, child_vals, (next_free, 0))
-    # route rows: each row needs its slot's (do_split, bb, child_idx, bf);
-    # gather-via-matmul against S — per-element gathers serialize on TPU
-    if S is not None:
-        pack = jnp.concatenate(
-            [do_split.astype(jnp.float32)[:, None],
-             bb.astype(jnp.float32)[:, None],
-             child_idx.astype(jnp.float32)[:, None],
-             jax.nn.one_hot(bf, d, dtype=jnp.float32)], axis=1)      # [m, 3+d]
-        routed = S @ pack                                            # [n, 3+d]
-        splits_here = routed[:, 0] > 0.5
-        child_r = routed[:, 2].astype(jnp.int32)
-        row_bin = (routed[:, 3:] * Xb).sum(axis=1)   # f32-exact small ints
-        go_right = (row_bin > routed[:, 1]).astype(jnp.int32)
-    else:
-        s_safe = jnp.maximum(row_slot, 0)
-        splits_here = do_split[s_safe] & (row_slot >= 0)
-        row_bin = jnp.take_along_axis(Xb, bf[s_safe][:, None], axis=1)[:, 0]
-        go_right = (row_bin > bb[s_safe]).astype(jnp.int32)
-        child_r = child_idx[s_safe]
-    new_row_slot = jnp.where(splits_here, child_r + go_right, -1)
-    row_node = jnp.where(splits_here, next_free + child_r + go_right, row_node)
-    if want_pairs:
-        # parent histograms for the NEXT level's sibling pairs: slot s's
-        # (post-psum, post-reassembly) G/H packed at pair j = child_idx/2 by
-        # reusing every other row of the child-packing selector L_eq; the
-        # light-left flag comes from the winning split's child hessians
-        GH_all = jnp.concatenate([G, H[:, None]], axis=1).reshape(m, -1)
-        P_pair = L_eq[0::2]                          # [next_cap // 2, m]
-        new_pair_hist = (P_pair @ GH_all).reshape(next_cap // 2, c + 1, d, B)
-        new_pair_light = P_pair @ (HL_best <= HR_best).astype(jnp.float32)
-        return (nodes, leaf_val, 2 * n_split, new_row_slot, row_node,
-                new_pair_light, new_pair_hist)
-    return nodes, leaf_val, 2 * n_split, new_row_slot, row_node
-
-
-def grow_tree(Xb, g, h, w, feat_mask, max_depth: int, n_bins: int,
-              frontier: int, reg_lambda: float = 1.0, gamma: float = 0.0,
-              min_child_weight: float = 1.0, min_info_gain=0.0,
-              Og=None, return_row_node: bool = False,
-              exact_cap: bool = False, axis_name: Optional[str] = None):
-    """Grow one second-order histogram tree (traceable; static shapes).
-
-    Xb: int[n, d] pre-binned features; g: f32[n, c] gradients; h: f32[n]
-    hessians; w: f32[n] row weights (bootstrap/balancing; 0 drops the row);
-    feat_mask: f32[d] 1/0 feature subsampling mask; ``frontier``: static
-    frontier width M (see ``frontier_cap``); ``Og``: optional shared
-    ``grad_onehot(Xb, concat([g, h], 1), n_bins)`` selecting the MXU
-    histogram path.  With ``return_row_node`` the final (tree, row_node)
-    pair is returned — ``leaf_val[row_node]`` is the tree's prediction on
-    the training rows, sparing boosting a predict walk.
-
-    Gain (XGBoost): sum_c GL_c^2/(HL+l) + GR_c^2/(HR+l) - GT_c^2/(HT+l);
-    leaf value: -G/(H+l).  With g=-y, h=1, l~0 this is exactly variance-gain
-    splitting with mean leaves (Spark variance impurity), and with
-    g=-onehot(y) it is gini-equivalent gain with class-distribution leaves
-    (Spark gini impurity).
-    """
-    Xb = Xb.astype(jnp.int32)
-    n, d = Xb.shape
-    c = g.shape[1]
-    P = _pool_size(max_depth, frontier)
-    gw = g * w[:, None]
-    hw = h * w
-    root_val = (-mesh_psum(gw.sum(axis=0), axis_name)
-                / (mesh_psum(hw.sum(), axis_name) + reg_lambda))  # [c]
-    nodes = jnp.tile(jnp.asarray([-1, 0, 0, 0], jnp.int32), (P, 1))
-    leaf_val = jnp.zeros((P, c), jnp.float32).at[0].set(root_val)
-    row_node = jnp.zeros((n,), jnp.int32)
-
-    def as_tree(nodes, leaf_val):
-        return Tree(split_feat=nodes[:, 0], split_bin=nodes[:, 1],
-                    left=nodes[:, 2], right=nodes[:, 3], leaf_val=leaf_val)
-
-    if max_depth <= 0:  # single leaf
-        tree = as_tree(nodes, leaf_val)
-        return (tree, row_node) if return_row_node else tree
-    gh = jnp.concatenate([g, h[:, None]], axis=1)  # unweighted; w rides S
-
-    M = frontier
-    L = M.bit_length() - 1
-    # histogram subtraction only pays from level 1 on (the root has no
-    # sibling); the pair carry rides alongside the 5-tuple when enabled
-    sub = _hist_subtract() and max_depth > 1
-    carry = (nodes, leaf_val,
-             jnp.asarray(1, jnp.int32),          # n_active (just the root)
-             jnp.zeros((n,), jnp.int32),         # row_slot
-             row_node)
-    pl = ph = None
-    # exact unrolled levels: widths 1, 2, 4, ..., min(2^(depth-1), M/ --)
-    # static pool layout (_pool_size): level t's frontier block starts at
-    # 2^t - 1; loop level t's at M - 1 + (t - L)*M — uniform across trees
-    u = min(max_depth, L)
-    for t in range(u):
-        next_cap = 1 << (t + 1)                  # = 2m: no beam cap
-        out = _grow_level(
-            Xb, gh, w, feat_mask, carry[0], carry[1], (1 << t) - 1,
-            (1 << (t + 1)) - 1, *carry[2:], m=1 << t, next_cap=next_cap,
-            n_bins=n_bins, reg_lambda=reg_lambda, gamma=gamma,
-            min_child_weight=min_child_weight, min_info_gain=min_info_gain,
-            Og=Og, exact_cap=exact_cap, axis_name=axis_name,
-            pair_light=pl, pair_hist=ph, want_pairs=sub)
-        if sub:
-            carry, pl, ph = out[:5], out[5], out[6]
-        else:
-            carry = out
-    # deep levels: ONE fori_loop body at fixed M slots.  With subtraction
-    # the carry gains (pair_light [M/2], pair_hist [M/2, c+1, d, B]) — the
-    # last unrolled level's next_cap is exactly M, so the shapes are static
-    # across iterations.
-    if max_depth > L:
-        if sub:
-            def body(t, state):
-                sb = M - 1 + (t - L) * M         # affine in t: batch-uniform
-                return _grow_level(
-                    Xb, gh, w, feat_mask, state[0], state[1], sb, sb + M,
-                    *state[2:5], m=M, next_cap=M, n_bins=n_bins,
-                    reg_lambda=reg_lambda, gamma=gamma,
-                    min_child_weight=min_child_weight,
-                    min_info_gain=min_info_gain, Og=Og, exact_cap=exact_cap,
-                    axis_name=axis_name, pair_light=state[5],
-                    pair_hist=state[6], want_pairs=True)
-
-            carry = lax.fori_loop(L, max_depth, body,
-                                  tuple(carry) + (pl, ph))[:5]
-        else:
-            def body(t, carry):
-                sb = M - 1 + (t - L) * M         # affine in t: batch-uniform
-                return _grow_level(Xb, gh, w, feat_mask, carry[0], carry[1],
-                                   sb, sb + M, *carry[2:], m=M, next_cap=M,
-                                   n_bins=n_bins, reg_lambda=reg_lambda,
-                                   gamma=gamma,
-                                   min_child_weight=min_child_weight,
-                                   min_info_gain=min_info_gain, Og=Og,
-                                   exact_cap=exact_cap, axis_name=axis_name)
-
-            carry = lax.fori_loop(L, max_depth, body, carry)
-    nodes, leaf_val, row_node = carry[0], carry[1], carry[4]
-    tree = as_tree(nodes, leaf_val)
-    return (tree, row_node) if return_row_node else tree
 
 
 def predict_tree(Xb, tree: Tree, max_depth: int) -> jax.Array:
@@ -726,13 +299,14 @@ def predict_tree(Xb, tree: Tree, max_depth: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Batch-native level grower — the whole tree chunk in ONE flat GEMM per level
+# The level grower — the whole tree chunk in ONE GEMM per level
 #
 # A note from before the first chip run (not chip evidence by PERF.md's
 # rule, not measured again): the per-tree contraction ([m, n] @ [n, c1*d*B]
 # batched over the trees) lowered far worse than the SAME reduction
-# flattened to a single [T*m, n] @ [n, c1*d*B] GEMM.  So the
-# forest kernels grow their whole chunk with an explicit tree axis: slot
+# flattened to a single [T*m, n] @ [n, c1*d*B] GEMM.  So every tree is grown
+# as one of a chunk with an explicit tree axis (a single tree is a chunk of
+# one, ``grow_tree``; a boosting round a chunk of its candidates' trees): slot
 # one-hots are built [T, m, rows] (slot axis ahead of rows: no transpose
 # before the flatten) and every level runs one flat GEMM — accumulated over
 # row blocks (``hist_blocks``), so its cost in memory does not grow with n.
@@ -741,6 +315,50 @@ def predict_tree(Xb, tree: Tree, max_depth: int) -> jax.Array:
 # pretend it has: there the level is a tree-batched GEMM over each tree's own
 # kept columns (chip numbers: PERF.md, PR 30).
 # ---------------------------------------------------------------------------
+def _hist_gemm(Xk, ghk, wk, slot_k, hist_slot, n_bins: int, per_tree: bool):
+    """The ONE place a level's sums are formed, on every backend: the
+    weighted g and h of each tree's rows by (slot, feature, bin),
+    f32[T, mh, c1, d, B], as a one-hot GEMM accumulated over the row blocks
+    (operands as ``_grow_level_batch`` describes them; ``hist_slot`` i32[T, mh]
+    names the frontier slot each histogram row collects)."""
+    compact = Xk.ndim == 4
+    bn, d = (Xk.shape[3], Xk.shape[2]) if compact else Xk.shape[1:]
+    T, mh = hist_slot.shape
+    c1 = ghk.shape[-1]
+    B = n_bins
+
+    def hist_block(acc, xs):
+        xb, ghb, wb, sb = xs
+        # weighted slot one-hot of the block, slot axis BEFORE rows:
+        # flattening needs no transpose
+        Sw = (sb[:, None, :] == hist_slot[:, :, None]).astype(jnp.float32) \
+            * wb[:, None, :]                                        # [T, mh, bn]
+        if compact:
+            ghb = ghb.transpose(0, 2, 1) if per_tree else ghb.T[None]
+            lhs = (Sw[:, :, None, :] * ghb[:, None, :, :]).reshape(T, -1, bn)
+            rhs = (xb[:, :, None, :] == jnp.arange(B, dtype=xb.dtype)[
+                None, None, :, None]).astype(jnp.float32).reshape(
+                    T, d * B, bn)
+            return acc + lax.dot_general(
+                lhs, rhs, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32), None  # [T, mh*c1, d*B]
+        if per_tree:
+            rhs = bin_onehot(xb, B)                                 # [bn, d*B]
+            lhs = (Sw[:, :, None, :]
+                   * ghb.transpose(0, 2, 1)[:, None, :, :]).reshape(-1, bn)
+        else:
+            rhs = grad_onehot(xb, ghb, B)                        # [bn, c1*d*B]
+            lhs = Sw.reshape(-1, bn)
+        return acc + lax.dot_general(lhs, rhs, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32), None
+
+    gemm = (T, mh * c1, d * B) if compact else \
+        (T * mh * c1, d * B) if per_tree else (T * mh, c1 * d * B)
+    GH, _ = lax.scan(hist_block, jnp.zeros(gemm, jnp.float32),
+                     (Xk, ghk, wk, slot_k))
+    return GH.reshape(T, mh, c1, d, B)
+
+
 def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
                       next_free, n_active, slot_k, node_k, m: int,
                       next_cap: int, n_bins: int, reg_lambda_t, gamma_t,
@@ -748,17 +366,42 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
                       axis_name: Optional[str] = None,
                       pair_light=None, pair_hist=None,
                       want_pairs: bool = False):
-    """One breadth-first level for a BATCH of T trees.
+    """One breadth-first level over an ``m``-slot frontier, for T trees.
 
-    Same split math as ``_grow_level`` (see its docstring for the
-    scatter/gather-free design).  Everything that has a row axis arrives cut
-    into ``nb`` row blocks of ``bn`` rows (``grow_forest`` cuts them once,
-    ``hist_blocks`` sizes them): wk f32[nb, T, bn], slot_k / node_k
-    i32[nb, T, bn] (each row's frontier slot, -1 = resting or padding, and
-    its pool node), ``ghk`` f32[nb, bn, c1] where every tree sees the same
-    g/h (forests) or, ``per_tree``, f32[nb, T, bn, c1] (boosting: each batch
-    element has its own margins F).  Per tree: nodes i32[T, P, 4], leaf_val
-    f32[T, P, c], n_active i32[T], hyperparameters f32[T].
+    SCATTER/GATHER-FREE by design: XLA TPU lowers batched scatters and
+    per-element gathers to near-serial loops, so the (slot, feature, bin)
+    sums are a one-hot GEMM, every per-row lookup of per-slot data is a
+    select against the row's slot, node records land with ONE
+    ``dynamic_update_slice`` per level (the frontier occupies the static
+    pool block ``[slot_base, slot_base + m)`` — see ``_pool_size``; offsets
+    are tree-independent so the write stays one vectorized op), children
+    pack into ``[next_free, next_free + 2k)`` via tiny selection matmuls (no
+    argsort), and the next frontier needs no materialized map — slot j of
+    the next level IS pool id ``next_free + j``.  ``m`` and ``next_cap`` are
+    static; when ``next_cap < 2*m`` the level keeps only the top
+    ``next_cap // 2`` splits by gain — unless ``exact_cap`` says the
+    frontier provably cannot overflow, where a count clamp replaces the
+    sorts.  A node's leaf value is written once, when the node is created
+    (root at init).
+
+    Everything that has a row axis arrives cut into ``nb`` row blocks of
+    ``bn`` rows (``grow_forest`` cuts them once, ``hist_blocks`` sizes
+    them): wk f32[nb, T, bn], slot_k / node_k i32[nb, T, bn] (each row's
+    frontier slot, -1 = resting or padding, and its pool node, so boosting
+    reads final leaf values without a predict walk), ``ghk`` f32[nb, bn, c1]
+    where every tree sees the same g/h (forests) or, ``per_tree``,
+    f32[nb, T, bn, c1] (boosting: each batch element has its own margins F).
+    Per tree: nodes i32[T, P, 4] (feat, bin, left, right), leaf_val
+    f32[T, P, c], n_active i32[T] (the live width of the frontier),
+    hyperparameters f32[T].
+
+    Histogram subtraction (``_hist_subtract``): with ``pair_hist``
+    f32[T, m/2, c+1, d, B] (the parent slots' histograms, packed at sibling-
+    pair positions by the PREVIOUS level) and ``pair_light`` f32[T, m/2]
+    (1.0 = the lighter child sits in the even/left slot), histograms are
+    built only for the light child of each pair; the heavy sibling is
+    ``parent - light`` AFTER the data-axis psum.  ``want_pairs`` appends
+    (pair_light', pair_hist') for the NEXT level to the return tuple.
 
     The binned matrix sets the width every level tensor has:
 
@@ -780,7 +423,6 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
     level in a profiler trace: ``trees.hist`` (the block scan),
     ``trees.split`` (cumsum, gain, arg-max, beam ranking, node records),
     ``trees.route`` (the second block scan: each row's next slot and node).
-    The segment-sum fallback stays on the vmapped ``grow_tree``.
     """
     B = n_bins
     compact = Xk.ndim == 4
@@ -797,54 +439,20 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
     if subtract:
         # histogram subtraction: the level GEMM's LHS covers only the LIGHT
         # child of each sibling pair (half the slot rows); the heavy sibling
-        # is parent - light after the data-axis psum (see _grow_level)
-        mh = pairs
+        # is parent - light after the data-axis psum
         hist_slot = (2 * jnp.arange(pairs)[None, :]
                      + (pair_light < 0.5).astype(jnp.int32))        # [T, mh]
         record_trace_event("hist_subtracted", "mm_batch",
                            2 * T * pairs * nb * bn * c1 * d * B)
     else:
-        mh = m
         hist_slot = jnp.broadcast_to(iota_m[None, :], (T, m))
-    acc_dt = jnp.bfloat16 if _bf16_hist_acc() else jnp.float32
-    if acc_dt == jnp.bfloat16:
-        record_trace_event("bf16_hist", "mm_batch", 2 * T * mh * c1 * d * B)
-
-    def hist_block(acc, xs):
-        xb, ghb, wb, sb = xs
-        # weighted slot one-hot of the block, slot axis BEFORE rows:
-        # flattening needs no transpose
-        Sw = (sb[:, None, :] == hist_slot[:, :, None]).astype(jnp.float32) \
-            * wb[:, None, :]                                        # [T, mh, bn]
-        if compact:
-            ghb = ghb.transpose(0, 2, 1) if per_tree else ghb.T[None]
-            lhs = (Sw[:, :, None, :] * ghb[:, None, :, :]).reshape(T, -1, bn)
-            dt = jnp.bfloat16 if _hist_bf16() else jnp.float32
-            rhs = (xb[:, :, None, :] == jnp.arange(B, dtype=xb.dtype)[
-                None, None, :, None]).astype(dt).reshape(T, d * B, bn)
-            return acc + lax.dot_general(
-                lhs.astype(dt), rhs, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=acc_dt), None    # [T, mh*c1, d*B]
-        if per_tree:
-            rhs = bin_onehot(xb, B)                                 # [bn, d*B]
-            lhs = (Sw[:, :, None, :]
-                   * ghb.transpose(0, 2, 1)[:, None, :, :]).reshape(-1, bn)
-        else:
-            rhs = grad_onehot(xb, ghb, B)                        # [bn, c1*d*B]
-            lhs = Sw.reshape(-1, bn)
-        return acc + lax.dot_general(lhs.astype(rhs.dtype), rhs,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=acc_dt), None
-
     with jax.named_scope("trees.hist"):
-        gemm = (T, mh * c1, d * B) if compact else \
-            (T * mh * c1, d * B) if per_tree else (T * mh, c1 * d * B)
-        GH, _ = lax.scan(hist_block, jnp.zeros(gemm, acc_dt),
-                         (Xk, ghk, wk, slot_k))
-        # bf16 accumulation ends HERE: psum and split gains stay f32
-        GH = GH.astype(jnp.float32).reshape(T, mh, c1, d, B)
-        # global per-bin stats under a row-sharded launch (see _grow_level);
-        # subtracted levels psum only the light half of the payload
+        GH = _hist_gemm(Xk, ghk, wk, slot_k, hist_slot, B, per_tree)
+        # row-sharded launch: local-rows histograms psum to the GLOBAL
+        # per-bin stats, so every shard picks identical splits (distributed-
+        # XGBoost histogram aggregation); row routing below stays local.
+        # Subtracted levels psum only the light half of the payload; parents
+        # are already post-psum globals from the prior level.
         GH = mesh_psum(GH, axis_name)
         if subtract:
             GH_h = pair_hist - GH
@@ -881,6 +489,11 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
         best_gain = jnp.max(flat, axis=-1)
         bf = (best // B).astype(jnp.int32)
         bb = (best % B).astype(jnp.int32)
+        # Spark minInfoGain parity: our gain is the total-sum-of-squares
+        # drop, which equals node_weight * Spark's per-row impurity decrease
+        # for both gini (g=-onehot) and variance (g=-y) trees — so the
+        # per-row threshold scales by the node's hessian total
+        # (DefaultSelectorParams.MinInfoGain).
         do_split = (best_gain > gamma_t[:, None]) \
             & (best_gain >= mig_t[:, None] * HT) & in_use
         half = next_cap // 2
@@ -913,6 +526,8 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
                              onehot_best, precision=_EXACT)
         GR_best = GT - GL_best
         HR_best = HT - HL_best
+        # dead slots have HL_best = 0; with reg_lambda = 0 the ratio is 0/0 =
+        # NaN and 0 * NaN would poison the child-packing matmul below
         lval = jnp.where(
             do_split[:, :, None],
             -GL_best / (HL_best + reg_lambda_t[:, None])[:, :, None], 0.0)
@@ -932,8 +547,10 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
         leaf_val = lax.dynamic_update_slice(leaf_val, child_vals,
                                             (0, next_free, 0))
         if want_pairs:
-            # parent histograms packed at next-level pair positions (see
-            # _grow_level): every other row of the child-packing selector
+            # parent histograms for the NEXT level's sibling pairs: slot s's
+            # (post-psum, post-reassembly) G/H packed at pair child_idx / 2 by
+            # every other row of the child-packing selector L_eq; the light-
+            # left flag comes from the winning split's child hessians
             GH_all = jnp.concatenate([G, H[:, :, None]],
                                      axis=2).reshape(T, m, -1)
             P_pair = L_eq[:, 0::2, :]                # [T, next_cap // 2, m]
@@ -988,7 +605,14 @@ def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
                 frontier: int, reg_lambda_t, gamma_t, mcw_t, mig_t,
                 exact_cap: bool = False, return_row_node: bool = False,
                 gh_t=None, axis_name: Optional[str] = None):
-    """Grow T trees together; ONE GEMM per level (see header note).
+    """Grow T second-order histogram trees together (traceable; static
+    shapes); ONE GEMM per level (see header note).
+
+    Gain (XGBoost): sum_c GL_c^2/(HL+l) + GR_c^2/(HR+l) - GT_c^2/(HT+l);
+    leaf value: -G/(H+l).  With g=-y, h=1, l~0 this is exactly variance-gain
+    splitting with mean leaves (Spark variance impurity), and with
+    g=-onehot(y) it is gini-equivalent gain with class-distribution leaves
+    (Spark gini impurity).
 
     Shared: Xb int[n, d].  Gradients either SHARED (g f32[n, c], h f32[n] —
     forests) or PER TREE (``gh_t`` f32[T, n, c1]; pass g/h as None —
@@ -1001,10 +625,10 @@ def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
     of 760 features never builds the other 732); with k == d, or a mask,
     the levels are d wide over the shared matrix and nothing is gathered.
     The rows are cut into blocks once, here, sized for the widest level
-    (``hist_blocks``).  Falls back to ``vmap(grow_tree)`` when the matmul
-    histogram path is off (CPU).  Node records hold original feature
-    indices on every path.
-    Returns Tree with leading [T] axis (+ row_node on request).
+    (``hist_blocks``).  Node records hold original feature indices in
+    either layout.  Returns Tree with leading [T] axis (+ row_node on
+    request: ``leaf_val[row_node]`` is a tree's prediction on the training
+    rows, sparing boosting a predict walk).
     """
     n, d = Xb.shape
     per_tree = gh_t is not None
@@ -1016,30 +640,6 @@ def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
     else:
         feat_idx_t, feat_mask_t = None, feat_t
     compact = feat_idx_t is not None and feat_idx_t.shape[1] < d
-    if not _hist_via_matmul():
-        if feat_mask_t is None:  # the segment-sum grower reads a mask
-            feat_mask_t = (feat_idx_t[:, :, None] == jnp.arange(d)).any(
-                axis=1).astype(jnp.float32)
-        if not per_tree:
-            def one(wt, fm, lam, gam, mcw, mig):
-                return grow_tree(Xb, g, h, wt, fm, max_depth, n_bins,
-                                 frontier, reg_lambda=lam, gamma=gam,
-                                 min_child_weight=mcw, min_info_gain=mig,
-                                 Og=None, return_row_node=return_row_node,
-                                 exact_cap=exact_cap, axis_name=axis_name)
-
-            return jax.vmap(one)(w_t, feat_mask_t, reg_lambda_t, gamma_t,
-                                 mcw_t, mig_t)
-
-        def one(ght, wt, fm, lam, gam, mcw, mig):
-            return grow_tree(Xb, ght[:, :c], ght[:, c], wt, fm, max_depth,
-                             n_bins, frontier, reg_lambda=lam, gamma=gam,
-                             min_child_weight=mcw, min_info_gain=mig,
-                             Og=None, return_row_node=return_row_node,
-                             exact_cap=exact_cap, axis_name=axis_name)
-
-        return jax.vmap(one)(gh_t, w_t, feat_mask_t, reg_lambda_t, gamma_t,
-                             mcw_t, mig_t)
     if per_tree:
         gw_sum = (gh_t[:, :, :c] * w_t[:, :, None]).sum(axis=1)
         hw_sum = (gh_t[:, :, c] * w_t).sum(axis=1)
@@ -1064,6 +664,8 @@ def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
 
     M = frontier
     L = M.bit_length() - 1
+    # histogram subtraction only pays from level 1 on (the root has no
+    # sibling); the pair carry rides alongside the 5-tuple when enabled
     sub = _hist_subtract() and max_depth > 1
     # row blocks, sized for the widest level's operands
     mh = min(M, 1 << (max_depth - 1))
@@ -1107,9 +709,14 @@ def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
             pair_hist=state[6] if len(state) > 5 else None,
             want_pairs=want_pairs)
 
+    # exact unrolled levels, widths 1, 2, 4, ...: level t's frontier block
+    # starts at 2^t - 1 (static pool layout, ``_pool_size``), next_cap = 2m
     for t in range(min(max_depth, L)):
         carry = level(carry, (1 << t) - 1, (1 << (t + 1)) - 1, 1 << t,
                       1 << (t + 1), sub)
+    # deep levels: ONE fori_loop body at fixed M slots, block starts affine
+    # in t.  The last unrolled level's next_cap is exactly M, so the carried
+    # pair histograms keep one static shape across iterations.
     if max_depth > L:
         def body(t, state):
             sb = M - 1 + (t - L) * M
@@ -1122,8 +729,29 @@ def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
     return tree, jnp.moveaxis(carry[4], 0, 1).reshape(T, nb * bn)[:, :n]
 
 
+def grow_tree(Xb, g, h, w, feat_mask, max_depth: int, n_bins: int,
+              frontier: int, reg_lambda: float = 1.0, gamma: float = 0.0,
+              min_child_weight: float = 1.0, min_info_gain=0.0,
+              return_row_node: bool = False, exact_cap: bool = False,
+              axis_name: Optional[str] = None):
+    """Grow one tree: ``grow_forest`` with T = 1, the tree axis stripped.
+
+    Xb: int[n, d] pre-binned features; g: f32[n, c] gradients; h: f32[n]
+    hessians; w: f32[n] row weights (bootstrap/balancing; 0 drops the row);
+    feat_mask: f32[d] 1/0 feature subsampling mask, or i32[k] kept indices;
+    ``frontier``: static frontier width M (see ``frontier_cap``).
+    """
+    one = lambda v: jnp.asarray(v, jnp.float32).reshape(1)
+    out = grow_forest(Xb, g, h, w[None], feat_mask[None], max_depth, n_bins,
+                      frontier, reg_lambda_t=one(reg_lambda),
+                      gamma_t=one(gamma), mcw_t=one(min_child_weight),
+                      mig_t=one(min_info_gain), exact_cap=exact_cap,
+                      return_row_node=return_row_node, axis_name=axis_name)
+    return jax.tree.map(lambda a: a[0], out)
+
+
 # ---------------------------------------------------------------------------
-# Random forest — vmap over trees
+# Random forest — chunks of trees
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("max_depth", "n_bins", "frontier",
                                              "exact_cap"))
@@ -1137,7 +765,6 @@ def fit_forest(Xb, g, h, w_trees, feat_masks, max_depth: int, n_bins: int,
     kept features' indices i32[T, k] (``kept_features``; see
     ``grow_forest``).  Returns Tree with leading tree axis.
     """
-
     T = w_trees.shape[0]
     return grow_forest(Xb, g, h, w_trees, feat_masks, max_depth, n_bins,
                        frontier,
@@ -1165,15 +792,14 @@ def forest_chunk_size(max_depth: int, n_bins: int, d: int, c: int,
     parent pair histograms add about half a level's histograms (the 0.5
     bump).  ``k`` is the width the levels are built at: ``n_kept`` where the
     forest is grown on its kept features (``grow_forest`` with an index
-    table, on the matmul path), else all ``d`` — the segment-sum grower
-    builds every tree's histograms full width whatever it keeps.  Of the
+    table), else all ``d``.  Of the
     rows a tree keeps its weights, slots and nodes (the ``3 * n_rows`` term)
     and, compacted, its k columns of the binned matrix: the [M, rows] slot
     one-hot exists one row block at a time and has its own quarter of the
     budget (``hist_blocks``)."""
     hist_factor = 3.5 if _hist_subtract() else 3.0
     k = d
-    if n_kept is not None and n_kept < d and _hist_via_matmul():
+    if n_kept is not None and n_kept < d:
         k = n_kept
     per_tree = (frontier * n_bins * k * (c + 1) * hist_factor + 3 * n_rows) * 4
     if k < d:
@@ -1199,7 +825,7 @@ def fit_forest_chunked(Xb, g, h, w_trees, feat_masks, mcw_trees, max_depth: int,
                        reg_lambda: float = 1e-6, mig_trees=None,
                        exact_cap: bool = False) -> Tree:
     """Train an arbitrary tree population with bounded memory: ``lax.map``
-    over chunks of ``chunk`` vmapped trees — one compile, sequential chunks.
+    over chunks of ``chunk`` trees — one compile, sequential chunks.
 
     ``feat_masks`` is f32[TT, d] masks or i32[TT, k] kept-feature indices
     (``grow_forest``).  The tree axis TT (a multiple of ``chunk``; callers
@@ -1314,53 +940,35 @@ def _gbt_impl(Xb, y, w, row_w_rounds, feat_mask_rounds, loss: str, n_rounds: int
     K = max(int(trees_per_round), 1)
 
     record_trace_event("gbt_chain", loss, n_rounds // K)
-    # the matmul histogram lives in the batch grower alone, so on that path
-    # K = 1 is a forest of one tree a round (the same scan, no collapse)
-    if K > 1 or _hist_via_matmul():
-        if n_rounds % K:
-            raise ValueError(
-                f"trees_per_round={K} must divide n_rounds={n_rounds}")
-        steps = n_rounds // K
-        rw_s = row_w_rounds.reshape(steps, K, n)
-        fm_s = feat_mask_rounds.reshape(steps, K, -1)
-        as_k = lambda v: jnp.broadcast_to(
-            jnp.asarray(v, jnp.float32), (K,))
+    # a round is a forest of its K trees (K = 1: the same scan, no collapse)
+    if n_rounds % K:
+        raise ValueError(
+            f"trees_per_round={K} must divide n_rounds={n_rounds}")
+    steps = n_rounds // K
+    rw_s = row_w_rounds.reshape(steps, K, n)
+    fm_s = feat_mask_rounds.reshape(steps, K, -1)
+    as_k = lambda v: jnp.broadcast_to(jnp.asarray(v, jnp.float32), (K,))
 
-        def step_fn(F, xs):
-            rwk, fmk = xs                              # [K, n], [K, d]
-            g, hh = _grad_hess(loss, F, y, Y)
-            trees, row_node = grow_forest(
-                Xb, g, hh, w[None, :] * rwk, fmk, max_depth, n_bins,
-                frontier, reg_lambda_t=as_k(reg_lambda), gamma_t=as_k(gamma),
-                mcw_t=as_k(min_child_weight), mig_t=as_k(min_info_gain),
-                exact_cap=exact_cap, return_row_node=True,
-                axis_name=axis_name)
-            leaves = jnp.take_along_axis(
-                trees.leaf_val, row_node[:, :, None].repeat(c, axis=2),
-                axis=1)                                # [K, n, c]
-            F = F + (eta / K) * leaves.sum(axis=0)
-            return F, trees
-
-        F, trees = lax.scan(step_fn, F0, (rw_s, fm_s))
-        # restore the flat [n_rounds, ...] tree axis
-        trees = jax.tree.map(
-            lambda a: a.reshape((n_rounds,) + a.shape[2:]), trees)
-        return trees, F
-
-    def round_fn(F, xs):
-        rw, fm = xs
+    def step_fn(F, xs):
+        rwk, fmk = xs                              # [K, n], [K, d]
         g, hh = _grad_hess(loss, F, y, Y)
-        tree, row_node = grow_tree(
-            Xb, g, hh, w * rw, fm, max_depth, n_bins, frontier,
-            reg_lambda=reg_lambda, gamma=gamma,
-            min_child_weight=min_child_weight,
-            min_info_gain=min_info_gain, return_row_node=True,
-            exact_cap=exact_cap, axis_name=axis_name)
+        trees, row_node = grow_forest(
+            Xb, g, hh, w[None, :] * rwk, fmk, max_depth, n_bins,
+            frontier, reg_lambda_t=as_k(reg_lambda), gamma_t=as_k(gamma),
+            mcw_t=as_k(min_child_weight), mig_t=as_k(min_info_gain),
+            exact_cap=exact_cap, return_row_node=True,
+            axis_name=axis_name)
         # row_node is each row's resting node — no predict walk needed
-        F = F + eta * tree.leaf_val[row_node]
-        return F, tree
+        leaves = jnp.take_along_axis(
+            trees.leaf_val, row_node[:, :, None].repeat(c, axis=2),
+            axis=1)                                # [K, n, c]
+        F = F + (eta / K) * leaves.sum(axis=0)
+        return F, trees
 
-    F, trees = lax.scan(round_fn, F0, (row_w_rounds, feat_mask_rounds))
+    F, trees = lax.scan(step_fn, F0, (rw_s, fm_s))
+    # restore the flat [n_rounds, ...] tree axis
+    trees = jax.tree.map(
+        lambda a: a.reshape((n_rounds,) + a.shape[2:]), trees)
     return trees, F
 
 
@@ -1425,20 +1033,7 @@ def _gbt_batch_impl(Xb, y, w_batch, row_w_rounds, feat_mask_rounds, loss: str,
     if n_rounds % max(K, 1):
         raise ValueError(
             f"trees_per_round={K} must divide n_rounds={n_rounds}")
-    if not _hist_via_matmul():
-        # segment-sum backends keep the per-element vmap formulation
-        def one(w, eta, lam, gam, mcw, base, mig):
-            _, F = _gbt_impl(Xb, y, w, row_w_rounds, feat_mask_rounds, loss,
-                             n_rounds, max_depth, n_bins, frontier, eta, lam,
-                             gam, mcw, base, n_classes, min_info_gain=mig,
-                             exact_cap=exact_cap, axis_name=axis_name,
-                             trees_per_round=K)
-            return F
-
-        return jax.vmap(one)(w_batch, eta_b, reg_lambda_b, gamma_b,
-                             min_child_weight_b, base_score_b, min_info_gain_b)
-
-    # batch-native boosting: every step grows its B * K trees as ONE
+    # every step grows its B * K trees as ONE
     # flat-GEMM forest: per-tree gradients ride the LHS, the RHS is the
     # gradient-free bin one-hot of a row block (see _grow_level_batch)
     Y = jax.nn.one_hot(y.astype(jnp.int32), max(c, 2), dtype=jnp.float32) \
@@ -1586,10 +1181,10 @@ def subsample_weights(key, n: int, n_rounds: int, frac: float) -> jax.Array:
 
 # ---------------------------------------------------------------------------
 # FLOPs accounting (bench MFU): wrap the tree kernels so every call records
-# its XLA cost_analysis when utils.flops is enabled.  NOTE: tree-histogram
-# work is scatter/cumsum-heavy (VPU, not MXU); the recorded flops are XLA's
-# arithmetic count for the optimized HLO, the honest numerator for an
-# arithmetic-utilization figure rather than an MXU duty cycle.
+# its XLA cost_analysis when utils.flops is enabled.  NOTE: the recorded
+# flops are XLA's arithmetic count for the optimized HLO (the one-hot GEMM's
+# B*m-fold contraction included), not the work the histogram method requires
+# (benchmarks/trees_ops_count.py).
 # ---------------------------------------------------------------------------
 from ..utils import flops as _flops  # noqa: E402
 

@@ -47,11 +47,6 @@ _hist_subtracted: Dict[str, float] = {"levels": 0.0, "flops_avoided": 0.0}
 #: chain (scan steps) any of them dispatched — the critical-path number the
 #: round-collapse attacks
 _gbt_chain: Dict[str, float] = {"chains": 0.0, "steps_max": 0.0}
-#: bf16 histogram accumulation (TMOG_BF16_HIST): levels built with bf16
-#: G/H accumulators and the HBM histogram traffic halved vs f32 — the
-#: bytes_saved mirror of the subtraction bucket (trace-time estimates,
-#: loop bodies counted once)
-_bf16_hist: Dict[str, float] = {"levels": 0.0, "bytes_saved": 0.0}
 #: streamed transform-pipeline traffic (workflow/stream.py): bytes pushed
 #: through device_put per chunk and pulled back for terminal columns, plus
 #: the chunk/launch counts — the "intermediates never leave the device"
@@ -82,7 +77,6 @@ def reset() -> None:
     _collectives.clear()
     _hist_subtracted.update(levels=0.0, flops_avoided=0.0)
     _gbt_chain.update(chains=0.0, steps_max=0.0)
-    _bf16_hist.update(levels=0.0, bytes_saved=0.0)
     _streamed.update(bytes_in=0.0, bytes_out=0.0, chunks=0.0, streams=0.0)
 
 
@@ -116,7 +110,6 @@ def totals() -> Dict[str, Any]:
     out["collectives"] = {k: dict(v) for k, v in _collectives.items()}
     out["hist_subtracted"] = dict(_hist_subtracted)
     out["gbt_chain"] = dict(_gbt_chain)
-    out["bf16_hist"] = dict(_bf16_hist)
     out["streamed"] = dict(_streamed)
     return out
 
@@ -168,12 +161,6 @@ def record_collectives(colls, device=None) -> None:
             _gbt_chain["steps_max"] = max(_gbt_chain["steps_max"],
                                           float(nbytes))
             continue
-        if kind == "bf16_hist":
-            # a trees-kernel trace event: one level's histograms were
-            # accumulated in bf16; payload = HBM bytes saved vs f32
-            _bf16_hist["levels"] += 1
-            _bf16_hist["bytes_saved"] += nbytes
-            continue
         agg = _collectives.setdefault(
             axis, {"count": 0.0, "bytes": 0.0})
         agg["count"] += 1
@@ -197,12 +184,6 @@ def collective_totals() -> Dict[str, Dict[str, float]]:
 def hist_subtracted_totals() -> Dict[str, float]:
     """{"levels", "flops_avoided"}: histogram builds saved by subtraction."""
     return dict(_hist_subtracted)
-
-
-def bf16_hist_totals() -> Dict[str, float]:
-    """{"levels", "bytes_saved"}: levels accumulated with bf16 histograms
-    (TMOG_BF16_HIST) and the HBM traffic halving vs f32 builds."""
-    return dict(_bf16_hist)
 
 
 def _signature(args, kwargs) -> Tuple:
@@ -284,8 +265,7 @@ def _cost(fn, args, kwargs) -> Optional[Dict[str, Any]]:
         return {"flops": float(ca.get("flops", 0.0)),
                 "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
                 "events": tuple(c for c in colls
-                                if c[0] in ("hist_subtracted", "gbt_chain",
-                                            "bf16_hist"))}
+                                if c[0] in ("hist_subtracted", "gbt_chain"))}
     except Exception:
         return None
 
