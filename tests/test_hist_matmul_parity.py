@@ -36,7 +36,8 @@ def _fixture(seed=0, n=400, d=6):
 def test_block_gemm_equals_add_at_histogram(layout):
     """Three row blocks of 160 rows (80 of padding, in no slot), 3 trees, 4
     slots of which the histogram collects a tree's own 2, rows resting at -1:
-    every (tree, slot, channel, feature, bin) sum against ``np.add.at``."""
+    every (tree, slot, channel, feature, bin) sum against ``np.add.at``; the
+    GEMM's sums come back [T, channel, bin, slot, feature]."""
     Xb, y, rng = _fixture()
     n, d = Xb.shape
     T, nb, bn, c1 = 3, 3, 160, 2
@@ -74,7 +75,9 @@ def test_block_gemm_equals_add_at_histogram(layout):
     got = Tr._hist_gemm(Xk, blocks(gh, gh.ndim - 2), blocks(w, 1),
                         blocks(slot, 1, fill=-1), jnp.asarray(hist_slot),
                         BINS, per_tree=layout != "shared")
-    assert got.shape == want.shape and np.abs(want).sum() > 100
+    assert got.shape == (T, c1, BINS, 2, cols.shape[1])
+    got = np.asarray(got).transpose(0, 3, 1, 4, 2)
+    assert np.abs(want).sum() > 100
     if layout == "shared":  # sums of small integers: exact
         assert np.array_equal(np.asarray(got), want.astype(np.float32))
     else:

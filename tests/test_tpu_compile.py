@@ -12,9 +12,10 @@ entry compiled for an absent chip cannot be read back).  ``jax.devices()``
 is still the CPU here; the tree kernels have one formulation for every
 backend, so what is lowered here is what a TPU traces.
 
-Time: about two minutes in all on this sandbox's 8 cores, not the one
-minute asked for — the programs of this repo are whole sweeps, not
-two-second kernels.  Whole programs are kept where one takes under a
+Time: about two and a half minutes in all on this sandbox's 8 cores, not the
+one minute asked for — the programs of this repo are whole sweeps, not
+two-second kernels (the boosted rounds at the trees cell's shape, whose
+compiled layouts the last test reads, are ~30 s of it).  Whole programs are kept where one takes under a
 minute: phase A's 28-candidate sweep is the slowest (45-60 s, most of it
 the three forest depth groups), the streamed chunk program ~20 s.  Two are
 cut to their dominant kernels, and say so where they are cut: phase B's
@@ -88,6 +89,52 @@ def _fits(compiled) -> float:
              + ma.output_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one v5e"
     return total
+
+
+_ARRAY = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]"
+                    r"\{([\d,]*)(?::T\((\d+),(\d+)\))?[^}]*\} ([\w\-]+)\(")
+_ITEM = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "s8": 1, "u8": 1, "pred": 1}
+
+
+def _buffers(text: str):
+    """The arrays a compiled TPU program materialises (instructions outside
+    fused computations): dicts of computation, name, op, dims in minor-to-
+    major order, and logical / tiled physical bytes — a (t2, t1) tile pads
+    the minor axis to t1 and the one above it to t2."""
+    comp, out = "", []
+    for line in text.splitlines():
+        if line[:1] not in (" ", "}", "") and "{" in line:
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            continue
+        m = _ARRAY.match(line)
+        if not m or comp.lstrip("%").startswith("fused_computation"):
+            continue
+        name, dt, dims, order, t2, t1, op = m.groups()
+        dims = [int(x) for x in dims.split(",")] if dims else []
+        order = [int(x) for x in order.split(",")] if order else []
+        phys = [dims[i] for i in order]                   # minor first
+        n = int(np.prod(dims)) if dims else 1
+        if t1 and phys:
+            phys[0] = -(-phys[0] // int(t1)) * int(t1)
+            if len(phys) > 1:
+                phys[1] = -(-phys[1] // int(t2)) * int(t2)
+        item = _ITEM.get(dt, 4)
+        out.append({"comp": comp, "name": name, "op": op, "n": n,
+                    "minor": [dims[i] for i in order], "logical": n * item,
+                    "physical": (int(np.prod(phys)) if phys else 1) * item})
+    return out
+
+
+def _relayout_bytes(buffers, at_least: float = 30e6):
+    """{computation: tiled bytes its ``copy`` / ``reshape`` / ``transpose``
+    instructions write} — ops that compute nothing (a reshape that is a
+    bitcast is printed as ``bitcast``, not ``reshape``)."""
+    by = {}
+    for b in buffers:
+        if b["op"] in ("copy", "reshape", "transpose") \
+                and b["physical"] >= at_least:
+            by[b["comp"]] = by.get(b["comp"], 0) + b["physical"]
+    return by
 
 
 # ---------------------------------------------------------------------------
@@ -331,3 +378,52 @@ def test_histogram_compacted_at_the_trees_cell_rows(scale_features, one_chip):
     assert "trees.hist" in text and "trees.route" in text
     assert "segment" not in text  # histograms ride dot ops
     assert f"s8[{T},{k}," in text  # the kept columns stay one byte a cell
+    # relayout copies of 30 MB and more, all computations together: no more
+    # than PR 31's program wrote (these three levels are narrow: what is
+    # copied is the gathered columns and the row blocks, not level tensors)
+    assert sum(_relayout_bytes(_buffers(text)).values()) <= 1_102_069_760
+
+
+def test_boosted_levels_keep_the_bins_off_the_lanes(scale_features, one_chip):
+    """Two boosting rounds at the ``scale-500-trees`` cell's shape (6 trees x
+    32,768 rows x the vector's width, 32 bins, depth 10, frontier 256: levels
+    8-9 are the beam loop), read from the program the v5e's compiler makes.
+
+    No level tensor has the 32 bins as its minor axis, where the (8, 128)
+    tiles store it four times over; and what is left of relayout copies in
+    any one computation — a beam level's body, or a round's eight unrolled
+    levels together — is under 0.6 GB (PR 31's program: 3.29 GB a beam
+    level, 3.43 GB the unrolled levels, plus 2.4 GB of padded fusion outputs
+    a beam level), and no array of 1e7 elements or more is stored above 1.1
+    times its size.  What stays, pinned here: the GEMM's accumulator
+    [1536, 24320] is cut to [6, 2, 128, 32, 760] and turned slots-minor, two
+    ops, 0.30 GB a beam level."""
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.ops import trees as Tr
+
+    d = scale_features["width"]
+    n, T, rounds, depth, frontier = 32768, 6, 2, 10, 256
+
+    def run(Xb, y, w, rw, fm, eta, lam, gam, mcw):
+        return Tr._gbt_batch_impl(Xb, y, w, rw, fm, "logistic", rounds, depth,
+                                  N_BINS, frontier, eta, lam, gam, mcw)
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(run).lower(
+        S((n, d), np.int8), S((n,), np.float32), S((T, n), np.float32),
+        S((rounds, n), np.float32), S((rounds, d), np.float32),
+        *[S((T,), np.float32)] * 4).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert "trees.hist" in text and "trees.split" in text
+    buffers = _buffers(text)
+    big = [b for b in buffers if b["n"] >= 1e7]
+    # the beam's level tensors are there: 6 x 2 x 32 x 256 x d
+    assert any(sorted(b["minor"]) == sorted([T, 2, N_BINS, frontier, d])
+               for b in big)
+    assert not [b for b in big if b["minor"][0] == N_BINS]
+    padded = [b for b in big if b["physical"] > 1.1 * b["logical"]]
+    assert not padded, padded[:3]
+    relayout = _relayout_bytes(buffers)
+    assert relayout and max(relayout.values()) < 0.6e9, relayout

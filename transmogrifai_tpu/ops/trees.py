@@ -17,7 +17,9 @@ per-row control flow (SURVEY §7 "Trees/GBT/XGBoost on TPU"):
   one-hot GEMM accumulated over row blocks (``grow_forest`` /
   ``_grow_level_batch``: one program for every row count and backend, so
   the program the tests run is the one the chip runs); the best split per
-  slot is a pure cumsum/argmax reduction,
+  slot is running sums over the bins and an arg-max, on level tensors that
+  keep their bins a MAJOR axis ([T, c+1, B, slots, features]: never the 32
+  bins on the TPU's 128 lanes),
 - rows carry a frontier-slot id; the level update is a small GEMM or a
   select, and a compare, per row block,
 - second-order (g, h) statistics make the same builder serve XGBoost-style
@@ -31,8 +33,8 @@ per-row control flow (SURVEY §7 "Trees/GBT/XGBoost on TPU"):
   forest of its candidates' trees — a whole RF trains as ONE XLA launch and
   boosting compiles to a single fixed-trip loop.
 
-Speeds: ``PERF.md`` (PRs 29, 30: the default selector grid on 32,768 x 760
-rows, TPU v5e).  A comment here that gives none says "not measured".
+Speeds: ``PERF.md`` (PRs 29, 30, 32: the default selector grid on 32,768 x
+760 rows, TPU v5e).  A comment here that gives none says "not measured".
 
 Frontier exactness: depth-wise growth is EXACT whenever every level has at
 most ``M // 2`` valid splits.  A valid split needs hessian weight
@@ -260,24 +262,26 @@ def hist_blocks(n: int, lhs_rows: int, rhs_cols: int) -> Tuple[int, int]:
 
 
 def bin_onehot(Xb, n_bins: int) -> jax.Array:
-    """Gradient-FREE histogram RHS of one row block: [n, d*B] with entry
-    (r, j*B + b) = 1[bin(r, j) == b].  Per-tree gradients (boosting) ride
-    the LHS of the level GEMM against it (``_hist_gemm``)."""
+    """Gradient-FREE histogram RHS of one row block: [n, B*d] with entry
+    (r, b*d + j) = 1[bin(r, j) == b] — bin-major, so the level's sums come
+    out with the features minor (``_hist_gemm``).  Per-tree gradients
+    (boosting) ride the LHS of the level GEMM against it."""
     n, d = Xb.shape
-    oh = jax.nn.one_hot(Xb.astype(jnp.int32), n_bins, dtype=jnp.float32)
-    return oh.reshape(n, -1)
+    hit = Xb.astype(jnp.int32)[:, None, :] == jnp.arange(n_bins)[:, None]
+    return hit.astype(jnp.float32).reshape(n, -1)
 
 
 def grad_onehot(Xb, gh, n_bins: int) -> jax.Array:
     """Shared RHS of the level-histogram GEMM for one row block:
-    [n, c1*d*B] where entry (r, c*d*B + j*B + b) = gh[r, c] * 1[bin(r, j)
+    [n, c1*B*d] where entry (r, c*B*d + b*d + j) = gh[r, c] * 1[bin(r, j)
     == b], contracted against the weighted slot one-hot — row weights live
     on the slot side, so this tensor is shared by every tree of a forest."""
     n, d = Xb.shape
-    # one select, not a product with a stored one-hot: the [n, d, B] one-hot
+    # one select, not a product with a stored one-hot: the [n, B, d] one-hot
     # would be written and read once more than this tensor is
-    hit = Xb.astype(jnp.int32)[:, None, :, None] == jnp.arange(n_bins)
-    og = jnp.where(hit, gh.astype(jnp.float32)[:, :, None, None], 0)  # [n,c1,d,B]
+    hit = Xb.astype(jnp.int32)[:, None, None, :] \
+        == jnp.arange(n_bins)[:, None]
+    og = jnp.where(hit, gh.astype(jnp.float32)[:, :, None, None], 0)  # [n,c1,B,d]
     return og.reshape(n, -1)
 
 
@@ -317,15 +321,31 @@ def predict_tree(Xb, tree: Tree, max_depth: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 def _hist_gemm(Xk, ghk, wk, slot_k, hist_slot, n_bins: int, per_tree: bool):
     """The ONE place a level's sums are formed, on every backend: the
-    weighted g and h of each tree's rows by (slot, feature, bin),
-    f32[T, mh, c1, d, B], as a one-hot GEMM accumulated over the row blocks
-    (operands as ``_grow_level_batch`` describes them; ``hist_slot`` i32[T, mh]
-    names the frontier slot each histogram row collects)."""
+    weighted g and h of each tree's rows by (channel, bin, slot, feature),
+    f32[T, c1, B, mh, d], as a one-hot GEMM accumulated over the row blocks
+    (operands as ``_grow_level_batch`` describes them; ``hist_slot``
+    i32[T, mh] names the frontier slot each histogram row collects).
+
+    Why that order.  On the TPU's (8, 128) tiles a level tensor with its 32
+    bins minor is stored four times its size, and every copy in or out of
+    that form moves the padding too: 5.4 GB a beam level of the trees cell,
+    most of the 23 ms it spent outside this GEMM (PERF.md, PR 32).  With the
+    bins a major axis the tiled pair is (slot, feature) or (feature, slot),
+    whichever XLA picks, both dense, and the running sums over the bins are
+    adds of whole planes.  So the LHS rows run (tree, channel, slot) — g and
+    h on planes of their own — and the transpose below is the one time the
+    level's sums change layout.  The shared matrix's one-hot columns run
+    bin-major (b*d + j), so that transpose never sees a 32-wide minor axis;
+    a compacted tree's columns stay feature-major (j*B + b), where XLA fuses
+    the bin compare into the tree-batched GEMM (bin-major there cost a
+    forest chunk 0.2 s of 1.07 on the chip) and k*B is lane-dense as it is.
+    """
     compact = Xk.ndim == 4
     bn, d = (Xk.shape[3], Xk.shape[2]) if compact else Xk.shape[1:]
     T, mh = hist_slot.shape
     c1 = ghk.shape[-1]
     B = n_bins
+    on_lhs = compact or per_tree          # where the gradients ride
 
     def hist_block(acc, xs):
         xb, ghb, wb, sb = xs
@@ -333,30 +353,35 @@ def _hist_gemm(Xk, ghk, wk, slot_k, hist_slot, n_bins: int, per_tree: bool):
         # flattening needs no transpose
         Sw = (sb[:, None, :] == hist_slot[:, :, None]).astype(jnp.float32) \
             * wb[:, None, :]                                        # [T, mh, bn]
-        if compact:
+        if on_lhs:   # LHS rows (tree, channel, slot): a plane a channel
             ghb = ghb.transpose(0, 2, 1) if per_tree else ghb.T[None]
-            lhs = (Sw[:, :, None, :] * ghb[:, None, :, :]).reshape(T, -1, bn)
+            lhs = ghb[:, :, None, :] * Sw[:, None, :, :]     # [T, c1, mh, bn]
+        if compact:
             rhs = (xb[:, :, None, :] == jnp.arange(B, dtype=xb.dtype)[
                 None, None, :, None]).astype(jnp.float32).reshape(
                     T, d * B, bn)
             return acc + lax.dot_general(
-                lhs, rhs, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32), None  # [T, mh*c1, d*B]
+                lhs.reshape(T, -1, bn), rhs, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32), None  # [T, c1*mh, d*B]
         if per_tree:
-            rhs = bin_onehot(xb, B)                                 # [bn, d*B]
-            lhs = (Sw[:, :, None, :]
-                   * ghb.transpose(0, 2, 1)[:, None, :, :]).reshape(-1, bn)
+            rhs = bin_onehot(xb, B)                                 # [bn, B*d]
+            lhs = lhs.reshape(-1, bn)
         else:
-            rhs = grad_onehot(xb, ghb, B)                        # [bn, c1*d*B]
+            rhs = grad_onehot(xb, ghb, B)                        # [bn, c1*B*d]
             lhs = Sw.reshape(-1, bn)
         return acc + lax.dot_general(lhs, rhs, (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32), None
 
-    gemm = (T, mh * c1, d * B) if compact else \
-        (T * mh * c1, d * B) if per_tree else (T * mh, c1 * d * B)
+    gemm = (T, c1 * mh, d * B) if compact else \
+        (T * c1 * mh, B * d) if per_tree else (T * mh, c1 * B * d)
     GH, _ = lax.scan(hist_block, jnp.zeros(gemm, jnp.float32),
                      (Xk, ghk, wk, slot_k))
-    return GH.reshape(T, mh, c1, d, B)
+    if compact:
+        return GH.reshape(T, c1, mh, d, B).transpose(0, 1, 4, 2, 3)
+    if per_tree:
+        return GH.reshape(T, c1, mh, B, d).transpose(0, 1, 3, 2, 4)
+    # shared gradients ride the RHS: the channel comes out beside the bins
+    return GH.reshape(T, mh, c1, B, d).transpose(0, 2, 3, 1, 4)
 
 
 def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
@@ -396,7 +421,7 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
     hyperparameters f32[T].
 
     Histogram subtraction (``_hist_subtract``): with ``pair_hist``
-    f32[T, m/2, c+1, d, B] (the parent slots' histograms, packed at sibling-
+    f32[T, c+1, B, m/2, d] (the parent slots' histograms, packed at sibling-
     pair positions by the PREVIOUS level) and ``pair_light`` f32[T, m/2]
     (1.0 = the lighter child sits in the even/left slot), histograms are
     built only for the light child of each pair; the heavy sibling is
@@ -419,10 +444,13 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
       order, so ties still go to the lower feature and bin, and node
       records hold ``feat_t``'s original index.
 
-    Every layout accumulates block by block.  Three named scopes split the
-    level in a profiler trace: ``trees.hist`` (the block scan),
-    ``trees.split`` (cumsum, gain, arg-max, beam ranking, node records),
-    ``trees.route`` (the second block scan: each row's next slot and node).
+    Every layout accumulates block by block, and every level tensor —
+    histograms, running sums, gains, the carried pair histograms — is
+    [T, c+1, B, slots, d]: bins major, never minor (``_hist_gemm``).  Three
+    named scopes split the level in a profiler trace: ``trees.hist`` (the
+    block scan), ``trees.split`` (running sums, gain, arg-max, beam ranking,
+    node records), ``trees.route`` (the second block scan: each row's next
+    slot and node).
     """
     B = n_bins
     compact = Xk.ndim == 4
@@ -436,6 +464,22 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
     in_use = iota_m[None, :] < n_active[:, None]                    # [T, m]
     subtract = pair_hist is not None
     pairs = m // 2
+
+    # The level's tensors list a frontier's slots LEFT CHILDREN FIRST: row
+    # side * pairs + p of their slot axis is slot 2p + side.  The two halves
+    # of a subtracted level (each pair's left child, each pair's right child)
+    # are then whole blocks of that axis, laid end to end — nothing is
+    # interleaved along a tiled axis.  What a level reduces to per slot is
+    # small ([T, m] or [T, c, m]), and is put in slot order as soon as it
+    # exists: pool ids, beam ranks and routing never see the row order.
+    def to_slots(a):                      # [.., m rows] -> [.., m slots]
+        return a if m == 1 else jnp.swapaxes(
+            a.reshape(a.shape[:-1] + (2, pairs)), -1, -2).reshape(a.shape)
+
+    def to_rows(a):                       # [.., m slots] -> [.., m rows]
+        return a if m == 1 else jnp.swapaxes(
+            a.reshape(a.shape[:-1] + (pairs, 2)), -1, -2).reshape(a.shape)
+
     if subtract:
         # histogram subtraction: the level GEMM's LHS covers only the LIGHT
         # child of each sibling pair (half the slot rows); the heavy sibling
@@ -445,7 +489,7 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
         record_trace_event("hist_subtracted", "mm_batch",
                            2 * T * pairs * nb * bn * c1 * d * B)
     else:
-        hist_slot = jnp.broadcast_to(iota_m[None, :], (T, m))
+        hist_slot = jnp.broadcast_to(to_rows(iota_m)[None, :], (T, m))
     with jax.named_scope("trees.hist"):
         GH = _hist_gemm(Xk, ghk, wk, slot_k, hist_slot, B, per_tree)
         # row-sharded launch: local-rows histograms psum to the GLOBAL
@@ -453,42 +497,65 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
         # XGBoost histogram aggregation); row routing below stays local.
         # Subtracted levels psum only the light half of the payload; parents
         # are already post-psum globals from the prior level.
-        GH = mesh_psum(GH, axis_name)
+        GH = mesh_psum(GH, axis_name)                    # [T, c1, B, mh, d]
         if subtract:
             GH_h = pair_hist - GH
-            lp = (pair_light > 0.5)[:, :, None, None, None]
-            GH = jnp.stack([jnp.where(lp, GH, GH_h),
-                            jnp.where(lp, GH_h, GH)],
-                           axis=2).reshape(T, m, c1, d, B)
+            lp = (pair_light > 0.5)[:, None, None, :, None]
+            GH = jnp.concatenate([jnp.where(lp, GH, GH_h),
+                                  jnp.where(lp, GH_h, GH)], axis=3)
     with jax.named_scope("trees.split"):
-        G, H = GH[:, :, :c], GH[:, :, c]            # [T,m,c,d,B], [T,m,d,B]
-        GT = G[:, :, :, 0, :].sum(axis=-1)          # [T, m, c]
-        HT = H[:, :, 0, :].sum(axis=-1)             # [T, m]
+        # running sums over the bins, g and h planes together, one bin after
+        # the other: B adds of whole [m, d] planes, a scan along the major
+        # axis.  Written as the recurrence, not ``cumsum``: S[b] IS
+        # S[b-1] + GH[b], so a bin that is empty in a node ties with the bin
+        # below it exactly and the lower bin wins (a log-step scan adds in
+        # another order and breaks such ties by its rounding); and XLA leaves
+        # the planes where they lie, where its ``reduce-window`` took the
+        # level's tensors through relayout copies in and out (PERF.md, PR 32)
+        def add_plane(run, plane):
+            run = run + plane
+            return run, run
 
-        GL = jnp.cumsum(G, axis=-1)
-        HL = jnp.cumsum(H, axis=-1)
-        GR = GT[:, :, :, None, None] - GL
-        HR = HT[:, :, None, None] - HL
+        _, S = lax.scan(add_plane, jnp.zeros_like(GH[:, :, 0]),
+                        jnp.moveaxis(GH, 2, 0))
+        S = jnp.moveaxis(S, 0, 2)                        # [T, c1, B, m, d]
+        GL, HL = S[:, :c], S[:, c]              # [T,c,B,m,d], [T,B,m,d]
+        # a node's totals: feature 0's bins
+        tot = GH[..., 0].sum(axis=2)            # [T, c1, m]
+        GT, HT = tot[:, :c], tot[:, c]
+        GR = GT[:, :, None, :, None] - GL
+        HR = HT[:, None, :, None] - HL
 
-        lam = reg_lambda_t[:, None, None, None]
+        lam = reg_lambda_t[:, None]
 
         def score(Gp, Hp):
-            return (Gp * Gp).sum(axis=2) / (Hp + lam)
+            return (Gp * Gp).sum(axis=1) / (Hp + lam[..., None, None])
 
         gain = score(GL, HL) + score(GR, HR) \
-            - ((GT * GT).sum(axis=2)
-               / (HT + reg_lambda_t[:, None]))[:, :, None, None]
-        valid = (HL >= mcw_t[:, None, None, None]) \
-            & (HR >= mcw_t[:, None, None, None])
+            - ((GT * GT).sum(axis=1) / (HT + lam))[:, None, :, None]
+        mcw = mcw_t[:, None, None, None]
+        valid = (HL >= mcw) & (HR >= mcw) \
+            & (jnp.arange(B)[:, None, None] < B - 1)
         if not compact and feat_t is not None:
-            valid &= feat_t[:, None, :, None] > 0.0
-        valid &= jnp.arange(B)[None, None, None, :] < B - 1
-        gain = jnp.where(valid, gain, -jnp.inf)
-        flat = gain.reshape(T, m, d * B)
-        best = jnp.argmax(flat, axis=-1)                            # [T, m]
-        best_gain = jnp.max(flat, axis=-1)
-        bf = (best // B).astype(jnp.int32)
-        bb = (best % B).astype(jnp.int32)
+            valid &= feat_t[:, None, None, :] > 0.0
+        gain = jnp.where(valid, gain, -jnp.inf)                 # [T,B,m,d]
+        # the best split of a slot, ties to the lower feature and then the
+        # lower bin: each feature's best bin first (the first of equals),
+        # then the best feature (the first of equals)
+        bin_of = jnp.argmax(gain, axis=1)                       # [T, m, d]
+        gain_of = jnp.max(gain, axis=1)
+        bf = jnp.argmax(gain_of, axis=-1).astype(jnp.int32)     # [T, m]
+        at_bf = bf[:, :, None] == jnp.arange(d)                 # [T, m, d]
+        bb = jnp.where(at_bf, bin_of, 0).sum(axis=-1).astype(jnp.int32)
+        # the winning split's running sums: one term a sum, so exact
+        hit = at_bf[:, None, :, :] & (bb[:, None, :, None]
+                                      == jnp.arange(B)[:, None, None])
+        S_best = jnp.where(hit[:, None], S, 0.0).sum(axis=(2, 4))  # [T,c1,m]
+        GL_best, HL_best = S_best[:, :c], S_best[:, c]
+        # slot order from here on
+        best_gain, bf, bb, GT, HT, GL_best, HL_best = map(
+            to_slots, (jnp.max(gain_of, axis=-1), bf, bb, GT, HT, GL_best,
+                       HL_best))
         # Spark minInfoGain parity: our gain is the total-sum-of-squares
         # drop, which equals node_weight * Spark's per-row impurity decrease
         # for both gini (g=-onehot) and variance (g=-y) trees — so the
@@ -519,21 +586,14 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
                          jnp.where(do_split, left_pool, 0),
                          jnp.where(do_split, right_pool, 0)], axis=-1)
         nodes = lax.dynamic_update_slice(nodes, rec, (0, slot_base, 0))
-        onehot_best = jax.nn.one_hot(best, d * B, dtype=GL.dtype)   # [T,m,dB]
-        GL_best = jnp.einsum("tmcx,tmx->tmc", GL.reshape(T, m, c, d * B),
-                             onehot_best, precision=_EXACT)
-        HL_best = jnp.einsum("tmx,tmx->tm", HL.reshape(T, m, d * B),
-                             onehot_best, precision=_EXACT)
         GR_best = GT - GL_best
         HR_best = HT - HL_best
         # dead slots have HL_best = 0; with reg_lambda = 0 the ratio is 0/0 =
         # NaN and 0 * NaN would poison the child-packing matmul below
-        lval = jnp.where(
-            do_split[:, :, None],
-            -GL_best / (HL_best + reg_lambda_t[:, None])[:, :, None], 0.0)
-        rval = jnp.where(
-            do_split[:, :, None],
-            -GR_best / (HR_best + reg_lambda_t[:, None])[:, :, None], 0.0)
+        lval = jnp.where(do_split[:, None, :],
+                         -GL_best / (HL_best + lam)[:, None, :], 0.0)
+        rval = jnp.where(do_split[:, None, :],
+                         -GR_best / (HR_best + lam)[:, None, :], 0.0)
         iota_cap = jnp.arange(next_cap)
         pos_l = jnp.where(do_split, child_idx, -1)
         pos_r = jnp.where(do_split, child_idx + 1, -1)
@@ -541,22 +601,20 @@ def _grow_level_batch(Xk, ghk, wk, feat_t, nodes, leaf_val, slot_base,
                 == pos_l[:, None, :]).astype(leaf_val.dtype)
         R_eq = (iota_cap[None, :, None]
                 == pos_r[:, None, :]).astype(leaf_val.dtype)
-        child_vals = jnp.einsum("tpm,tmc->tpc", L_eq, lval, precision=_EXACT) \
-            + jnp.einsum("tpm,tmc->tpc", R_eq, rval,
+        child_vals = jnp.einsum("tpm,tcm->tpc", L_eq, lval, precision=_EXACT) \
+            + jnp.einsum("tpm,tcm->tpc", R_eq, rval,
                          precision=_EXACT)                # [T, next_cap, c]
         leaf_val = lax.dynamic_update_slice(leaf_val, child_vals,
                                             (0, next_free, 0))
         if want_pairs:
             # parent histograms for the NEXT level's sibling pairs: slot s's
-            # (post-psum, post-reassembly) G/H packed at pair child_idx / 2 by
-            # every other row of the child-packing selector L_eq; the light-
-            # left flag comes from the winning split's child hessians
-            GH_all = jnp.concatenate([G, H[:, :, None]],
-                                     axis=2).reshape(T, m, -1)
+            # (post-psum, post-reassembly) planes packed at pair child_idx / 2
+            # by every other row of the child-packing selector L_eq; the
+            # light-left flag comes from the winning split's child hessians
             P_pair = L_eq[:, 0::2, :]                # [T, next_cap // 2, m]
             new_pair_hist = jnp.einsum(
-                "tpm,tmx->tpx", P_pair, GH_all, precision=_EXACT).reshape(
-                T, next_cap // 2, c1, d, B)
+                "tpm,tcbmd->tcbpd", to_rows(P_pair), GH,
+                precision=_EXACT)            # [T, c1, B, next_cap // 2, d]
             new_pair_light = jnp.einsum(
                 "tpm,tm->tp", P_pair, (HL_best <= HR_best).astype(jnp.float32))
     # route rows, a row block at a time.  Each row needs its slot's
