@@ -391,7 +391,7 @@ def _split_forest_group(group, picks: List[int], local: Dict[int, int],
     # the (bootstrap, feature-mask) draw is keyed by (seed, n_trees) only, so
     # any candidate subset reuses the SAME per-tree draws — parity preserved.
     # chunk shrinks with the smaller tree population (same memory ceiling).
-    new_chunk = Tr.balanced_chunk(F * len(picks) * ntrees, chunk)
+    new_chunk = Tr.balanced_chunk(F * len(picks) * ntrees, chunk, group=ntrees)
     return (new_cis, depth, ntrees, xb_idx, n_bins, frac, rate, bootstrap,
             seed, frontier, exact_cap, new_chunk,
             out_blob.add(blob[[off_mcw + p for p in picks]]),
@@ -637,7 +637,7 @@ def _forest_fragment(est, grids, pos: int, blob: _Blob, xbs, X, train_w,
         TT = F * len(idxs) * ntrees
         chunk = Tr.balanced_chunk(
             TT, Tr.forest_chunk_size(depth, n_bins, d, c, frontier, n_rows=n,
-                                     n_kept=Tr.n_kept(d, frac)))
+                                     n_kept=Tr.n_kept(d, frac)), group=ntrees)
         out_groups.append((
             tuple(int(pos + i) for i in idxs), depth, ntrees,
             _xb_index(xbs, X, n_bins), n_bins, frac,

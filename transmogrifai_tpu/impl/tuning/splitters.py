@@ -12,7 +12,7 @@ DataCutter}.scala —
   DataBalancer.scala:84),
 - ``DataCutter`` (:78): multiclass — keeps at most ``maxLabelCategories``
   labels with at least ``minLabelFraction`` support, drops rows of other
-  labels,
+  labels; caps the training set at ``maxTrainingSample`` rows,
 - each emits a ``SplitterSummary`` into stage metadata.
 
 TPU-first redesign: inside the CV sweep, preparation must preserve static
@@ -247,15 +247,20 @@ class DataBalancer(Splitter):
 class DataCutter(Splitter):
     """Multiclass label cutter (DataCutter.scala:78): keep at most
     ``max_label_categories`` labels each with at least ``min_label_fraction``
-    support; rows with dropped labels get zero weight / are removed."""
+    support; rows with dropped labels get zero weight / are removed.
+    ``max_training_sample`` is ``SplitterParams.maxTrainingSample``: the
+    selector draws that many training rows (uniformly, without replacement)
+    before the sweep, as it does for the other splitters that carry one."""
 
     def __init__(self, max_label_categories: int = 100, min_label_fraction: float = 0.0,
-                 reserve_test_fraction: float = 0.1, seed: int = 42):
+                 reserve_test_fraction: float = 0.1, seed: int = 42,
+                 max_training_sample: int = 1_000_000):
         super().__init__(reserve_test_fraction, seed)
         if min_label_fraction >= 0.5:
             raise ValueError("min_label_fraction must be < 0.5")
         self.max_label_categories = max_label_categories
         self.min_label_fraction = min_label_fraction
+        self.max_training_sample = max_training_sample
         self.labels_kept: Optional[List[float]] = None
 
     def pre_validation_prepare(self, y: np.ndarray) -> SplitterSummary:
@@ -287,4 +292,5 @@ class DataCutter(Splitter):
 
     def _params(self):
         return {**super()._params(), "maxLabelCategories": self.max_label_categories,
-                "minLabelFraction": self.min_label_fraction}
+                "minLabelFraction": self.min_label_fraction,
+                "maxTrainingSample": self.max_training_sample}

@@ -223,6 +223,18 @@ def _forest_group_scores(group, xbs, y, train_w, blob, out_c: int, rs=None):
     # the draw's kept features as an index table: its static width k is what
     # grow_forest grows the chunk at (k < d: on the kept columns alone)
     fi = Tr.kept_features(kf, d, n_trees, frac)                   # [T, k]
+    # a chunk adds up its own trees' leaves, ``unit`` trees at a time: whole
+    # forests where the plan's chunk holds one (``Tr.balanced_chunk`` with
+    # the forest as its group), else equal parts of one, each forest filled
+    # up to whole parts with zero-weight trees, which grow nothing and read
+    # 0 at every leaf — the [TT, n, c] leaf reads of a group (1.18 GB at 900
+    # trees x 32,768 rows x 10 classes) never exist side by side
+    unit = min(chunk, n_trees)
+    chunk -= chunk % unit
+    per = -(-n_trees // unit) * unit
+    if per > n_trees:
+        boot = jnp.concatenate([boot, jnp.zeros((per - n_trees, n), boot.dtype)])
+        fi = jnp.concatenate([fi, jnp.tile(fi[:1], (per - n_trees, 1))])
     g = -y[:, None] if out_c == 1 else -jax.nn.one_hot(
         y.astype(jnp.int32), out_c, dtype=jnp.float32)
     h = jnp.ones_like(y)
@@ -231,11 +243,11 @@ def _forest_group_scores(group, xbs, y, train_w, blob, out_c: int, rs=None):
     mig = blob[off_mig:off_mig + Gc]
     # tree population: (fold, candidate, tree) -> [F*Gc*T, n]
     wt = jnp.broadcast_to(boot[None, None] * train_w[:, None, None, :],
-                          (F, Gc, n_trees, n)).reshape(F * Gc * n_trees, n)
-    mcw_t = jnp.tile(jnp.repeat(mcw, n_trees), F)
-    mig_t = jnp.tile(jnp.repeat(mig, n_trees), F)
+                          (F, Gc, per, n)).reshape(F * Gc * per, n)
+    mcw_t = jnp.tile(jnp.repeat(mcw, per), F)
+    mig_t = jnp.tile(jnp.repeat(mig, per), F)
     fi_t = jnp.tile(fi, (F * Gc, 1))
-    TT = F * Gc * n_trees
+    TT = F * Gc * per
     pad = (-TT) % chunk
     if pad:  # zero-weight padding trees grow nothing and are sliced off
         wt = jnp.concatenate([wt, jnp.zeros((pad, n), jnp.float32)])
@@ -255,17 +267,22 @@ def _forest_group_scores(group, xbs, y, train_w, blob, out_c: int, rs=None):
         # growth routes EVERY row (weights only gate histograms), so
         # row_node already holds each row's leaf — reading leaf_val there
         # replaces the depth-step pointer walk that dominated the fragment
-        # (measured 123-692 ms walk vs ~20 ms take at 900 trees)
-        c = tree.leaf_val.shape[-1]
-        return jnp.take_along_axis(
-            tree.leaf_val, row_node[:, :, None].repeat(c, axis=2), axis=1)
+        # (measured 123-692 ms walk vs ~20 ms take at 900 trees).  A plane a
+        # class, rows minor: one [chunk, n, c] read would lay the c classes
+        # on the TPU's 128 lanes (12.8 x its bytes at c = 10, PERF.md PR 33)
+        leaf = jnp.stack([jnp.take_along_axis(tree.leaf_val[:, :, j],
+                                              row_node, axis=1)
+                          for j in range(tree.leaf_val.shape[-1])], axis=1)
+        return leaf.reshape((chunk // unit, unit) + leaf.shape[1:]).sum(axis=1)
 
-    preds = lax.map(one_chunk, (wt.reshape(-1, chunk, n),
-                                fi_t.reshape(-1, chunk, fi.shape[1]),
-                                mcw_t.reshape(-1, chunk),
-                                mig_t.reshape(-1, chunk)))
-    preds = preds.reshape((-1,) + preds.shape[2:])[:TT]       # [TT, n, c]
-    return preds.reshape(F, Gc, n_trees, n, -1).mean(axis=2)  # [F, Gc, n, c]
+    sums = lax.map(one_chunk, (wt.reshape(-1, chunk, n),
+                               fi_t.reshape(-1, chunk, fi.shape[1]),
+                               mcw_t.reshape(-1, chunk),
+                               mig_t.reshape(-1, chunk)))
+    sums = sums.reshape((-1,) + sums.shape[2:])[:TT // unit]  # [TT/unit, c, n]
+    mean = sums.reshape((F, Gc, per // unit) + sums.shape[1:]).sum(axis=2) \
+        / n_trees
+    return jnp.moveaxis(mean, 2, 3)                           # [F, Gc, n, c]
 
 
 def _gbt_group_scores(group, xbs, y, train_w, blob, loss: str, out_c: int,
@@ -630,7 +647,7 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
     n = int(np.asarray(y).shape[0] if not hasattr(y, "shape") else y.shape[0])
     F = train_w.shape[0]
     k = spec[0][1] if isinstance(spec[0], tuple) else 1
-    split = F * C * n * k > SPLIT_METRICS_ELEMS
+    split = F * C * n * k > SPLIT_METRICS_ELEMS or _kept_scores["on"]
     # whole-launch checkpoint (the single-device sweep is one work unit)
     _ck = _ckpt.store()
     ck_key = None
@@ -646,7 +663,8 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
             _sweep_scope.append("launches", {
                 "shards": 1, "candidates": C, "checkpoint": "hit"})
             return jnp.asarray(hit[0]["metrics"])
-    entry = {"shards": 1, "candidates": C, "split": bool(split)}
+    entry = {"shards": 1, "candidates": C, "split": bool(split),
+             "classes": int(k), "score_block_bytes": 4 * F * C * n * k}
     chain = _spec_gbt_chain(spec)
     if chain:
         entry["gbt_chain"] = chain
@@ -719,6 +737,8 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
         else:
             out, scores, colls, _lwall = _dispatch()
         _replay_trace_events(spec, n, colls)
+        if _kept_scores["on"]:
+            _kept_scores["block"] = scores
         if split:
             with trace.span("sweep.account", fn="sweep.run_scores+metrics"):
                 costs = [
@@ -786,6 +806,29 @@ def reset_run_stats() -> None:
     _sweep_scope.reset()
 
 
+#: what ``keep_scores`` asked ``run_sweep`` to hold on to: the score block of
+#: its last single-device launch, on the device
+_kept_scores: Dict[str, Any] = {"on": False, "block": None}
+
+
+def keep_scores(on: bool = True) -> None:
+    """Hold the [F, C, n(, k)] score block of every single-device launch
+    that follows until the next one replaces it (``last_scores``): what each
+    candidate scored each row on each fold, its validation rows among them
+    (out-of-fold predictions for stacking or calibration; a benchmark's
+    comparison with a reference, row by row).  A launch then runs as
+    ``_run_scores`` + ``_run_metrics`` whatever its size, the block being
+    what the first hands the second.  ``keep_scores(False)`` lets go."""
+    _kept_scores.update(on=bool(on), block=None)
+
+
+def last_scores():
+    """The score block kept by ``keep_scores``: a device array whose
+    candidate axis runs in the launch's flat candidate order and whose rows
+    are the sweep's, or None where no launch has run since."""
+    return _kept_scores["block"]
+
+
 def record_fallback(reason: str, **detail) -> None:
     """Note that a launch declined row-sharding (or fusion) and why.
 
@@ -830,6 +873,11 @@ def run_stats() -> Dict[str, Any]:
             # since reset, and sequential dispatches avoided vs the
             # one-launch-per-candidate baseline (record_packs + the
             # row-sharded metric map)
+            # the widest score block a single-device launch held: classes k
+            # of its plan (1: one score a row), bytes of its [F, C, n(, k)]
+            "classes": max((e.get("classes", 0) for e in launches), default=0),
+            "score_block_bytes": max(
+                (e.get("score_block_bytes", 0) for e in launches), default=0),
             # tree levels grown by the single-device launches since reset,
             # those that ranked a full frontier, and those grown on a
             # tree's kept features alone (_spec_tree_levels)

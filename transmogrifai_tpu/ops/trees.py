@@ -851,27 +851,41 @@ def forest_chunk_size(max_depth: int, n_bins: int, d: int, c: int,
     bump).  ``k`` is the width the levels are built at: ``n_kept`` where the
     forest is grown on its kept features (``grow_forest`` with an index
     table), else all ``d``.  Of the
-    rows a tree keeps its weights, slots and nodes (the ``3 * n_rows`` term)
-    and, compacted, its k columns of the binned matrix: the [M, rows] slot
+    rows a tree keeps its weights, slots and nodes (the ``3 * n_rows`` term),
+    the leaf it read for each row, a plane a channel (``c * n_rows``: the
+    fused sweep's ``_forest_group_scores``) and, compacted, its k columns of
+    the binned matrix: the [M, rows] slot
     one-hot exists one row block at a time and has its own quarter of the
     budget (``hist_blocks``)."""
     hist_factor = 3.5 if _hist_subtract() else 3.0
     k = d
     if n_kept is not None and n_kept < d:
         k = n_kept
-    per_tree = (frontier * n_bins * k * (c + 1) * hist_factor + 3 * n_rows) * 4
+    per_tree = (frontier * n_bins * k * (c + 1) * hist_factor
+                + (3 + c) * n_rows) * 4
     if k < d:
         per_tree += n_rows * k * np.dtype(_bin_dtype(n_bins)).itemsize
     return max(1, int(budget_bytes / max(per_tree, 1)))
 
 
-def balanced_chunk(total: int, chunk_max: int) -> int:
+def balanced_chunk(total: int, chunk_max: int, group: int = 1) -> int:
     """Even chunk size: ceil-divide ``total`` into the fewest chunks that
     respect ``chunk_max``, then size chunks evenly so zero-weight padding is
     at most ``n_chunks - 1`` trees (a naive min(total, chunk_max) padded a
-    900-tree group to 2 x 635 = 41% waste — round-5 profile)."""
-    total = max(int(total), 1)
-    n_chunks = -(-total // max(int(chunk_max), 1))
+    900-tree group to 2 x 635 = 41% waste — round-5 profile).
+
+    ``group`` is the trees that belong together, a forest laid tree after
+    tree: a chunk is then whole groups, cut as evenly, where ``chunk_max``
+    holds one, and else the even part of ONE group — so no chunk straddles
+    two forests and a chunk can add up its own trees' leaves
+    (``ops.sweep._forest_group_scores``, which fills a forest up to whole
+    parts)."""
+    total, chunk_max = max(int(total), 1), max(int(chunk_max), 1)
+    if group > 1:
+        if chunk_max >= group:
+            return group * balanced_chunk(total // group, chunk_max // group)
+        total = group
+    n_chunks = -(-total // chunk_max)
     return -(-total // n_chunks)
 
 
