@@ -125,3 +125,46 @@ def test_zero_reg_lambda_leaves_finite(data):
         jnp.ones(d, jnp.float32), max_depth=4, n_bins=16, frontier=16,
         reg_lambda=0.0))(jnp.asarray(Xb), jnp.asarray(g))
     assert bool(jnp.isfinite(tree.leaf_val).all())
+
+
+@pytest.mark.parametrize("k", [2, 10], ids=["one-channel", "ten-classes"])
+def test_fused_boosted_margins_are_the_estimators_walk(data, k):
+    """A boosted group of the fused sweep (``_gbt_group_scores``: every
+    round's leaves read by selection at ``row_node``, ``ops/trees.read_leaves``)
+    against the estimator path, a fold's candidate fitted alone and its stacked
+    trees walked over the training rows (``predict_gbt``): the same margins,
+    logistic at one channel and softmax at ten; and a launch of the plan counts
+    trees x rows x channels leaf reads."""
+    from transmogrifai_tpu.evaluators import Evaluators
+    from transmogrifai_tpu.impl.sweep_fragments import build_sweep_plan
+    from transmogrifai_tpu.impl.trees_common import tree_from_params
+    from transmogrifai_tpu.ops import sweep, trees as Tr
+
+    X, y_bin, y_reg, folds = data
+    n = len(y_bin)
+    y = y_bin if k == 2 else (np.argsort(np.argsort(y_reg)) * k // n).astype(np.float32)
+    c = 1 if k == 2 else k
+    grids = [{"num_round": 6, "eta": 0.2, "max_depth": 3, "min_child_weight": 1.0},
+             {"num_round": 6, "eta": 0.05, "max_depth": 3, "min_child_weight": 5.0}]
+    est = OpXGBoostClassifier(max_bins=16)
+    ev = (Evaluators.BinaryClassification.auPR() if k == 2
+          else Evaluators.MultiClassification.error())
+    plan = build_sweep_plan([(est, grids)], X, y, folds, ev)
+    assert plan is not None
+    (frag,) = plan.spec[1]
+    assert frag[:3] == ("gbt", "logistic" if k == 2 else "softmax", c)
+    (group,) = frag[3]
+    fused = np.asarray(sweep._gbt_group_scores(
+        group, tuple(plan.xbs), plan.y, folds, plan.blob, frag[1], c))
+    assert fused.shape == (2, 2, n, c)
+    for f in range(2):
+        for ci, grid in enumerate(grids):
+            params = est.copy_with_params(grid).fit_arrays(X, y, w=folds[f])
+            walk = np.asarray(Tr.predict_gbt(
+                Tr.bin_with_edges(X, params["edges"]), tree_from_params(params),
+                3, float(params["eta"])))
+            np.testing.assert_allclose(fused[f, ci], walk, rtol=0, atol=1e-5)
+    sweep.reset_run_stats()
+    sweep.run_sweep(plan.spec, plan.X, tuple(plan.xbs), plan.y, folds,
+                    1.0 - folds, plan.blob)
+    assert sweep.run_stats()["tree_leaf_reads"] == 2 * 2 * 6 * n * c

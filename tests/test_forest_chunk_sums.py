@@ -3,12 +3,16 @@ up its own trees' leaves (``ops/sweep._forest_group_scores``), whole forests
 at a time where the plan's chunk holds one, equal parts of one where it does
 not — each forest filled up to whole parts with zero-weight trees — and
 ``ops/trees.balanced_chunk`` with the forest as its ``group`` cuts such
-chunks for every tree count, a prime one too."""
+chunks for every tree count, a prime one too.  And what a chunk reads is
+right: the leaves it selects at ``row_node`` (``ops/trees.read_leaves``) are
+the estimator path's pointer walk over the training rows, at one channel and
+at ten, and ``run_stats()`` counts them."""
 import numpy as np
 import pytest
 
 from transmogrifai_tpu.evaluators import Evaluators
 from transmogrifai_tpu.impl.classification.trees import OpRandomForestClassifier
+from transmogrifai_tpu.impl.trees_common import tree_from_params
 from transmogrifai_tpu.impl.sweep_fragments import build_sweep_plan
 from transmogrifai_tpu.ops import sweep, trees as Tr
 
@@ -39,11 +43,12 @@ def plan(request):
     return _plan(request.param, 10)
 
 
-def _plan(k, n_trees):
+def _plan(k, n_trees, spread=1.0):
     rng = np.random.default_rng(33)
     n, d = 300, 12
     X = np.round(rng.normal(size=(n, d)), 2).astype(np.float32)
-    y = np.clip(np.round(X[:, 0] + X[:, 1] + k / 2 - 0.5), 0, k - 1).astype(np.float32)
+    y = np.clip(np.round(spread * (X[:, 0] + X[:, 1]) + k / 2 - 0.5),
+                0, k - 1).astype(np.float32)
     fold = rng.permutation(n) % FOLDS
     train_w = np.stack([fold != f for f in range(FOLDS)]).astype(np.float32)
     grid = [{"max_depth": 3, "min_instances_per_node": m} for m in (1, 10)]
@@ -88,3 +93,31 @@ def test_a_prime_forest_is_the_mean_of_its_own_trees(chunk):
     np.testing.assert_allclose(single.sum(axis=-1), 1.0, rtol=0, atol=1e-5)
     np.testing.assert_allclose(_scores(p, train_w, frag, chunk), single,
                                rtol=0, atol=2e-7)
+
+
+@pytest.mark.parametrize("k", [2, 10], ids=["one-channel", "ten-classes"])
+def test_group_scores_are_the_estimators_walk_and_the_reads_are_counted(k):
+    """A fold's candidate fitted alone (``fit_arrays``) and walked over the
+    training rows (``predict_forest``) against the fused group's [F, Gc, n, c]
+    block: the same draws, the same trees, the leaves read by selection where
+    the walk gathers them; and a launch of the plan counts trees x rows x
+    channels leaf reads."""
+    p, train_w, frag = _plan(k, 10, spread=k / 4)
+    c = frag[1]
+    assert len(np.unique(p.y)) == k
+    (group,) = frag[2]
+    fused = _scores(p, train_w, frag, group[11])
+    assert fused.shape == (FOLDS, 2, 300, c)
+    X, y = np.asarray(p.X), np.asarray(p.y)
+    for f in range(FOLDS):
+        for ci, mcw in enumerate((1, 10)):
+            cand = OpRandomForestClassifier(
+                num_trees=10, max_depth=3, min_instances_per_node=mcw)
+            params = cand.fit_arrays(X, y, w=train_w[f])
+            walk = np.asarray(Tr.predict_forest(
+                Tr.bin_with_edges(X, params["edges"]), tree_from_params(params), 3))
+            # a binary forest's one channel is p(1): the estimator stores [1-p, p]
+            np.testing.assert_allclose(fused[f, ci], walk[:, -c:], rtol=0, atol=1e-6)
+    sweep.reset_run_stats()
+    sweep.run_sweep(p.spec, p.X, tuple(p.xbs), p.y, train_w, 1.0 - train_w, p.blob)
+    assert sweep.run_stats()["tree_leaf_reads"] == FOLDS * 2 * 10 * 300 * c

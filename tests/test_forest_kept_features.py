@@ -199,6 +199,10 @@ def test_default_grid_counts_its_forest_levels_as_kept():
     levels = sweep._spec_tree_levels(plan.spec, 3)
     assert levels["tree_level_builds"] == 30_900
     assert levels["tree_kept_levels"] == 900 * (3 + 6 + 12) == 18_900
+    # the leaves its 2,700 forest and 1,200 boosted trees hand the trees
+    # cell's 32,768 rows, one channel each (PERF.md, PR 34)
+    assert sweep._spec_tree_levels(plan.spec, 3, 32768)["tree_leaf_reads"] \
+        == 88_473_600 + 39_321_600
 
 
 @pytest.mark.parametrize("strategy,kept", [("auto", 3 * 2 * 3 * (2 + 3)), ("all", 0)])

@@ -427,3 +427,55 @@ def test_boosted_levels_keep_the_bins_off_the_lanes(scale_features, one_chip):
     assert not padded, padded[:3]
     relayout = _relayout_bytes(buffers)
     assert relayout and max(relayout.values()) < 0.6e9, relayout
+
+
+def test_forest_chunk_reads_its_leaves_without_a_gather(one_chip, monkeypatch):
+    """One depth-12 chunk of the ``scale-500-multiclass`` cell, cut to the
+    chunk: one 50-tree forest x 32,768 rows x 10 classes on its 28 kept
+    columns, through ``ops/sweep._forest_group_scores`` under its
+    ``scores.forest`` scope.  The program the v5e's compiler makes reads the
+    chunk's leaves under ``trees.leaves`` and holds no gather that hands back
+    a [chunk, rows] plane (the parent's ten ``take_along_axis`` a chunk, 8.85e8
+    lookups a step: PERF.md, PR 34) — the one gather left takes whole rows of
+    the transposed matrix, each tree's kept columns; and the row block's
+    one-hot and product cost the chunk no memory: its temporaries are no
+    larger than with the parent's read in the helper's place (measured here:
+    1,743,680,000 B against 1,744,292,864 B)."""
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.ops import sweep, trees as Tr
+
+    n, d, c, T, depth = 32768, 760, 10, 50, 12
+    group = ((0,), depth, T, 0, N_BINS, np.sqrt(d) / d, 1.0, True, 42, 256,
+             False, Tr.balanced_chunk(T, Tr.forest_chunk_size(
+                 depth, N_BINS, d, c, 256, n_rows=n,
+                 n_kept=Tr.n_kept(d, np.sqrt(d) / d)), group=T), 0, 1)
+    assert group[11] == T          # the plan's own cut: a forest a chunk
+
+    def gathered(leaf_val, row_node):
+        return jnp.stack([jnp.take_along_axis(leaf_val[:, :, j], row_node, axis=1)
+                          for j in range(leaf_val.shape[-1])], axis=1)
+
+    def compiled_chunk():
+        def chunk(Xb, y, train_w, blob):
+            with jax.named_scope("scores.forest"):
+                return sweep._forest_group_scores(group, (Xb,), y, train_w,
+                                                  blob, c)
+
+        S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+        return jax.jit(chunk).lower(
+            S((n, d), np.int8), S((n,), np.float32), S((1, n), np.float32),
+            S((2,), np.float32)).compile()
+
+    # all of this program is the chunk, under ``scores.forest``
+    planes = re.compile(rf"= \w+\[{T},{n}\]\S* gather\(")
+    ours = compiled_chunk()
+    _fits(ours)
+    text = ours.as_text()
+    assert "trees.leaves" in text
+    assert not planes.search(text)
+    monkeypatch.setattr(Tr, "read_leaves", gathered)
+    parents = compiled_chunk()
+    assert len(planes.findall(parents.as_text())) == c     # the pattern finds them
+    assert ours.memory_analysis().temp_size_in_bytes \
+        <= parents.memory_analysis().temp_size_in_bytes

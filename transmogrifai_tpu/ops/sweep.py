@@ -265,14 +265,13 @@ def _forest_group_scores(group, xbs, y, train_w, blob, out_c: int, rs=None):
             exact_cap=exact_cap, return_row_node=True,
             axis_name=_rs_axis(rs))
         # growth routes EVERY row (weights only gate histograms), so
-        # row_node already holds each row's leaf — reading leaf_val there
-        # replaces the depth-step pointer walk that dominated the fragment
-        # (measured 123-692 ms walk vs ~20 ms take at 900 trees).  A plane a
-        # class, rows minor: one [chunk, n, c] read would lay the c classes
-        # on the TPU's 128 lanes (12.8 x its bytes at c = 10, PERF.md PR 33)
-        leaf = jnp.stack([jnp.take_along_axis(tree.leaf_val[:, :, j],
-                                              row_node, axis=1)
-                          for j in range(tree.leaf_val.shape[-1])], axis=1)
+        # row_node already holds each row's leaf: no depth-step pointer walk.
+        # The leaves are read by selection, a plane a class with the rows
+        # minor, [chunk, c, n] — as per-element gathers they were ~8.7 s of
+        # the ten-class grid's 19.3 s step on a v5e (PERF.md, PR 34), and a
+        # [chunk, n, c] read would lay the c classes on the TPU's 128 lanes
+        # (12.8 x its bytes at c = 10, PERF.md PR 33)
+        leaf = Tr.read_leaves(tree.leaf_val, row_node)
         return leaf.reshape((chunk // unit, unit) + leaf.shape[1:]).sum(axis=1)
 
     sums = lax.map(one_chunk, (wt.reshape(-1, chunk, n),
@@ -669,7 +668,7 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
     if chain:
         entry["gbt_chain"] = chain
     _sweep_scope.append("launches", entry)
-    for name, levels in _spec_tree_levels(spec, F).items():
+    for name, levels in _spec_tree_levels(spec, F, n).items():
         _sweep_scope.inc(name, levels)
     with trace.span("sweep.launch", shards=1, candidates=C,
                     split=bool(split)):
@@ -789,7 +788,8 @@ _sweep_scope = obs_registry.scope("sweep", defaults={
     "pruned_candidates": 0, "full_candidates": 0, "checkpoint_skips": 0,
     "hedges_fired": 0, "hedge_wasted_s": 0.0, "asha_rungs": [],
     "sweep_pack_count": 0, "launches_avoided": 0,
-    "tree_level_builds": 0, "tree_beam_levels": 0, "tree_kept_levels": 0})
+    "tree_level_builds": 0, "tree_beam_levels": 0, "tree_kept_levels": 0,
+    "tree_leaf_reads": 0})
 obs_registry.register_provider("sweep", lambda: run_stats())
 
 #: per-(name, spec, device, arg-signature) AOT executables.  jit's own cache
@@ -879,11 +879,13 @@ def run_stats() -> Dict[str, Any]:
             "score_block_bytes": max(
                 (e.get("score_block_bytes", 0) for e in launches), default=0),
             # tree levels grown by the single-device launches since reset,
-            # those that ranked a full frontier, and those grown on a
-            # tree's kept features alone (_spec_tree_levels)
+            # those that ranked a full frontier, those grown on a tree's
+            # kept features alone, and the leaf values their trees handed
+            # the training rows (_spec_tree_levels)
             "tree_level_builds": _sweep_scope.get("tree_level_builds"),
             "tree_beam_levels": _sweep_scope.get("tree_beam_levels"),
             "tree_kept_levels": _sweep_scope.get("tree_kept_levels"),
+            "tree_leaf_reads": _sweep_scope.get("tree_leaf_reads"),
             "sweep_pack_count": _sweep_scope.get("sweep_pack_count"),
             "launches_avoided": _sweep_scope.get("launches_avoided"),
             # sequential non-overlapped GBT launch-levels on the critical
@@ -977,32 +979,37 @@ def _spec_gbt_chain(spec) -> Optional[Dict[str, int]]:
     return {"steps": steps, "levels": levels}
 
 
-def _spec_tree_levels(spec, F: int) -> Dict[str, int]:
+def _spec_tree_levels(spec, F: int, n: int = 0) -> Dict[str, int]:
     """Tree levels one launch of ``spec`` grows over ``F`` folds:
     ``tree_level_builds`` (levels x trees: one level histogram each),
     ``tree_beam_levels`` (those at which a full frontier ranked its splits
     by gain and kept half: ``frontier`` slots, not provably enough) and
     ``tree_kept_levels`` (those built on a compacted feature axis, the
     tree's kept features alone: forests with a subset fraction under 1;
-    boosting builds full width)."""
-    builds = beam = kept = 0
+    boosting builds full width); and, over its ``n`` rows,
+    ``tree_leaf_reads``: the leaf values its grown trees hand their training
+    rows (trees x rows x channels, ``ops.trees.read_leaves``)."""
+    builds = beam = kept = reads = 0
     for frag in spec[1]:
         if frag[0] == "forest":
+            c = frag[1]
             groups = [(len(g[0]) * g[2], g[1], g[9], g[10], g[5])
                       for g in frag[2]]
         elif frag[0] == "gbt":
+            c = frag[2]
             groups = [(len(g[0]) * g[1], g[2], g[8], g[9], 1.0)
                       for g in frag[3]]
         else:
             continue
         for trees, depth, frontier, exact_cap, frac in groups:
             builds += F * trees * depth
+            reads += F * trees * n * c
             if not exact_cap:
                 beam += F * trees * max(depth - (frontier.bit_length() - 1), 0)
             if frac < 1.0:
                 kept += F * trees * depth
     return {"tree_level_builds": builds, "tree_beam_levels": beam,
-            "tree_kept_levels": kept}
+            "tree_kept_levels": kept, "tree_leaf_reads": reads}
 
 
 def _max_gbt_chain(specs) -> Optional[Dict[str, int]]:

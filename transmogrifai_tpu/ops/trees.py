@@ -302,6 +302,58 @@ def predict_tree(Xb, tree: Tree, max_depth: int) -> jax.Array:
     return tree.leaf_val[node]
 
 
+def read_leaves(leaf_val, row_node) -> jax.Array:
+    """f32[T, c, n]: ``leaf_val[t, row_node[t, i], :]`` for every tree and
+    training row, rows minor — what ``grow_forest``'s ``row_node`` is for
+    (growth routes every row, so a tree's prediction on its training rows
+    needs no pointer walk).  Read by SELECTION, not by gather: a per-element
+    gather runs at ~1e8 elements a second on the TPU, and the default grids
+    ask for 1e8-1e9 of them a fit (PERF.md, PR 34).
+
+    A node index is (block of ``lanes`` <= 128 nodes, lane).  The row's lane
+    picks its entry of every block at once — ONE one-hot contraction,
+    [T, blocks * c, lanes] x [T, lanes, rows], a single K tile whatever the
+    pool size P — and a select-sum over the blocks (``route_block``'s
+    ``pick``) keeps the row's own.  One term of each sum is non-zero and the
+    contraction is ``_EXACT``, so the float32 leaf comes back bit for bit
+    (-0.0 as +0.0).  The one-hot exists a row block at a time
+    (``hist_blocks``: its own quarter of the chunk budget, so
+    ``forest_chunk_size`` does not count it), and each block's values land
+    in the output where they belong; the last block is moved back to end at
+    row n, so nothing is padded or sliced and the rows it shares with the
+    block before it are written twice with the same numbers.
+
+    The table must be finite: 0 * NaN poisons a selection.  ``grow_forest``
+    keeps it so (dead slots and unclaimed pool entries hold 0.0, a root
+    without weight reads 0.0).  A non-finite entry is not hidden and never
+    turned into a wrong number: every row of that tree whose node lies in
+    the entry's block reads NaN in the entry's channel, whether or not the
+    entry is the row's own, and every other read is exact."""
+    T, P, c = leaf_val.shape
+    n = row_node.shape[1]
+    lanes = min(P, 128)
+    nblk = -(-P // lanes)
+    table = jnp.pad(leaf_val, ((0, 0), (0, nblk * lanes - P), (0, 0)))
+    table = table.reshape(T, nblk, lanes, c).transpose(0, 1, 3, 2) \
+        .reshape(T, nblk * c, lanes)
+    nb, bn = hist_blocks(n, T * lanes, T * nblk * c)
+    iota_l = jnp.arange(lanes)[None, :, None]
+    iota_b = jnp.arange(nblk)[None, :, None, None]
+
+    def block(i, out):
+        start = jnp.minimum(i * bn, n - bn)
+        node = lax.dynamic_slice_in_dim(row_node, start, bn, axis=1)  # [T, bn]
+        lane_hot = (node % lanes)[:, None, :] == iota_l        # [T, lanes, bn]
+        part = jnp.einsum("tql,tlr->tqr", table,
+                          lane_hot.astype(jnp.float32), precision=_EXACT)
+        mine = (node // lanes)[:, None, None, :] == iota_b  # [T, nblk, 1, bn]
+        vals = jnp.where(mine, part.reshape(T, nblk, c, bn), 0.0).sum(axis=1)
+        return lax.dynamic_update_slice_in_dim(out, vals, start, axis=2)
+
+    with jax.named_scope("trees.leaves"):
+        return lax.fori_loop(0, nb, block, jnp.zeros((T, c, n), jnp.float32))
+
+
 # ---------------------------------------------------------------------------
 # The level grower — the whole tree chunk in ONE GEMM per level
 #
@@ -686,7 +738,7 @@ def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
     (``hist_blocks``).  Node records hold original feature indices in
     either layout.  Returns Tree with leading [T] axis (+ row_node on
     request: ``leaf_val[row_node]`` is a tree's prediction on the training
-    rows, sparing boosting a predict walk).
+    rows, which ``read_leaves`` reads without a predict walk).
     """
     n, d = Xb.shape
     per_tree = gh_t is not None
@@ -707,7 +759,10 @@ def grow_forest(Xb, g, h, w_t, feat_t, max_depth: int, n_bins: int,
     gw_sum = mesh_psum(gw_sum, axis_name)
     hw_sum = mesh_psum(hw_sum, axis_name)
     P = _pool_size(max_depth, frontier)
-    root_val = -gw_sum / (hw_sum + reg_lambda_t)[:, None]
+    # a tree without weight (padding; reg_lambda 0) would read 0 / 0: its
+    # leaves are read by selection, which a NaN poisons (``read_leaves``)
+    root_den = (hw_sum + reg_lambda_t)[:, None]
+    root_val = jnp.where(root_den != 0, -gw_sum / root_den, 0.0)
     nodes = jnp.tile(jnp.asarray([-1, 0, 0, 0], jnp.int32), (T, P, 1))
     leaf_val = jnp.zeros((T, P, c), jnp.float32).at[:, 0].set(root_val)
 
@@ -1031,10 +1086,8 @@ def _gbt_impl(Xb, y, w, row_w_rounds, feat_mask_rounds, loss: str, n_rounds: int
             exact_cap=exact_cap, return_row_node=True,
             axis_name=axis_name)
         # row_node is each row's resting node — no predict walk needed
-        leaves = jnp.take_along_axis(
-            trees.leaf_val, row_node[:, :, None].repeat(c, axis=2),
-            axis=1)                                # [K, n, c]
-        F = F + (eta / K) * leaves.sum(axis=0)
+        leaves = read_leaves(trees.leaf_val, row_node)         # [K, c, n]
+        F = F + (eta / K) * leaves.sum(axis=0).T
         return F, trees
 
     F, trees = lax.scan(step_fn, F0, (rw_s, fm_s))
@@ -1144,11 +1197,10 @@ def _gbt_batch_impl(Xb, y, w_batch, row_w_rounds, feat_mask_rounds, loss: str,
             mig_t=jnp.repeat(min_info_gain_b, K),
             exact_cap=exact_cap, return_row_node=True,
             gh_t=gh_T, axis_name=axis_name)
-        # leaf lookup via one gather per step (row_node tracks leaves)
-        leaves = jnp.take_along_axis(
-            tree.leaf_val, row_node[:, :, None].repeat(c, axis=2), axis=1)
-        leaves = leaves.reshape(B, K, n, c).sum(axis=1)
-        F = F + (eta_b / K)[:, None, None] * leaves
+        # row_node tracks each row's leaf: one selection a step
+        leaves = read_leaves(tree.leaf_val, row_node)        # [B * K, c, n]
+        leaves = leaves.reshape(B, K, c, n).sum(axis=1)
+        F = F + (eta_b / K)[:, None, None] * jnp.swapaxes(leaves, 1, 2)
         return F, None
 
     F, _ = lax.scan(step_fn, F0, (rw_s, fm_s))
