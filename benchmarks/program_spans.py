@@ -7,13 +7,15 @@ for every span, so a traced step's ``.xplane.pb`` holds them on the host
 plane, on the device ops' clock, their attributes as event stats; and
 ``ops/sweep.py`` / ``ops/metrics.py`` wrap each model family and each part of
 the metric pass in a ``jax.named_scope``, which the compiler carries into
-every op's name path.  ``trace_reduce.read_xplane`` keeps neither (only
-``bench.`` spans, no stats), so this module reads the same file again.
+every op's name path.  ``trace_reduce.read_xplane`` keeps the spans' names
+and times alone (no stats) and no name path, so this module reads the same
+file again.
 
 On a TPU v5e trace the name path is the ``tf_op`` stat of the op's EVENT
 METADATA (``jit(_run_metrics)/metrics.binary/jit(_binary_grid_metrics)/
-vmap(vmap(metrics.rank))/jit(searchsorted)/vmap()/while/body/closed_call/
-gather:``), not of the event: the event's name is its HLO line without
+vmap(vmap(metrics.sort))/gather:``, ``jit(_run_scores)/scores.forest/while/
+body/trees.hist/while/body/dot_general:``), not of the event: the event's
+name is its HLO line without
 ``metadata={...}`` and its own stats are ``device_offset_ps``,
 ``device_duration_ps`` and ``Time Scale Multiplier`` alone.
 ``jax.profiler.ProfileData`` shows event stats only, so the file is decoded
@@ -52,10 +54,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 #: where ``run.py`` has the profiler write (still on disk when readers run)
 TRACE_DIR = os.path.join(os.path.dirname(HERE), ".bench_trace")
 
-#: roots of the program's span names (``obs/trace.py`` call sites), and the
-#: benchmark's own, which the innermost-span rule needs as the outer cover
-ROOTS = ("selector.", "sweep.", "devcache.", "stream.", "stage.", "serve.",
-         trace_reduce.SPAN_PREFIX)
 NO_SPAN = "(no span)"
 
 #: the phases of one selector fit that feed the device: it idles until the
@@ -67,8 +65,9 @@ FEED = ("selector.split", "selector.prepare", "selector.gather",
 #: refit, its train and holdout evaluation
 REFIT_EVAL = ("sweep.gather", "selector.refit", "selector.evaluate")
 
-#: a named scope as ``ops/sweep.py`` and ``ops/metrics.py`` write them
-SCOPE = re.compile(r"\b(?:scores|metrics)\.[a-z_]+")
+#: a named scope as ``ops/sweep.py``, ``ops/metrics.py`` and ``ops/trees.py``
+#: write them
+SCOPE = re.compile(r"\b(?:scores|metrics|trees)\.[a-z_]+")
 #: the stat of an op's event metadata that holds its name path
 SCOPE_STAT = "tf_op"
 
@@ -227,7 +226,7 @@ def read_xplane(path: str) -> Dict[str, Any]:
                 spans.extend(
                     (names[mid], a, b, _stats(stats, stat_names))
                     for mid, a, b, stats in _events(line)
-                    if names.get(mid, "").startswith(ROOTS))
+                    if names.get(mid, "").startswith(trace_reduce.SPAN_PREFIX))
     return {"spans": spans, "ops": ops}
 
 

@@ -12,6 +12,7 @@ Times are seconds on the trace's own clock.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -23,8 +24,12 @@ Named = Tuple[str, float, float]
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-#: host spans this prefix marks as the benchmark's own (run.py, entries)
-SPAN_PREFIX = "bench."
+#: roots of the host spans kept, here and by ``program_spans``: the
+#: benchmark's own (run.py, entries), the outer cover of every idle gap, and
+#: the program's (``obs/trace.py`` call sites), so that a gap is named by what
+#: the program was doing and not by ``bench.step`` alone
+SPAN_PREFIX = ("bench.", "selector.", "sweep.", "devcache.", "stage.",
+               "stream.", "serve.")
 OP_NAME_CHARS = 120
 
 
@@ -138,10 +143,19 @@ def top_ops(events: Sequence[Named], window: Interval, k: int = 10
 def idle_gap_table(events: Sequence[Named], window: Interval,
                    spans: Sequence[Named], k: int = 10) -> List[List[Any]]:
     """``[[span name, seconds], ...]``: idle seconds summed by the host span
-    covering each gap, largest first."""
+    covering each gap (``name_gap``), largest first.  A tree step leaves
+    hundreds of thousands of gaps between its ops and nearly all lie between
+    two neighbouring span edges, where every span covers all of a gap or none
+    of it: those stretches are named once."""
+    edges = sorted({t for _, a, b in spans for t in (a, b)})
+    stretch: Dict[int, str] = {}
     tot: Dict[str, float] = {}
     for g in gaps(events, window):
-        n = name_gap(g, spans)
+        i = bisect.bisect_right(edges, g[0])
+        if i < len(edges) and edges[i] < g[1]:     # a span edge inside the gap
+            n = name_gap(g, spans)
+        else:
+            n = stretch.get(i) or stretch.setdefault(i, name_gap(g, spans))
         tot[n] = tot.get(n, 0.0) + (g[1] - g[0])
     return [[n, s] for n, s in sorted(tot.items(), key=lambda t: -t[1])[:k]]
 

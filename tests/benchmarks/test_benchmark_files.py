@@ -51,13 +51,16 @@ def test_metric_entry(metric):
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda c: c["name"])
-def test_cell_names_files_that_exist(cell):
+def test_cell_names_files_that_exist(cell, bench):
+    """Each committed cell, also where a later PR has appended to the lists
+    (``conftest.appended``): what it reports and finds is the same."""
+    assert cell in bench["workloads"]
     workload = bench_run.load_json(
         os.path.join(BENCH_DIR, "workloads", cell["name"] + ".json"))
     assert workload["config"] == cell["config"]
     assert workload["chips"] == cell["chips"]
     assert workload["traffic"]["name"] == cell["traffic"]
-    cfg_entry = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
+    cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = bench_run.load_json(os.path.join(ROOT, cfg_entry["file"]))
     assert all(k in cfg for k in cfg_entry["reduced"])
     assert cfg["reduced"] == cfg_entry["reduced"]
@@ -65,7 +68,7 @@ def test_cell_names_files_that_exist(cell):
     entry = bench_run.load_module("entries", workload["entry"])
     assert all(hasattr(entry, f) for f in
                ("setup", "step", "work", "answers", "shapes", "rehearsal_config"))
-    e2e, layers = bench_run.metrics_for(BENCH, cell["name"])
+    e2e, layers = bench_run.metrics_for(bench, cell["name"])
     names = [m["name"] for m in e2e]
     assert "setup_s" in names and len(names) >= 2 and layers
     for kind, group in (("end_to_end", e2e), ("layers", layers)):
@@ -76,11 +79,38 @@ def test_cell_names_files_that_exist(cell):
     assert all(v is not None for v in check["limits"].values())
 
 
-@pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
-def test_moves_is_reported_by_each_of_its_cells(metric):
-    moved = next(m for m in BENCH["end_to_end"] if m["name"] == metric["moves"])
-    for cell in metric.get("workloads", CELLS):
-        assert cell in moved.get("workloads", CELLS)
+@pytest.mark.parametrize("name", [m["name"] for m in BENCH["per_layer"]])
+def test_moves_is_reported_by_each_of_its_cells(name, bench):
+    (metric,) = [m for m in bench["per_layer"] if m["name"] == name]
+    moved = next(m for m in bench["end_to_end"] if m["name"] == metric["moves"])
+    cells = [c["name"] for c in bench["workloads"]]
+    for cell in metric.get("workloads", cells):
+        assert cell in moved.get("workloads", cells)
+
+
+def test_what_a_later_pr_appends_is_found_and_moves_nothing(later_pr):
+    """``conftest.appended`` does what the driver lets a program PR do: every
+    list of the committed file is the head of its list in the copy; the later
+    cell reports ``fits_per_s`` and every reader whose list it joined, and
+    its own; the cells that were there report what they reported."""
+    bench, cell, metric = later_pr
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        for old, new in zip(BENCH[key], bench[key]):
+            if "workloads" in old:
+                head = new["workloads"][:len(old["workloads"])]
+                assert dict(new, workloads=head) == old
+                assert new["workloads"][len(head):] == [cell]
+            else:
+                assert new == old
+    assert [len(bench[k]) - len(BENCH[k]) for k in
+            ("configs", "workloads", "end_to_end", "per_layer")] == [1, 1, 0, 1]
+    e2e, layers = bench_run.metrics_for(bench, cell)
+    assert [m["name"] for m in e2e] == ["fits_per_s", "setup_s"]
+    joined = [m["name"] for m in BENCH["per_layer"] if "workloads" in m]
+    assert [m["name"] for m in layers] == joined + [metric]
+    for name in CELLS:
+        assert [[m["name"] for m in g] for g in bench_run.metrics_for(bench, name)] \
+            == [[m["name"] for m in g] for g in bench_run.metrics_for(BENCH, name)]
 
 
 def test_a_metric_without_workloads_follows_what_it_moves():
@@ -275,6 +305,61 @@ def test_busy_union_idle_gaps_and_names():
     assert trace_reduce.top_ops(OPS, w, k=2) == [["fusion.1", 2.5], ["fusion.2", 2.0]]
     table = dict(map(tuple, trace_reduce.idle_gap_table(OPS, w, SPANS)))
     assert table == {"bench.step": pytest.approx(3.5), "bench.inner.pull": 2.0}
+
+
+def test_the_programs_spans_name_the_gaps_and_move_no_number():
+    """One trace read both ways: with the benchmark's spans alone (what
+    ``SPAN_PREFIX`` kept before PR 36) and with the program's beside them.
+    The window is still found by ``bench.step``; busy seconds, window and the
+    idle share are the same to the digit; only the gaps' names differ."""
+    program = [("stage.fit", 0.0, 10.0), ("selector.fit", 0.0, 9.9),
+               ("selector.gather", 0.1, 0.9), ("sweep.plan", 0.9, 1.0),
+               ("sweep.quantize", 0.92, 0.99), ("devcache.upload", 0.95, 0.98),
+               ("sweep.gather", 1.0, 7.0), ("selector.evaluate", 7.2, 9.4),
+               ("other.library", 0.0, 10.0)]
+    assert all(n.startswith(trace_reduce.SPAN_PREFIX) for n, _, _ in program[:-1])
+    assert not program[-1][0].startswith(trace_reduce.SPAN_PREFIX)
+    kept = [s for s in SPANS + program if s[0].startswith(trace_reduce.SPAN_PREFIX)]
+    read = lambda spans: {"devices": {0: {"ops": OPS, "modules": []}},  # noqa: E731
+                          "host_spans": spans, "inventory": {}}
+    before = trace_reduce.summarize(read(SPANS), "bench.step")
+    after = trace_reduce.summarize(read(kept), "bench.step")
+    for key in ("window", "window_s", "busy_s"):
+        assert after[key] == before[key], key
+    assert after["breakdown"]["device_ops"] == before["breakdown"]["device_ops"]
+    idle = bench_run.load_module("layers", "device_idle_pct.sweep").read
+    ns = lambda s: argparse.Namespace(trace=s)  # noqa: E731
+    assert idle(ns(after)) == idle(ns(before)) == pytest.approx(55.0)
+    named = lambda s: dict(map(tuple, s["breakdown"]["idle_gaps"]))  # noqa: E731
+    assert named(before) == {"bench.step": pytest.approx(3.5), "bench.inner.pull": 2.0}
+    # gaps [7, 9.5], [4, 6], [0, 1]: each to the innermost span over half of it
+    assert named(after) == {"selector.evaluate": 2.5, "bench.inner.pull": 2.0,
+                            "selector.gather": 1.0}
+    assert sum(named(after).values()) == pytest.approx(sum(named(before).values()))
+
+
+def test_gaps_between_span_edges_are_named_as_one_by_one():
+    """``idle_gap_table`` names a stretch between two span edges once; the
+    answer is ``name_gap``'s, gap by gap, on 2,000 ops under nested,
+    overlapping and abutting spans (edges on op boundaries among them)."""
+    rng = __import__("random").Random(36)
+    t, ops = 0.0, []
+    for k in range(2000):
+        d, g = rng.choice((0.25, 0.5, 1.0)), rng.choice((0.0, 0.125, 0.5, 2.0))
+        ops.append((f"op.{k % 7}", t, t + d))
+        t += d + g
+    w = (-1.0, t + 1.0)
+    cut = lambda: rng.choice(ops)[rng.choice((1, 2))] + rng.choice((0.0, 0.0625))  # noqa: E731
+    spans = [("bench.step", w[0], w[1]), ("selector.fit", 0.0, t)]
+    for k in range(60):
+        a, b = sorted((cut(), cut()))
+        spans.append((f"sweep.s{k}", a, b + rng.choice((0.0, 40.0))))
+    want = {}
+    for g in trace_reduce.gaps(ops, w):
+        n = trace_reduce.name_gap(g, spans)
+        want[n] = want.get(n, 0.0) + g[1] - g[0]
+    got = dict(map(tuple, trace_reduce.idle_gap_table(ops, w, spans, k=1000)))
+    assert len(got) > 20 and got == pytest.approx(want)
 
 
 def test_program_seconds_and_summary():

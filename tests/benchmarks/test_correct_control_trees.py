@@ -50,10 +50,12 @@ def small(tmp_path_factory):
     bench = bench_run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     sweep = bench_run.load_json(os.path.join(bench_dir, "workloads", CELL + ".json"))
     sweep["config"] = "small-trees"
-    # the cell's own limits but one: on the CPU the program sums its
-    # histograms by scatter, in another order than the reference's products,
-    # and a depth-10 boosted fold moves by 4e-4 to 5e-4 (PERF.md section 7);
-    # on the chip, where the limit was set, it reads 1e-7
+    # the cell's own limits but one: the CPU backend adds the histogram
+    # GEMM's float32 products in another order than the chip does (the
+    # program is the same: one grower since PR 31), a depth-10 tree has many
+    # near-tied gains, and a boosted fold moves by 4e-4 to 1.4e-3 here
+    # (PERF.md section 7: "it is not the scatter"); on the chip, where the
+    # limit was set, it reads 1e-7
     assert sweep["correct"]["limits"]["xgb_fold_gap"] < 2e-3
     sweep["correct"]["limits"]["xgb_fold_gap"] = 2e-3
     with open(os.path.join(bench_dir, "workloads", SMALL + ".json"), "w") as f:

@@ -16,9 +16,6 @@ BENCH_DIR = os.path.join(ROOT, "benchmarks")
 
 from benchmarks import program_spans as ps, run as bench_run, trace_reduce  # noqa: E402
 
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
-
 #: the metrics whose readers go through program_spans
 NEW = ("sweep_feed_idle_s.sweep", "refit_eval_idle_s.sweep",
        "unattributed_idle_s.sweep", "sweep_h2d_bytes.sweep",
@@ -199,7 +196,7 @@ def test_read_xplane_agrees_with_profile_data_on_a_recorded_trace(tmp_path):
         if plane.name.startswith("/host"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(ps.ROOTS):
+                    if e.name.startswith(trace_reduce.SPAN_PREFIX):
                         want[e.name] = (e.start_ns / 1e9, e.end_ns / 1e9,
                                         dict(e.stats))
     got = ps.read_xplane(path)
@@ -210,6 +207,12 @@ def test_read_xplane_agrees_with_profile_data_on_a_recorded_trace(tmp_path):
         assert stats == want[name][2]
     (upload,) = [s for s in got["spans"] if s[0] == "devcache.upload"]
     assert upload[3] == {"bytes": 2**40, "tag": "base"}
+    # trace_reduce's reader keeps the same spans (names and times alone), so
+    # that ``breakdown.idle_gaps`` names what the program was doing
+    kept = sorted(trace_reduce.read_xplane(path)["host_spans"])
+    assert [s[0] for s in kept] == ["devcache.upload", "selector.fit"]
+    for (_, a, b), s in zip(kept, sorted(got["spans"])):
+        assert (a, b) == pytest.approx(s[1:3], abs=1e-9)
 
 
 def _run_like(spans, ops, monkeypatch, capsys=None):
@@ -263,9 +266,11 @@ def test_readers_are_silent_on_a_program_without_spans(monkeypatch, capsys):
 
 
 @pytest.mark.parametrize("name", NEW)
-def test_new_entry_has_its_reader_file(name):
-    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == ["scale-500.sweep"]
+def test_new_entry_has_its_reader_file(name, bench):
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    # the first cell they read in; a cell whose trace holds the spans or the
+    # scope joins after it (the two tree cells did, PR 36)
+    assert entry["workloads"][0] == "scale-500.sweep"
     assert entry["moves"] == "fits_per_s" and entry["better"] == "lower"
     assert entry["layer"] in ("fused sweep", "kernels")
     assert os.path.isfile(os.path.join(BENCH_DIR, "layers", name + ".py"))

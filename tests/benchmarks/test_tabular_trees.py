@@ -1,8 +1,10 @@
 """The plain reference of the tree families (``references/tabular_trees.py``)
 against the program, through the fused sweep, on a seeded 2,000 x 40 table;
 the required-operation counts of ``trees_ops_count`` on a hand-worked shape.
-CPU: the program builds its histograms with ``segment_sum`` here, the
-reference with its one-hot products, so the two share no formulation."""
+CPU: the program is the one the chip runs (one grower since PR 31: whole
+forests and boosted rounds a level at a time, the histograms a GEMM over row
+blocks), the reference grows one tree and one level at a time from exact
+one-hot products; the two share no code."""
 import os
 import sys
 

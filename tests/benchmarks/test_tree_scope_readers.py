@@ -19,9 +19,6 @@ BENCH_DIR = os.path.join(ROOT, "benchmarks")
 
 from benchmarks import program_spans as ps, run as bench_run  # noqa: E402
 
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
-
 READERS = {"tree_route_device_s": "trees.route",
            "tree_leaf_device_s": "trees.leaves"}
 TREE_CELLS = ["scale-500-trees.sweep", "scale-500-multiclass.sweep"]
@@ -29,14 +26,19 @@ WINDOW = (0.0, 10.0)
 
 
 @pytest.mark.parametrize("name", sorted(READERS))
-def test_entry_and_file(name):
-    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert entry == {"name": name, "unit": "s", "better": "lower",
-                     "source": "device_trace", "layer": "kernels",
-                     "moves": "fits_per_s", "workloads": TREE_CELLS}
-    # added at the end of the list, after every metric the benchmark had
-    assert [m["name"] for m in BENCH["per_layer"][-2:]] == [
-        "tree_route_device_s", "tree_leaf_device_s"]
+def test_entry_and_file(name, bench):
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    assert dict(entry, workloads=None) == {
+        "name": name, "unit": "s", "better": "lower", "source": "device_trace",
+        "layer": "kernels", "moves": "fits_per_s", "workloads": None}
+    # the two tree cells first; a later tree cell joins after them
+    assert entry["workloads"][:len(TREE_CELLS)] == TREE_CELLS
+    # route directly before leaves, both after the last metric the benchmark
+    # had before them; what a later PR appends after them is its own
+    order = [m["name"] for m in bench["per_layer"]]
+    route = order.index("tree_route_device_s")
+    assert order[route + 1] == "tree_leaf_device_s"
+    assert order.index("mc_step_mfu") < route
     assert os.path.isfile(os.path.join(BENCH_DIR, "layers", name + ".py"))
     assert callable(bench_run.load_module("layers", name).read)
 
@@ -102,6 +104,10 @@ def test_both_read_their_scopes_from_a_device_plane(tmp_path, monkeypatch, capsy
     assert read["tree_leaf_device_s"] == pytest.approx(0.4375)
     lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
     assert [ln["phase"] for ln in lines] == ["program_spans"]    # parsed once
+    # the fact line lists the tree scopes beside the families' (``SCOPE``)
+    assert lines[0]["scopes"] == pytest.approx({
+        "scores.forest": 3.375, "scores.gbt": 0.5625, "trees.hist": 2.0,
+        "trees.route": 1.5, "trees.leaves": 0.4375})
 
 
 def test_the_parent_reads_its_route_and_no_leaves(tmp_path, monkeypatch):
