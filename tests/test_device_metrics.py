@@ -8,6 +8,7 @@ OpBinaryClassificationEvaluator.scala:56, OpRegressionEvaluator.scala:55.
 """
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -153,6 +154,120 @@ def test_binary_metrics_program_holds_no_search_loop():
     assert "metrics.rank" in text            # the scope the benchmark reads
     assert not re.search(r"\bwhile\b", text), "a loop in the metric pass"
     assert "searchsorted" not in text
+
+
+def test_binary_metrics_program_holds_no_gather_and_one_sort():
+    """The pass reads its operands in score order by sorting them with the
+    key, not by permuting them after an ``argsort``, and the AuPR curve
+    selects the recall at the previous threshold instead of reading it at a
+    computed index: a per-element gather costs the v5e 0.5-1.3 s over the
+    ``[5, 64, 180224]`` block where a streaming pass costs ~1 ms (PERF.md
+    §6)."""
+    lowered = _binary_grid_metrics.lower(
+        jnp.zeros(33), jnp.zeros((2, 3, 33)), jnp.zeros((2, 33)),
+        jnp.zeros(3))
+    text = lowered.as_text(debug_info=True)
+    assert "gather" not in text, "a per-element gather in the metric pass"
+    assert len(re.findall(r"stablehlo\.sort\b", text)) == 1
+    for scope in ("metrics.sort", "metrics.rank", "metrics.curve"):
+        assert scope in text, scope
+
+
+def _binary_one_by_gather(y, s, vm, strict):
+    """The pass as it was written before it sorted its operands: ``argsort``
+    and three permutation gathers, the AuPR curve's previous recall read at a
+    ``cummax`` index.  Kept here as the reference the pass must equal bit for
+    bit."""
+    n = y.shape[0]
+    neg_inf = jnp.float32(-jnp.inf)
+    sv = jnp.where(vm > 0, s, neg_inf)
+    npos = (vm * y).sum()
+    nneg = (vm * (1.0 - y)).sum()
+    n_exc = (1.0 - vm).sum()
+    order = jnp.argsort(sv)
+    ss, ys, vs = sv[order], y[order], vm[order]
+    lo, hi = _tie_bounds(ss)
+    rank_val = (lo.astype(jnp.float32) + hi.astype(jnp.float32)
+                + 1.0) * 0.5 - n_exc
+    r_pos = (vs * ys * rank_val).sum()
+    auroc = jnp.where(
+        (npos > 0) & (nneg > 0),
+        (r_pos - npos * (npos + 1.0) * 0.5) / jnp.maximum(npos * nneg, 1.0),
+        0.0)
+    sd, yd, vd = ss[::-1], ys[::-1], vs[::-1]
+    tp = jnp.cumsum(yd * vd)
+    fp = jnp.cumsum((1.0 - yd) * vd)
+    nxt = jnp.concatenate([sd[1:], jnp.full((1,), neg_inf, sd.dtype)])
+    distinct = (sd != nxt) & (sd > neg_inf)
+    prec_c = tp / jnp.maximum(tp + fp, 1.0)
+    rec_c = tp / jnp.maximum(npos, 1.0)
+    run = jax.lax.cummax(jnp.where(distinct, jnp.arange(n), -1))
+    prev = jnp.concatenate([jnp.full((1,), -1), run[:-1]])
+    r_prev = jnp.where(prev >= 0, rec_c[jnp.maximum(prev, 0)], 0.0)
+    aupr = jnp.where(
+        npos > 0,
+        jnp.where(distinct, prec_c * (rec_c - r_prev), 0.0).sum(), 0.0)
+    pred1 = jnp.where(strict > 0, (s > 0.5), (s >= 0.5)).astype(jnp.float32)
+    tp_c = (vm * y * pred1).sum()
+    fp_c = (vm * (1.0 - y) * pred1).sum()
+    fn_c = (vm * y * (1.0 - pred1)).sum()
+    err = (fp_c + fn_c) / jnp.maximum(npos + nneg, 1.0)
+    precision = jnp.where(tp_c + fp_c > 0,
+                          tp_c / jnp.maximum(tp_c + fp_c, 1.0), 0.0)
+    recall = jnp.where(tp_c + fn_c > 0,
+                       tp_c / jnp.maximum(tp_c + fn_c, 1.0), 0.0)
+    f1 = jnp.where(precision + recall > 0,
+                   2.0 * precision * recall
+                   / jnp.maximum(precision + recall, 1e-30), 0.0)
+    return jnp.stack([auroc, aupr, err, precision, recall, f1])
+
+
+@jax.jit
+def _binary_grid_by_gather(y, scores, val_w, strict_c):
+    per_c = jax.vmap(_binary_one_by_gather, in_axes=(None, 0, None, 0))
+    return jax.vmap(per_c, in_axes=(None, 0, 0, None))(
+        y, scores, val_w, strict_c)
+
+
+def _gather_case(n, kind):
+    """(y f32[n], scores f32[2, 4, n], val_w f32[2, n]): every fold excludes
+    about a third of its rows (score ``-inf`` inside the pass)."""
+    rng = np.random.default_rng(1000 + n)
+    y = rng.integers(0, 2, n).astype(np.float32)
+    scores = rng.random((2, 4, n)).astype(np.float32)
+    val_w = (rng.random((2, n)) > 0.35).astype(np.float32)
+    if kind == "many_ties":
+        scores = np.round(scores, 2)
+    elif kind == "fractional_weights":
+        scores[:, 1] = np.round(scores[:, 1], 1)
+        val_w = val_w * rng.choice(np.float32([0.25, 0.5, 1.0, 0.3]), (2, n))
+    elif kind == "nan_candidates":
+        scores[:, 1, ::3] = np.nan           # a third of the rows
+        scores[:, 2, :] = np.nan             # every row
+    else:
+        assert kind == "few_ties", kind
+    return y, scores, val_w.astype(np.float32)
+
+
+@pytest.mark.parametrize("kind", ("few_ties", "many_ties",
+                                  "fractional_weights", "nan_candidates"))
+@pytest.mark.parametrize("n", (1, 2, 3, 257, 4096, 20000))
+def test_binary_metrics_equal_the_gather_formulation_bit_for_bit(n, kind):
+    """Sorting the operands with the key and selecting the previous recall
+    return the very floats the argsort + gather pass returned: the stable
+    order of equal keys is the row index in both, and a select never
+    rounds.  A shifted ``cummax`` of the recall would not: with fractional
+    weights at n = 4,096 and 20,000 the float32 ``cumsum`` is not monotone,
+    and AuPR moves."""
+    y, scores, val_w = _gather_case(n, kind)
+    strict = np.float32([0, 1, 0, 1])
+    got = np.asarray(_binary_grid_metrics(y, scores, val_w, strict))
+    want = np.asarray(_binary_grid_by_gather(y, scores, val_w, strict))
+    assert got.shape == want.shape == (2, 4, len(BINARY_METRICS))
+    for i, name in enumerate(BINARY_METRICS):
+        np.testing.assert_array_equal(
+            got[..., i].view(np.int32), want[..., i].view(np.int32),
+            err_msg=name)
 
 
 def test_binary_metrics_empty_validation_class():

@@ -296,6 +296,28 @@ def test_phase_b_fused_sweep_fits_one_chip(scale_features, one_chip):
         assert held + 16 * scores_bytes < HBM_BYTES  # room for the metric sort
 
 
+def test_binary_metric_program_at_the_sweep_shape(one_chip):
+    """``_run_metrics`` over the ``scale-500`` cell's ``[5, 64, 180224]``
+    score block: no gather in the compiled program (the argsort's three
+    permutation gathers and the AuPR curve's indexed read were 3.5 s of a
+    7.3 s step on the v5e), and temporaries no larger than the gather form's
+    1,777,358,848 B plus the one ``y`` broadcast to the block that the sort
+    carries.  ~40 s to compile on an 8-core host; the same pass written as
+    ``lax.associative_scan`` took the compiler six minutes there."""
+    from transmogrifai_tpu.ops import sweep
+
+    F, C, n = 5, 64, 180224
+    spec = ("binary", (), (0.0,) * C)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, np.float32,  # noqa: E731
+                                            sharding=one_chip)
+    compiled = sweep._run_metrics.lower(
+        spec, shape(n), shape(F, C, n), shape(F, n)).compile()
+    _fits(compiled)
+    assert not re.search(r"\sgather\(", compiled.as_text())
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1_777_358_848 + F * C * n * 4, temp
+
+
 def test_streamed_chunk_program(scale_features, one_chip):
     """One chunk of the streamed transform program: every fusable stage of
     the fitted DAG, ``chunk_rows`` x 500 raw features in."""

@@ -13,16 +13,17 @@ sweep's wall-clock (round-4 VERDICT weak #2: ~84 transfers + host sorts per
 Titanic rep) and lets XLA dispatch the training launches of successive model
 families back-to-back with no host sync between them.
 
-What the pass costs (PERF.md, PR 28; one TPU v5e chip, F=5, C=64,
-n=180,224): 3.62 s of device time in a 7.3 s selector fit, against 0.86 s
-for training all 320 models -- it is NOT small next to training.  The sort's
-permutation gathers (``sv[order]``, ``y[order]``, ``vm[order]``) are 2.20 s
-of it and the AuPR curve's ``rec_c[prev]`` gather 1.29 s; the cumulative
-scans (``cumsum`` / ``cummax`` / ``cummin`` lower to ``reduce-window`` on a
-TPU) are milliseconds.  Until PR 28 AuROC's midranks came from two
-``searchsorted(ss, ss)`` calls, ~18 rounds of data-dependent gathers each:
-46.2 s of a 53.5 s fit.  A gather at computed indices is the slowest memory
-access this chip has; tests/test_device_metrics.py keeps the search loop out.
+What the pass costs (PERF.md §5; one TPU v5e chip, F=5, C=64, n=180,224):
+0.17 s of device time in a 3.8 s selector fit, against 0.88 s for training
+all 320 models.  The stable sort that carries the labels and weights with
+the key is 0.13 s of it, the AuPR curve 0.03 s, AuROC's tie-group scans
+4 ms.  Written as an ``argsort`` followed by three permutation gathers
+(2.20 s), with the curve reading the previous recall at a computed index
+(1.29 s), the pass took 3.6 s of a 7.3 s fit; with AuROC's midranks from
+two ``searchsorted(ss, ss)`` calls, ~18 rounds of data-dependent gathers
+each, 46.2 s of a 53.5 s fit.  A gather at computed indices is the slowest
+memory access this chip has; tests/test_device_metrics.py keeps gathers and
+the search loop out of the pass.
 
 Semantics notes (validated against the host evaluators in
 tests/test_device_metrics.py):
@@ -83,10 +84,31 @@ def _tie_bounds(ss):
     return lo, hi
 
 
+def _shift(a, k):
+    """``a`` moved ``k`` places towards the end, zeros (False) shifted in."""
+    return jnp.concatenate([jnp.zeros((k,), a.dtype), a[:-k]])
+
+
+def _latest_flagged(flag, val):
+    """``out[i]`` = ``val[j]`` at the largest ``j <= i`` with ``flag[j]``, or
+    0 where there is none: a log-step scan whose every step only selects,
+    never adds, so the result is one of ``val``'s own elements bit for bit
+    -- and each step streams, where reading ``val`` at a computed index
+    gathers.  (``lax.associative_scan`` with the same select takes the v5e's
+    compiler minutes at the sweep's ``[5, 64, 180224]``; these steps take
+    seconds.)"""
+    val = jnp.where(flag, val, 0.0)
+    k = 1
+    while k < flag.shape[0]:
+        val = jnp.where(flag, val, _shift(val, k))
+        flag = flag | _shift(flag, k)
+        k *= 2
+    return val
+
+
 def _binary_one(y, s, vm, strict):
     """Metrics for ONE (fold, candidate): y f32[n] in {0,1}, s f32[n] class-1
     score, vm f32[n] validation weights, strict f32 scalar."""
-    n = y.shape[0]
     neg_inf = jnp.float32(-jnp.inf)
     sv = jnp.where(vm > 0, s, neg_inf)
     wpos = vm * y
@@ -98,10 +120,10 @@ def _binary_one(y, s, vm, strict):
     # the three scopes name this function's device ops in a profiler trace
     # (metadata only): the sort, the tie-group scans, the curve sums
     with jax.named_scope("metrics.sort"):
-        order = jnp.argsort(sv)  # ascending; excluded (-inf) first
-        ss = sv[order]
-        ys = y[order]
-        vs = vm[order]
+        # ascending, excluded (-inf) first; stable on the one key, so equal
+        # scores keep their row order: the permutation ``argsort`` gives,
+        # applied by the sort itself rather than by three gathers
+        ss, ys, vs = jax.lax.sort((sv, y, vm), num_keys=1, is_stable=True)
 
     # ---- AuROC: rank statistic with midrank ties --------------------------
     with jax.named_scope("metrics.rank"):
@@ -127,11 +149,10 @@ def _binary_one(y, s, vm, strict):
         distinct = (sd != nxt) & finite      # last index of each tie group
         prec_c = tp / jnp.maximum(tp + fp, 1.0)
         rec_c = tp / jnp.maximum(npos, 1.0)
-        idx = jnp.arange(n)
-        dmark = jnp.where(distinct, idx, -1)
-        run = jax.lax.cummax(dmark)          # inclusive last-distinct index
-        prev = jnp.concatenate([jnp.full((1,), -1), run[:-1]])
-        r_prev = jnp.where(prev >= 0, rec_c[jnp.maximum(prev, 0)], 0.0)
+        # recall at the last distinct threshold strictly before i (0 if
+        # none): selected, not read at an index, and not a running max --
+        # a float32 cumsum is not monotone, so a cummax would move AuPR
+        r_prev = _shift(_latest_flagged(distinct, rec_c), 1)
         aupr = jnp.where(
             npos > 0,
             jnp.where(distinct, prec_c * (rec_c - r_prev), 0.0).sum(), 0.0)
