@@ -278,6 +278,143 @@ def test_devcache_counts_uploads_and_hits():
     assert hit["cache_hits"] - first["cache_hits"] == 1
 
 
+#: the fit's host work inside its phases: span -> (parent span, count)
+HOST_SPANS = {"selector.winner_fit": ("selector.refit", 1),
+              "selector.winner_predict": ("selector.evaluate", 2),
+              "selector.metrics": ("selector.evaluate", 2)}
+#: each ``selector.rows`` gather by its ``what``: the span it sits in
+ROW_GATHERS = {"train": "selector.gather", "refit": "selector.refit",
+               "evaluate": "selector.evaluate", "holdout": "selector.evaluate"}
+
+
+def _spans_by_name(tracer):
+    evs = [e for e in tracer.events() if e["ph"] == "X"]
+    name_of = {e["args"]["id"]: e["name"] for e in evs}
+    by_name = {}
+    for e in evs:
+        by_name.setdefault(e["name"], []).append(e)
+    return by_name, name_of
+
+
+def test_fit_spans_its_host_work_inside_its_phases(tracer):
+    sel, ds = _traced_selector()
+    sel.fit(ds)
+    by_name, name_of = _spans_by_name(tracer)
+    for name, (parent, count) in HOST_SPANS.items():
+        got = by_name.get(name, [])
+        assert len(got) == count, (name, len(got))
+        assert {name_of[e["args"]["parent"]] for e in got} == {parent}, name
+    X = ds["features"].values
+    row_bytes = X.shape[1] * X.itemsize
+    rows = {e["args"]["what"]: e for e in by_name["selector.rows"]}
+    assert set(rows) == set(ROW_GATHERS) and len(by_name["selector.rows"]) == 4
+    for what, parent in ROW_GATHERS.items():
+        args = rows[what]["args"]
+        assert name_of[args["parent"]] == parent, what
+        assert args["bytes"] == args["rows"] * row_bytes, what
+    split = by_name["selector.split"][0]["args"]
+    kept = by_name["selector.prepare"][0]["args"]["kept_rows"]
+    assert rows["train"]["args"]["rows"] == kept
+    assert rows["holdout"]["args"]["rows"] == split["holdout_rows"]
+    assert rows["refit"]["args"]["rows"] \
+        == by_name["selector.refit"][0]["args"]["rows"]
+    assert rows["train"]["args"]["bytes"] \
+        == by_name["selector.gather"][0]["args"]["bytes"]
+    assert {e["args"]["what"] for e in by_name["selector.winner_predict"]} \
+        == {"train", "holdout"}
+    assert {e["args"]["what"] for e in by_name["selector.metrics"]} \
+        == {"train", "holdout"}
+    # every uncached upload sits in the sweep's dispatch or in the winner's
+    # device calls, so the accepted idle readers' phases still cover it
+    puts = by_name["devcache.put"]
+    assert {name_of[e["args"]["parent"]] for e in puts} == {
+        "sweep.dispatch", "selector.winner_fit", "selector.winner_predict"}
+    fit_x, = [e for e in puts if e["args"]["tag"] == "X"
+              and name_of[e["args"]["parent"]] == "selector.winner_fit"]
+    assert fit_x["args"]["bytes"] == rows["refit"]["args"]["bytes"]
+    pred_x = sorted(e["args"]["bytes"] for e in puts
+                    if name_of[e["args"]["parent"]] == "selector.winner_predict")
+    assert pred_x == sorted([rows["holdout"]["args"]["bytes"],
+                             rows["evaluate"]["args"]["bytes"]])
+    folds = [e["args"] for e in puts if e["args"]["tag"] == "fold_w"]
+    assert [f["bytes"] for f in folds] == [3 * kept * 4] * 2   # train, val
+
+
+def test_fit_with_the_buffer_off_appends_nothing():
+    from transmogrifai_tpu.obs import trace
+
+    trace.disable()
+    trace.reset()
+    sel, ds = _traced_selector()
+    sel.fit(ds)
+    assert trace.events() == []
+
+
+def test_quantize_spans_the_sketch_alone():
+    # ``sweep.quantize`` = the host sketch (its own span) + the binning, whose
+    # upload of X is an uncached put beside the sketch, not inside it; the
+    # binned matrix is what ``ops/trees.quantize`` gives
+    from transmogrifai_tpu.impl import sweep_fragments
+    from transmogrifai_tpu.obs import trace
+    from transmogrifai_tpu.ops import trees as Tr
+
+    X = np.random.default_rng(3).standard_normal((300, 5)).astype(np.float32)
+    trace.disable()
+    trace.reset()
+    trace.enable(path=None)
+    try:
+        xbs = []
+        assert sweep_fragments._xb_index(xbs, X, 8) == 0
+        by_name, name_of = _spans_by_name(trace)
+    finally:
+        trace.disable()
+        trace.reset()
+    (quant,), (sketch,) = by_name["sweep.quantize"], by_name["sweep.sketch"]
+    assert name_of[sketch["args"]["parent"]] == "sweep.quantize"
+    for stat, want in (("rows", 300), ("width", 5), ("bins", 8)):
+        assert sketch["args"][stat] == quant["args"][stat] == want
+    (put,) = [e for e in by_name["devcache.put"] if e["args"]["tag"] == "bin"]
+    assert name_of[put["args"]["parent"]] == "sweep.quantize"
+    assert put["args"]["bytes"] == X.nbytes
+    assert not sketch["ts"] <= put["ts"] <= sketch["ts"] + sketch["dur"]
+    np.testing.assert_array_equal(np.asarray(xbs[0]), Tr.quantize(X, 8)[0])
+
+
+def test_devcache_put_counts_host_bytes_and_passes_device_arrays():
+    import jax
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu import obs
+    from transmogrifai_tpu.utils import devcache
+
+    X = np.arange(60, dtype=np.float64).reshape(20, 3)
+    before = obs.snapshot()["devcache"]
+    a = devcache.put(X, np.float32, tag="X")
+    first = obs.snapshot()["devcache"]
+    assert isinstance(a, jax.Array) and a.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(a), X.astype(np.float32))
+    # counted at the dtype it lands in: 20 x 3 float32; never cached
+    assert first["put_bytes"] - before["put_bytes"] == 20 * 3 * 4
+    assert first["puts"] - before["puts"] == 1
+    assert first["h2d_bytes"] == before["h2d_bytes"]
+    devcache.put(X, np.float32)
+    again = obs.snapshot()["devcache"]
+    assert again["put_bytes"] - first["put_bytes"] == 20 * 3 * 4
+    # with no dtype, what ``jnp.asarray`` gives: float64 lands as float32
+    assert devcache.put(X).dtype == jnp.asarray(X).dtype
+    # a device array passes through and counts nothing
+    assert devcache.put(a) is a
+    assert devcache.put(a, jnp.float32) is a
+    dev = jax.devices()[0]
+    assert devcache.put(a, device=dev).devices() == {dev}
+    assert obs.snapshot()["devcache"]["put_bytes"] \
+        == again["put_bytes"] + 20 * 3 * 4
+    assert obs.snapshot()["devcache"]["puts"] == again["puts"] + 1
+    # onto a device: ``jax.device_put``'s placement
+    b = devcache.put(X[:2], np.float32, device=dev)
+    assert b.devices() == {dev} and b.dtype == jnp.float32
+
+
 def test_sharded_fit_links_each_shard_to_its_launch(tracer):
     # the default mesh partitions the candidates over the virtual devices:
     # every sweep.shard (a pool or hedge thread) has the sweep.launch as its

@@ -491,11 +491,16 @@ class TestTraceCoverage:
         # the span call itself must not allocate or format when off
         import timeit
 
-        base = timeit.timeit(lambda: None, number=20000)
-        spans = timeit.timeit(lambda: trace.span("x", a=1), number=20000)
+        # best of five repeats for both, so that a busy host (the tests run
+        # under several workers) does not decide
+        base = min(timeit.repeat(lambda: None, number=20000, repeat=5))
+        spans = min(timeit.repeat(lambda: trace.span("x", a=1), number=20000,
+                                  repeat=5))
         # generous bound: a no-op span is within ~20x of an empty lambda
         # (both sub-microsecond); catches accidental allocation/formatting
         assert spans < max(base * 20, 0.05)
+        # the contract itself: an off span reaches no buffer
+        assert not trace.enabled() and trace.events() == []
 
 
 # ---------------------------------------------------------------------------
@@ -520,13 +525,16 @@ class TestLedgerDisabled:
         from transmogrifai_tpu.obs import ledger
 
         ledger.disable()
-        base = timeit.timeit(lambda: None, number=20000)
-        hooks = timeit.timeit(
+        # best of five repeats for both, as the null-span test takes them
+        base = min(timeit.repeat(lambda: None, number=20000, repeat=5))
+        hooks = min(timeit.repeat(
             lambda: ledger.get().launch("x", wall_s=0.0, flops=0.0),
-            number=20000)
+            number=20000, repeat=5))
         # one module-global boolean check + a no-op method: same generous
         # bound the null-span overhead test uses
         assert hooks < max(base * 20, 0.05)
+        # the contract itself: an off ledger records no row
+        assert ledger.rows() == []
 
     def test_enable_reflects_in_get_and_snapshot(self):
         from transmogrifai_tpu.obs import ledger

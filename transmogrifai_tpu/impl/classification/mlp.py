@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops import mlp as M
+from ...utils import devcache
 from ..selector.predictor import PredictorEstimator
 
 
@@ -32,9 +33,9 @@ class OpMultilayerPerceptronClassifier(PredictorEstimator):
         layers = (X.shape[1],) + tuple(int(h) for h in
                                        self.get_param("hidden_layers", (10,))) + (k,)
         sw = np.ones(len(y), np.float32) if w is None else np.asarray(w, np.float32)
-        params = M.fit_mlp(jnp.asarray(X, jnp.float32),
-                           jnp.asarray(np.asarray(y, np.float32)),
-                           jnp.asarray(sw), layers=layers,
+        params = M.fit_mlp(devcache.put(X, jnp.float32, tag="X"),
+                           devcache.put(np.asarray(y, np.float32), tag="y"),
+                           devcache.put(sw, tag="w"), layers=layers,
                            max_iter=int(self.get_param("max_iter", 200)),
                            lr=float(self.get_param("step_size", 0.03)),
                            seed=int(self.get_param("seed", 42)))
@@ -45,7 +46,7 @@ class OpMultilayerPerceptronClassifier(PredictorEstimator):
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         p = [(jnp.asarray(W), jnp.asarray(b)) for W, b in params["weights"]]
-        z, prob, pred = M.predict_mlp(p, jnp.asarray(X, jnp.float32))
+        z, prob, pred = M.predict_mlp(p, devcache.put(X, jnp.float32, tag="X"))
         return np.asarray(pred), np.asarray(z), np.asarray(prob)
 
     #: grid keys the batched sweep understands; others raise -> loop fallback

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..selector.predictor import PredictorEstimator
+from ...utils import devcache
 import functools
 
 
@@ -64,9 +65,9 @@ class OpNaiveBayes(PredictorEstimator):
         k = max(k, 2)
         smoothing = float(self.get_param("smoothing", 1.0))
         model_type = self.get_param("model_type", "multinomial")
-        Xd = jnp.asarray(X if model_type == "multinomial" else (X > 0).astype(np.float32))
-        Y = jax.nn.one_hot(jnp.asarray(y, jnp.int32), k, dtype=jnp.float32)
-        Yw = Y * jnp.asarray(sw)[:, None]
+        Xd = devcache.put(X if model_type == "multinomial" else (X > 0).astype(np.float32), tag="X")
+        Y = jax.nn.one_hot(devcache.put(y, jnp.int32, tag="y"), k, dtype=jnp.float32)
+        Yw = Y * devcache.put(sw, tag="w")[:, None]
         class_mass = Yw.sum(axis=0)                     # [k]
         feat_mass = Yw.T @ Xd                           # [k, d] one MXU matmul
         pi = jnp.log(class_mass + smoothing) - jnp.log(
@@ -129,7 +130,7 @@ class OpNaiveBayes(PredictorEstimator):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        X = jnp.asarray(np.asarray(X, np.float32))
+        X = devcache.put(np.asarray(X, np.float32), tag="X")
         pi = jnp.asarray(params["pi"])
         theta = jnp.asarray(params["theta"])
         if params.get("model_type") == "bernoulli":

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops import linear as L
+from ...utils import devcache
 from ..selector.predictor import PredictorEstimator
 
 
@@ -31,9 +32,9 @@ class OpLinearSVC(PredictorEstimator):
 
     def fit_arrays(self, X: np.ndarray, y: np.ndarray,
                    w: Optional[np.ndarray] = None) -> Dict[str, Any]:
-        X = jnp.asarray(X, jnp.float32)
-        y = jnp.asarray(y, jnp.float32)
-        sw = jnp.ones(X.shape[0], jnp.float32) if w is None else jnp.asarray(w, jnp.float32)
+        X = devcache.put(X, jnp.float32, tag="X")
+        y = devcache.put(y, jnp.float32, tag="y")
+        sw = jnp.ones(X.shape[0], jnp.float32) if w is None else devcache.put(w, jnp.float32, tag="w")
         fit = L.fit_linear_svc(X, y, sw, l2=float(self.get_param("reg_param", 0.0)),
                                max_iter=max(int(self.get_param("max_iter", 100)), 200),
                                fit_intercept=bool(self.get_param("fit_intercept", True)))
@@ -60,7 +61,7 @@ class OpLinearSVC(PredictorEstimator):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]:
-        X = jnp.asarray(X, jnp.float32)
+        X = devcache.put(X, jnp.float32, tag="X")
         raw, pred = L.predict_svc(X, jnp.asarray(params["coef"], jnp.float32),
                                   jnp.asarray(params["intercept"], jnp.float32))
         return np.asarray(pred), np.asarray(raw), None

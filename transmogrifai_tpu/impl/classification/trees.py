@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops import trees as Tr
+from ...utils import devcache
 from ..selector.predictor import PredictorEstimator
 from ..trees_common import (DEFAULT_MAX_FRONTIER, DEFAULT_MAX_FRONTIER_BOOSTED,
                             TreeParamsMixin,
@@ -31,7 +32,7 @@ from ..trees_common import (DEFAULT_MAX_FRONTIER, DEFAULT_MAX_FRONTIER_BOOSTED,
 
 
 def _as_f32(x):
-    return jnp.asarray(np.asarray(x, np.float32))
+    return devcache.put(np.asarray(x, np.float32))
 
 
 class _TreeClassifierBase(TreeParamsMixin, PredictorEstimator):
@@ -104,8 +105,8 @@ class OpRandomForestClassifier(_TreeClassifierBase):
             rate=float(self.get_param("subsampling_rate", 1.0))) * _as_f32(sw)[None, :]
         fms = Tr.kept_features(kf, d, n_trees, self._subset_frac(d))
         mcw = float(self.get_param("min_instances_per_node", 1))
-        forest = Tr.fit_forest(jnp.asarray(Xb), jnp.asarray(G), _as_f32(np.ones(n)),
-                               jnp.asarray(wt), jnp.asarray(fms),
+        forest = Tr.fit_forest(devcache.put(Xb, tag="Xb"), devcache.put(G, tag="y"),
+                               _as_f32(np.ones(n)), jnp.asarray(wt), jnp.asarray(fms),
                                max_depth=depth, n_bins=n_bins,
                                frontier=self._frontier(n, depth, mcw, 1.0),
                                min_child_weight=mcw,
@@ -126,7 +127,7 @@ class OpRandomForestClassifier(_TreeClassifierBase):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        Xb = jnp.asarray(Tr.bin_with_edges(X, params["edges"]))
+        Xb = devcache.put(Tr.bin_with_edges(X, params["edges"]), tag="Xb")
         forest = tree_from_params(params)
         dist = np.asarray(Tr.predict_forest(Xb, forest, int(params["max_depth"])))
         return cls._dist_to_preds(dist, int(params["num_trees"]))
@@ -173,8 +174,9 @@ class OpDecisionTreeClassifier(OpRandomForestClassifier):
         G = self._class_grads(y, k)
         sw = np.ones(n, np.float32) if w is None else np.asarray(w, np.float32)
         mcw = float(self.get_param("min_instances_per_node", 1))
-        forest = Tr.fit_forest(jnp.asarray(Xb), jnp.asarray(G), _as_f32(np.ones(n)),
-                               jnp.asarray(sw[None, :]), jnp.asarray(np.ones((1, d), np.float32)),
+        forest = Tr.fit_forest(devcache.put(Xb, tag="Xb"), devcache.put(G, tag="y"),
+                               _as_f32(np.ones(n)), devcache.put(sw[None, :], tag="w"),
+                               jnp.asarray(np.ones((1, d), np.float32)),
                                max_depth=depth, n_bins=n_bins,
                                frontier=self._frontier(n, depth, mcw, 1.0),
                                min_child_weight=mcw,
@@ -215,7 +217,7 @@ class _BoostedClassifierBase(_TreeClassifierBase):
         # exactly one fit_gbt call
         from ...resilience import checkpointed_gbt_fit
         trees, _ = checkpointed_gbt_fit(
-            Tr.fit_gbt, jnp.asarray(Xb), _as_f32(y), jnp.asarray(sw),
+            Tr.fit_gbt, devcache.put(Xb, tag="Xb"), _as_f32(y), devcache.put(sw, tag="w"),
             jnp.asarray(rw), jnp.asarray(fms), loss=loss,
             n_rounds=bp["n_rounds"], max_depth=bp["max_depth"],
             n_bins=bp["n_bins"], frontier=frontier,
@@ -245,7 +247,7 @@ class _BoostedClassifierBase(_TreeClassifierBase):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        Xb = jnp.asarray(Tr.bin_with_edges(X, params["edges"]))
+        Xb = devcache.put(Tr.bin_with_edges(X, params["edges"]), tag="Xb")
         trees = tree_from_params(params)
         F = Tr.predict_gbt(Xb, trees, int(params["max_depth"]),
                            float(params["eta"]))

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops import linear as L
+from ...utils import devcache
 from ..selector.predictor import PredictorEstimator
 
 
@@ -38,8 +39,8 @@ class OpGeneralizedLinearRegression(PredictorEstimator):
                    w: Optional[np.ndarray] = None) -> Dict[str, Any]:
         sw = np.ones(len(y), np.float32) if w is None else np.asarray(w, np.float32)
         fit = L.fit_glm_irls(
-            jnp.asarray(X, jnp.float32), jnp.asarray(np.asarray(y, np.float32)),
-            jnp.asarray(sw), l2=float(self.get_param("reg_param", 0.0)),
+            devcache.put(X, jnp.float32, tag="X"), devcache.put(np.asarray(y, np.float32), tag="y"),
+            devcache.put(sw, tag="w"), l2=float(self.get_param("reg_param", 0.0)),
             family=self.get_param("family"), link=self.get_param("link"),
             max_iter=int(self.get_param("max_iter", 25)),
             fit_intercept=bool(self.get_param("fit_intercept", True)),
@@ -50,7 +51,7 @@ class OpGeneralizedLinearRegression(PredictorEstimator):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]]:
-        mu = L.predict_glm(jnp.asarray(X, jnp.float32),
+        mu = L.predict_glm(devcache.put(X, jnp.float32, tag="X"),
                            jnp.asarray(params["coef"], jnp.float32),
                            jnp.asarray(params["intercept"], jnp.float32),
                            link=params["link"])

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops import linear as L
+from ...utils import devcache
 from ..selector.predictor import PredictorEstimator
 
 
@@ -29,9 +30,9 @@ class OpLinearRegression(PredictorEstimator):
 
     def fit_arrays(self, X: np.ndarray, y: np.ndarray,
                    w: Optional[np.ndarray] = None) -> Dict[str, Any]:
-        X = jnp.asarray(X, jnp.float32)
-        y = jnp.asarray(y, jnp.float32)
-        sw = jnp.ones(X.shape[0], jnp.float32) if w is None else jnp.asarray(w, jnp.float32)
+        X = devcache.put(X, jnp.float32, tag="X")
+        y = devcache.put(y, jnp.float32, tag="y")
+        sw = jnp.ones(X.shape[0], jnp.float32) if w is None else devcache.put(w, jnp.float32, tag="w")
         reg = float(self.get_param("reg_param", 0.0))
         alpha = float(self.get_param("elastic_net_param", 0.0))
         fit_intercept = bool(self.get_param("fit_intercept", True))
@@ -85,7 +86,7 @@ class OpLinearRegression(PredictorEstimator):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]]:
-        X = jnp.asarray(X, jnp.float32)
+        X = devcache.put(X, jnp.float32, tag="X")
         pred = L.predict_linear(X, jnp.asarray(params["coef"], jnp.float32),
                                 jnp.asarray(params["intercept"], jnp.float32))
         return np.asarray(pred), None, None

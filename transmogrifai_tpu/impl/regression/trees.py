@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops import trees as Tr
+from ...utils import devcache
 from ..selector.predictor import PredictorEstimator
 from ..trees_common import (DEFAULT_MAX_FRONTIER, DEFAULT_MAX_FRONTIER_BOOSTED,
                             TreeParamsMixin,
@@ -64,11 +65,11 @@ class OpRandomForestRegressor(_TreeRegressorBase):
         wt = Tr.bootstrap_weights(
             kb, n, n_trees,
             rate=float(self.get_param("subsampling_rate", 1.0))
-        ) * jnp.asarray(sw)[None, :]
+        ) * devcache.put(sw, tag="w")[None, :]
         fms = Tr.kept_features(kf, d, n_trees, self._subset_frac(d))
-        g = jnp.asarray(-np.asarray(y, np.float32)[:, None])
+        g = devcache.put(-np.asarray(y, np.float32)[:, None], tag="y")
         mcw = float(self.get_param("min_instances_per_node", 1))
-        forest = Tr.fit_forest(jnp.asarray(Xb), g, jnp.ones(n, jnp.float32),
+        forest = Tr.fit_forest(devcache.put(Xb, tag="Xb"), g, jnp.ones(n, jnp.float32),
                                jnp.asarray(wt), jnp.asarray(fms),
                                max_depth=depth, n_bins=n_bins,
                                frontier=self._frontier(n, depth, mcw),
@@ -80,7 +81,7 @@ class OpRandomForestRegressor(_TreeRegressorBase):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]]:
-        Xb = jnp.asarray(Tr.bin_with_edges(X, params["edges"]))
+        Xb = devcache.put(Tr.bin_with_edges(X, params["edges"]), tag="Xb")
         forest = tree_from_params(params)
         pred = np.asarray(Tr.predict_forest(Xb, forest, int(params["max_depth"])))[:, 0]
         return pred.astype(np.float64), None, None
@@ -117,10 +118,10 @@ class OpDecisionTreeRegressor(OpRandomForestRegressor):
         depth = int(self.get_param("max_depth", 5))
         Xb, edges = Tr.quantize(X, n_bins)
         sw = np.ones(n, np.float32) if w is None else np.asarray(w, np.float32)
-        g = jnp.asarray(-np.asarray(y, np.float32)[:, None])
+        g = devcache.put(-np.asarray(y, np.float32)[:, None], tag="y")
         mcw = float(self.get_param("min_instances_per_node", 1))
-        forest = Tr.fit_forest(jnp.asarray(Xb), g, jnp.ones(n, jnp.float32),
-                               jnp.asarray(sw[None, :]),
+        forest = Tr.fit_forest(devcache.put(Xb, tag="Xb"), g, jnp.ones(n, jnp.float32),
+                               devcache.put(sw[None, :], tag="w"),
                                jnp.asarray(np.ones((1, d), np.float32)),
                                max_depth=depth, n_bins=n_bins,
                                frontier=self._frontier(n, depth, mcw),
@@ -156,9 +157,9 @@ class _BoostedRegressorBase(_TreeRegressorBase):
         # exactly one fit_gbt call
         from ...resilience import checkpointed_gbt_fit
         trees, _ = checkpointed_gbt_fit(
-            Tr.fit_gbt, jnp.asarray(Xb),
-            jnp.asarray(np.asarray(y, np.float32)),
-            jnp.asarray(sw), jnp.asarray(rw), jnp.asarray(fms),
+            Tr.fit_gbt, devcache.put(Xb, tag="Xb"),
+            devcache.put(np.asarray(y, np.float32), tag="y"),
+            devcache.put(sw, tag="w"), jnp.asarray(rw), jnp.asarray(fms),
             loss="squared", n_rounds=bp["n_rounds"],
             max_depth=bp["max_depth"], n_bins=bp["n_bins"],
             frontier=frontier,
@@ -174,7 +175,7 @@ class _BoostedRegressorBase(_TreeRegressorBase):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]]:
-        Xb = jnp.asarray(Tr.bin_with_edges(X, params["edges"]))
+        Xb = devcache.put(Tr.bin_with_edges(X, params["edges"]), tag="Xb")
         trees = tree_from_params(params)
         F = Tr.predict_gbt(Xb, trees, int(params["max_depth"]),
                            float(params["eta"]),

@@ -377,7 +377,7 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
 
         with trace.span("selector.gather", bytes=len(train_idx) * row_bytes,
                         handoff=_stream.device_view(vec_col.values) is not None):
-            Xtr = X[train_idx]
+            Xtr = _rows(X, train_idx, "train")
             # device-side handoff: when the streaming transform executor
             # produced this feature matrix, its chunks are still
             # device-resident — gather the training rows ON DEVICE and seed
@@ -406,7 +406,10 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
             else:
                 ridx = np.arange(len(ytr))
             sp.set(rows=len(ridx), bytes=len(ridx) * row_bytes)
-            params = refit.fit_arrays(Xtr[ridx], ytr[ridx])
+            Xr = _rows(Xtr, ridx, "refit")
+            with trace.span("selector.winner_fit", rows=len(ridx)):
+                params = refit.fit_arrays(Xr, ytr[ridx])
+            del Xr
 
         # 5. evaluate train + holdout with every evaluator; train metrics are
         #    computed on the PREPARED training data (the reference evaluates
@@ -415,21 +418,24 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
         evaluators = self.evaluators or [self.validator.evaluator]
         with trace.span("selector.evaluate", holdout_rows=len(hold_idx),
                         bytes=(len(ridx) + len(hold_idx)) * row_bytes):
-            pred_tr, raw_tr, prob_tr = refit.predict_arrays(params, Xtr[ridx])
+            Xr = _rows(Xtr, ridx, "evaluate")
+            pred_tr, prob_tr = _winner_predict(refit, params, Xr, "train")
+            del Xr
             train_eval: Dict[str, Any] = {}
-            for ev in evaluators:
-                train_eval.update(ev.evaluate_arrays(
-                    ytr[ridx], np.asarray(pred_tr),
-                    None if prob_tr is None else np.asarray(prob_tr)))
+            with trace.span("selector.metrics", what="train"):
+                for ev in evaluators:
+                    train_eval.update(ev.evaluate_arrays(
+                        ytr[ridx], pred_tr, prob_tr))
             holdout_eval = None
             if len(hold_idx):
-                Xho, yho = X[hold_idx], y[hold_idx]
-                pred_ho, _, prob_ho = refit.predict_arrays(params, Xho)
+                Xho, yho = _rows(X, hold_idx, "holdout"), y[hold_idx]
+                pred_ho, prob_ho = _winner_predict(refit, params, Xho,
+                                                   "holdout")
                 holdout_eval = {}
-                for ev in evaluators:
-                    holdout_eval.update(ev.evaluate_arrays(
-                        yho, np.asarray(pred_ho),
-                        None if prob_ho is None else np.asarray(prob_ho)))
+                with trace.span("selector.metrics", what="holdout"):
+                    for ev in evaluators:
+                        holdout_eval.update(ev.evaluate_arrays(
+                            yho, pred_ho, prob_ho))
 
         summary = ModelSelectorSummary(
             validation_type=vsummary.validation_type,
@@ -457,6 +463,23 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
         model.metadata = dict(self.metadata)
         model.metadata["model_selector_summary"] = summary.to_json()
         return model
+
+
+def _rows(X: np.ndarray, idx: np.ndarray, what: str) -> np.ndarray:
+    """``X[idx]``: one of a fit's host row gathers, in a ``selector.rows``
+    span (``what``: ``train``, ``refit``, ``evaluate`` or ``holdout``)."""
+    with trace.span("selector.rows", rows=len(idx),
+                    bytes=len(idx) * int(X.shape[1]) * X.itemsize, what=what):
+        return X[idx]
+
+
+def _winner_predict(refit, params, X: np.ndarray, what: str):
+    """The refit winner's ``(prediction, probability | None)`` on ``X`` as
+    host arrays: its upload, device call and the pull, in a
+    ``selector.winner_predict`` span."""
+    with trace.span("selector.winner_predict", rows=len(X), what=what):
+        pred, _, prob = refit.predict_arrays(params, X)
+        return np.asarray(pred), None if prob is None else np.asarray(prob)
 
 
 class SelectedModel(PredictorModel):

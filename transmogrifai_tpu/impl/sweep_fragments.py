@@ -480,9 +480,13 @@ def _xb_index(xbs: List, X: np.ndarray, n_bins: int) -> int:
     """Pre-binned matrix index for ``n_bins`` (cached per X identity)."""
     def binned():
         # host quantile sketch + device binning + the pull and upload back
-        with trace.span("sweep.quantize", rows=int(X.shape[0]),
-                        width=int(X.shape[1]), bins=int(n_bins)):
-            return devcache.device_array(Tr.quantize(X, n_bins)[0],
+        # (``Tr.quantize`` in its two halves, the sketch in a span of its own)
+        stats = dict(rows=int(X.shape[0]), width=int(X.shape[1]),
+                     bins=int(n_bins))
+        with trace.span("sweep.quantize", **stats):
+            with trace.span("sweep.sketch", **stats):
+                edges = Tr.sketch_edges(X, n_bins)
+            return devcache.device_array(Tr.bin_with_edges(X, edges),
                                          tag=f"xb{n_bins}")
 
     dev = devcache.derived(X, ("xb", n_bins), binned)

@@ -13,8 +13,8 @@ which perf lever to pull first.
 Buckets (:data:`BUCKETS`):
 
 - ``host_prep``    — host-blocked preparation: array staging/device upload
-  (``sweep.upload``, ``stream.chunk.upload``), checkpoint writes, flops
-  accounting (``sweep.account``).
+  (``sweep.upload``, ``stream.chunk.upload``, ``devcache.put``), host row
+  gathers (``selector.gather``, ``selector.rows``), checkpoint writes.
 - ``compile``      — XLA lowering/compilation (``sweep.compile``,
   ``serve.rebuild``).
 - ``dispatch``     — launch serialization: async-dispatch enqueue
@@ -63,14 +63,15 @@ BUCKETS = ("host_prep", "compile", "dispatch", "collective", "gather",
 #: instrumented work); structural wrappers classify to None (excluded).
 _EXACT = {
     "sweep.upload": "host_prep",
-    "sweep.account": "host_prep",
     "sweep.checkpoint": "host_prep",
     "stream.chunk.upload": "host_prep",
     "selector.split": "host_prep",
     "selector.prepare": "host_prep",
     "selector.gather": "host_prep",
+    "selector.rows": "host_prep",
     "sweep.plan": "host_prep",
     "devcache.upload": "host_prep",
+    "devcache.put": "host_prep",
     "sweep.compile": "compile",
     "serve.rebuild": "compile",
     "sweep.dispatch": "dispatch",

@@ -677,6 +677,12 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
                           levels=chain["levels"])
         _lg = _ledger.get()
 
+        def _put_folds():
+            # the fold weights and the blob, where they are host arrays
+            return (devcache.put(train_w, tag="fold_w"),
+                    devcache.put(val_w, tag="fold_w"),
+                    devcache.put(blob, tag="blob"))
+
         def _dispatch(ctl=None):
             _inject.maybe_fail("sweep.dispatch", key="fused")
             if ctl is not None:
@@ -684,16 +690,16 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
             _t0 = _lg.now()
             if split:
                 with trace.span("sweep.dispatch", shards=1, split=True):
+                    tw, vw, bl = _put_folds()
                     with mesh_mod.trace_collectives() as colls:
-                        scores = _run_scores(spec, X, tuple(xbs), y, train_w,
-                                             blob)
-                    res = _run_metrics(spec, y, scores, val_w)
+                        scores = _run_scores(spec, X, tuple(xbs), y, tw, bl)
+                    res = _run_metrics(spec, y, scores, vw)
             else:
                 scores = None
                 with trace.span("sweep.dispatch", shards=1, split=False):
+                    tw, vw, bl = _put_folds()
                     with mesh_mod.trace_collectives() as colls:
-                        res = _run(spec, X, tuple(xbs), y, train_w, val_w,
-                                   blob)
+                        res = _run(spec, X, tuple(xbs), y, tw, vw, bl)
             return res, scores, tuple(colls), _lg.now() - _t0
 
         hedged = False
@@ -739,17 +745,15 @@ def run_sweep(spec, X, xbs: Tuple, y, train_w, val_w, blob):
         if _kept_scores["on"]:
             _kept_scores["block"] = scores
         if split:
-            with trace.span("sweep.account", fn="sweep.run_scores+metrics"):
-                costs = [
-                    flops.record("sweep.run_scores", _run_scores, spec, X,
-                                 tuple(xbs), y, train_w, blob),
-                    flops.record("sweep.run_metrics", _run_metrics, spec, y,
-                                 scores, val_w)]
+            costs = [
+                flops.record("sweep.run_scores", _run_scores, spec, X,
+                             tuple(xbs), y, train_w, blob),
+                flops.record("sweep.run_metrics", _run_metrics, spec, y,
+                             scores, val_w)]
             kernel = "sweep.run_scores+metrics"
         else:
-            with trace.span("sweep.account", fn="sweep.run"):
-                costs = [flops.record("sweep.run", _run, spec, X, tuple(xbs),
-                                      y, train_w, val_w, blob)]
+            costs = [flops.record("sweep.run", _run, spec, X, tuple(xbs),
+                                  y, train_w, val_w, blob)]
             kernel = "sweep.run"
         if _lg.enabled:
             # dispatch is async on this path (nothing gathers here), so the
@@ -1168,11 +1172,11 @@ def _shard_arrays(shard, dev, X, xbs, y, X_host, y_host, xb_bins):
     if X_host is not None:
         Xd = devcache.device_array(X_host, np.float32, device=dev)
     else:
-        Xd = jax.device_put(X, dev)
+        Xd = devcache.put(X, tag="X", device=dev)
     if y_host is not None:
         yd = devcache.device_array(y_host, np.float32, device=dev)
     else:
-        yd = jax.device_put(y, dev)
+        yd = devcache.put(y, tag="y", device=dev)
     xbs_d = []
     for i, xb in enumerate(xbs):
         if X_host is not None and xb_bins is not None:
@@ -1180,7 +1184,7 @@ def _shard_arrays(shard, dev, X, xbs, y, X_host, y_host, xb_bins):
                 X_host, ("sweep_xb_dev", int(xb_bins[i]), str(dev)),
                 lambda xb=xb: jax.device_put(xb, dev)))
         else:
-            xbs_d.append(jax.device_put(xb, dev))
+            xbs_d.append(devcache.put(xb, tag="xb", device=dev))
     return Xd, tuple(xbs_d), yd
 
 
@@ -1249,9 +1253,9 @@ def run_sweep_partitioned(shards, X, xbs: Tuple, y, train_w, val_w,
             with trace.span("sweep.upload", device=str(dev), shard=idx):
                 Xd, xbs_d, yd = _shard_arrays(shard, dev, X, xbs, y,
                                               X_host, y_host, xb_bins)
-                tw = jax.device_put(jnp.asarray(train_w), dev)
-                vw = jax.device_put(jnp.asarray(val_w), dev)
-                bl = jax.device_put(jnp.asarray(shard.blob), dev)
+                tw = devcache.put(train_w, tag="fold_w", device=dev)
+                vw = devcache.put(val_w, tag="fold_w", device=dev)
+                bl = devcache.put(shard.blob, tag="blob", device=dev)
             C_s = len(shard.cis)
             # the pipelined path NEEDS the two-launch stage split: the
             # scores/metrics boundary is where one shard's scoring can
@@ -1682,14 +1686,14 @@ def run_sweep_rowsharded(shards, X, xbs: Tuple, y, train_w, val_w,
                 n_pad = int(Xd.shape[0])
                 fold_sh = NamedSharding(submesh,
                                         P(None, mesh_mod.DATA_AXIS))
-                tw = jax.device_put(
+                tw = devcache.put(
                     mesh_mod.pad_to_multiple(tw_host, n_data, axis=1)[0],
-                    fold_sh)
-                vw = jax.device_put(
+                    tag="fold_w", device=fold_sh)
+                vw = devcache.put(
                     mesh_mod.pad_to_multiple(vw_host, n_data, axis=1)[0],
-                    fold_sh)
-                bl = jax.device_put(np.asarray(shard.blob, np.float32),
-                                    NamedSharding(submesh, P()))
+                    tag="fold_w", device=fold_sh)
+                bl = devcache.put(shard.blob, np.float32, tag="blob",
+                                  device=NamedSharding(submesh, P()))
             args = (Xd, xbs_d, yd, tw, vw, bl)
             compiled, compile_s, colls = _aot_rs(shard.spec, submesh, n_orig,
                                                  args)

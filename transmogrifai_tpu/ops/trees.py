@@ -58,8 +58,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-
 from ..parallel.mesh import mesh_psum, record_trace_event
+from ..utils import devcache
 
 
 class Tree(NamedTuple):
@@ -123,11 +123,11 @@ def bin_with_edges(X: np.ndarray, edges: np.ndarray,
     dt = _bin_dtype(n_bins)
     ed = jnp.asarray(edges)
     if n <= chunk:
-        return np.asarray(_bin_chunk(jnp.asarray(X), ed)).astype(dt)
+        return np.asarray(_bin_chunk(devcache.put(X, tag="bin"), ed)).astype(dt)
     out = np.empty(X.shape, dt)
     for lo in range(0, n, chunk):
         hi = min(lo + chunk, n)
-        out[lo:hi] = np.asarray(_bin_chunk(jnp.asarray(X[lo:hi]), ed)).astype(dt)
+        out[lo:hi] = np.asarray(_bin_chunk(devcache.put(X[lo:hi], tag="bin"), ed)).astype(dt)
     return out
 
 

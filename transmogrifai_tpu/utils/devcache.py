@@ -30,11 +30,12 @@ import numpy as np
 from ..obs import registry as obs_registry
 from ..obs import trace
 
-#: what crossed host -> device through this cache, for ``obs.snapshot()``
-#: (``["devcache"]``): bytes and count of real uploads, and lookups served
-#: from a resident buffer
+#: what crossed host -> device, for ``obs.snapshot()`` (``["devcache"]``):
+#: bytes and count of this cache's real uploads, lookups served from a
+#: resident buffer, and bytes and count of the uncached uploads of :func:`put`
 _scope = obs_registry.scope("devcache", defaults={
-    "h2d_bytes": 0, "h2d_uploads": 0, "cache_hits": 0})
+    "h2d_bytes": 0, "h2d_uploads": 0, "cache_hits": 0,
+    "put_bytes": 0, "puts": 0})
 
 
 class DevCacheMutationError(RuntimeError):
@@ -127,6 +128,38 @@ def device_array(arr, dtype=None, tag: str = "base", device=None):
     else:
         _scope.inc("cache_hits")
     return dev
+
+
+def put(x, dtype=None, tag: str = "put", device=None):
+    """Uncached upload: what ``jnp.asarray(x, dtype)`` does (with
+    ``device``, what ``jax.device_put`` onto it does), seen by the trace and
+    the counters.
+
+    For a one-off host array (an estimator's rows, a fold-weight block) that
+    :func:`device_array` would only cache to no use.  The copy runs in a
+    ``devcache.put`` span and adds to ``put_bytes`` / ``puts``; its size is
+    the element count x the width of the dtype it lands in.  An argument
+    that is already a ``jax.Array`` passes through (converted to ``dtype``,
+    placed on ``device``) and counts 0 bytes.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    if isinstance(x, jax.Array):
+        a = x if dtype is None else jnp.asarray(x, dtype)
+        return a if device is None else jax.device_put(a, device)
+    dt = jax.dtypes.canonicalize_dtype(
+        np.asarray(x).dtype if dtype is None else dtype)
+    nbytes = int(np.size(x)) * dt.itemsize
+    with trace.span("devcache.put", bytes=nbytes, tag=tag):
+        if device is not None:
+            a = jax.device_put(x if dtype is None else np.asarray(x, dtype),
+                               device)
+        else:
+            a = jnp.asarray(x) if dtype is None else jnp.asarray(x, dtype)
+    _scope.inc("put_bytes", nbytes)
+    _scope.inc("puts")
+    return a
 
 
 def seed(arr: np.ndarray, dev, dtype=None, tag: str = "base",
