@@ -194,7 +194,25 @@ def tracer():
     trace.reset()
 
 
+@pytest.fixture
+def host_path(monkeypatch):
+    """The selector's size rule forced to the host path: a device limit
+    under which no matrix fits (8 bytes, so an eighth of it is 1)."""
+    from transmogrifai_tpu.impl.selector import model_selector
+
+    monkeypatch.setattr(model_selector, "_device_bytes_limit", lambda: 8)
+
+
 def test_fit_opens_each_span_once_under_one_request(tracer):
+    _check_fit_opens_each_span_once(tracer, "device")
+
+
+def test_fit_opens_each_span_once_under_one_request_on_the_host_path(
+        tracer, host_path):
+    _check_fit_opens_each_span_once(tracer, "host")
+
+
+def _check_fit_opens_each_span_once(tracer, where):
     sel, ds = _traced_selector()
     sel.fit(ds)
     evs = [e for e in tracer.events() if e["ph"] == "X"]
@@ -203,11 +221,19 @@ def test_fit_opens_each_span_once_under_one_request(tracer):
         by_name.setdefault(e["name"], []).append(e)
     for name in FIT_SPANS:
         assert len(by_name.get(name, [])) == 1, (name, sorted(by_name))
-    # X and y go up once each, under the plan
+    # X and y go up once each: y under the plan; X under the plan as the
+    # sweep's rows on the host path, whole under the gather on the device
+    # path (the resident matrix the selector takes its rows from)
     uploads = by_name["devcache.upload"]
     assert len(uploads) == 2
     plan_id = by_name["sweep.plan"][0]["args"]["id"]
-    assert all(u["args"]["parent"] == plan_id for u in uploads)
+    gather_id = by_name["selector.gather"][0]["args"]["id"]
+    X_bytes = ds["features"].values.nbytes
+    assert [u["args"]["parent"] for u in uploads
+            if u["args"]["bytes"] == X_bytes] \
+        == ([gather_id] if where == "device" else [])
+    assert all(u["args"]["parent"] == plan_id for u in uploads
+               if u["args"]["bytes"] != X_bytes)
     # one tree: every span reaches selector.fit through its parents ...
     root = by_name["selector.fit"][0]["args"]
     parent_of = {e["args"]["id"]: e["args"]["parent"] for e in evs}
@@ -297,6 +323,15 @@ def _spans_by_name(tracer):
 
 
 def test_fit_spans_its_host_work_inside_its_phases(tracer):
+    _check_fit_spans_its_host_work(tracer, "device")
+
+
+def test_fit_spans_its_host_work_inside_its_phases_on_the_host_path(
+        tracer, host_path):
+    _check_fit_spans_its_host_work(tracer, "host")
+
+
+def _check_fit_spans_its_host_work(tracer, where):
     sel, ds = _traced_selector()
     sel.fit(ds)
     by_name, name_of = _spans_by_name(tracer)
@@ -312,6 +347,7 @@ def test_fit_spans_its_host_work_inside_its_phases(tracer):
         args = rows[what]["args"]
         assert name_of[args["parent"]] == parent, what
         assert args["bytes"] == args["rows"] * row_bytes, what
+        assert args["where"] == where, what
     split = by_name["selector.split"][0]["args"]
     kept = by_name["selector.prepare"][0]["args"]["kept_rows"]
     assert rows["train"]["args"]["rows"] == kept
@@ -327,15 +363,23 @@ def test_fit_spans_its_host_work_inside_its_phases(tracer):
     # every uncached upload sits in the sweep's dispatch or in the winner's
     # device calls, so the accepted idle readers' phases still cover it
     puts = by_name["devcache.put"]
-    assert {name_of[e["args"]["parent"]] for e in puts} == {
-        "sweep.dispatch", "selector.winner_fit", "selector.winner_predict"}
-    fit_x, = [e for e in puts if e["args"]["tag"] == "X"
-              and name_of[e["args"]["parent"]] == "selector.winner_fit"]
-    assert fit_x["args"]["bytes"] == rows["refit"]["args"]["bytes"]
-    pred_x = sorted(e["args"]["bytes"] for e in puts
-                    if name_of[e["args"]["parent"]] == "selector.winner_predict")
-    assert pred_x == sorted([rows["holdout"]["args"]["bytes"],
-                             rows["evaluate"]["args"]["bytes"]])
+    xs = [e for e in puts if e["args"]["tag"] == "X"]
+    if where == "device":
+        # the winner takes its rows as the device gave them: no row matrix
+        # goes up past the cache
+        assert xs == []
+        assert {name_of[e["args"]["parent"]] for e in puts} == {
+            "sweep.dispatch", "selector.winner_fit"}
+    else:
+        assert {name_of[e["args"]["parent"]] for e in puts} == {
+            "sweep.dispatch", "selector.winner_fit", "selector.winner_predict"}
+        fit_x, = [e for e in xs
+                  if name_of[e["args"]["parent"]] == "selector.winner_fit"]
+        assert fit_x["args"]["bytes"] == rows["refit"]["args"]["bytes"]
+        pred_x = sorted(e["args"]["bytes"] for e in xs if name_of[
+            e["args"]["parent"]] == "selector.winner_predict")
+        assert pred_x == sorted([rows["holdout"]["args"]["bytes"],
+                                 rows["evaluate"]["args"]["bytes"]])
     folds = [e["args"] for e in puts if e["args"]["tag"] == "fold_w"]
     assert [f["bytes"] for f in folds] == [3 * kept * 4] * 2   # train, val
 

@@ -10,7 +10,7 @@ atol 1e-6 — XLA fuses the multiply-add, numpy doesn't, so the last
 
 Also covers: padded-tail mask contract, multi-chunk + at-most-one
 steady-state compile telemetry, liveness (device-only intermediates),
-the model-selector device handoff, the jax_chunkable opt-out, the
+the model-selector device view, the jax_chunkable opt-out, the
 too-few-stages fallback, and an end-to-end workflow train/score run
 under forced-small chunk envs.
 """
@@ -144,6 +144,8 @@ def test_liveness_keeps_intermediates_device_only(monkeypatch):
 
 def test_handoff_device_view_and_devcache_seed(monkeypatch):
     monkeypatch.setenv("TMOG_TRANSFORM_CHUNK_ROWS", "64")
+    from transmogrifai_tpu import obs
+    from transmogrifai_tpu.impl.selector import model_selector
     from transmogrifai_tpu.utils import devcache
 
     ds = _mkds(237, seed=4)
@@ -158,14 +160,17 @@ def test_handoff_device_view_and_devcache_seed(monkeypatch):
     assert view is not None
     np.testing.assert_array_equal(np.asarray(view), X)  # pad sliced off
 
+    # the selector takes its training rows from the view on the device ...
     idx = np.arange(0, len(ds), 3)
-    Xtr = X[idx]
-    assert stream.handoff_rows(X, Xtr, idx)
-    s = stream.stream_stats()
-    assert s["device_handoffs"] == 1 and s["handoff_bytes"] > 0
-    # the sweep's upload call now resolves to the resident gather
-    dev = devcache.device_array(Xtr, np.float32)
-    np.testing.assert_array_equal(np.asarray(dev), Xtr)
+    Xtr = model_selector._rows(view, idx, "train")
+    np.testing.assert_array_equal(np.asarray(Xtr), X[idx])
+    # ... and the sweep's upload calls take them as they are: no re-upload
+    before = obs.snapshot()["devcache"]
+    assert devcache.device_array(Xtr, np.float32) is Xtr
+    assert devcache.put(Xtr, np.float32) is Xtr
+    after = obs.snapshot()["devcache"]
+    assert after["h2d_bytes"] == before["h2d_bytes"]
+    assert after["put_bytes"] == before["put_bytes"]
     stream.clear_views()
 
 

@@ -8,8 +8,8 @@ rtol 2e-6 / atol 1e-6 (the documented XLA fusion tolerance) — across
 divide/remainder/exceed chunkings at 2/4/8 data shards.
 
 Also covers: the double-padding edge (chunk tail x shard tail both
-zero-filled and mask-aware), sharded handoff -> devcache resolution,
-the overlap_efficiency floor on a multi-chunk prefetched run, winner
+zero-filled and mask-aware), a sharded device view as the selector's row
+source, the overlap_efficiency floor on a multi-chunk prefetched run, winner
 scoring routed through the sharded head with recorded (never raised)
 fallbacks, Chan-merge sharded column moments vs numpy, and the
 compiles <= n_shards telemetry contract (one program per chip).
@@ -168,6 +168,8 @@ def test_double_padding_edge(monkeypatch):
 def test_sharded_handoff_devcache_skips_reupload(monkeypatch):
     monkeypatch.setenv("TMOG_TRANSFORM_CHUNK_ROWS", "64")
     monkeypatch.setenv("TMOG_STREAM_SHARDS", "4")
+    from transmogrifai_tpu import obs
+    from transmogrifai_tpu.impl.selector import model_selector
     from transmogrifai_tpu.utils import devcache
 
     ds = _mkds(237, seed=3)
@@ -183,14 +185,17 @@ def test_sharded_handoff_devcache_skips_reupload(monkeypatch):
     assert view is not None
     np.testing.assert_array_equal(np.asarray(view), X)
 
+    # the selector takes its training rows from the view on the device ...
     idx = np.arange(0, len(ds), 3)
-    Xtr = X[idx]
-    assert stream.handoff_rows(X, Xtr, idx)
-    s = stream.stream_stats()
-    assert s["device_handoffs"] == 1 and s["handoff_bytes"] > 0
-    # the sweep's upload call resolves to the resident gather — no re-upload
-    dev = devcache.device_array(Xtr, np.float32)
-    np.testing.assert_array_equal(np.asarray(dev), Xtr)
+    Xtr = model_selector._rows(view, idx, "train")
+    np.testing.assert_array_equal(np.asarray(Xtr), X[idx])
+    # ... and the sweep's upload calls take them as they are: no re-upload
+    before = obs.snapshot()["devcache"]
+    assert devcache.device_array(Xtr, np.float32) is Xtr
+    assert devcache.put(Xtr, np.float32) is Xtr
+    after = obs.snapshot()["devcache"]
+    assert after["h2d_bytes"] == before["h2d_bytes"]
+    assert after["put_bytes"] == before["put_bytes"]
     stream.clear_views()
 
 

@@ -130,7 +130,7 @@ class OpNaiveBayes(PredictorEstimator):
     @classmethod
     def predict_arrays(cls, params: Dict[str, Any], X: np.ndarray
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        X = devcache.put(np.asarray(X, np.float32), tag="X")
+        X = devcache.put(X, np.float32, tag="X")
         pi = jnp.asarray(params["pi"])
         theta = jnp.asarray(params["theta"])
         if params.get("model_type") == "bernoulli":

@@ -45,7 +45,7 @@ class OpLinearSVC(PredictorEstimator):
 
         l2s, g = shard_candidates(
             self._grid_param_arrays(grids, ("reg_param",))["reg_param"], fill=1.0)
-        Xd = replicate_input(np.asarray(X, np.float32))
+        Xd = replicate_input(devcache.as_f32(X))
         yd = replicate_input(np.asarray(y, np.float32))
         fits = L.fit_svc_grid_folds(Xd, yd, replicate_input(np.asarray(train_w, np.float32)),
                                     l2s,

@@ -55,7 +55,7 @@ class OpLinearRegression(PredictorEstimator):
         l2 = reg * (1.0 - alpha)
         from ...parallel.mesh import replicate_input, shard_candidates
 
-        Xd = replicate_input(np.asarray(X, np.float32))
+        Xd = replicate_input(devcache.as_f32(X))
         yd = replicate_input(np.asarray(y, np.float32))
         twd = replicate_input(np.asarray(train_w, np.float32))
         F, G = train_w.shape[0], len(grids)

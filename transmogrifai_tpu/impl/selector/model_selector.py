@@ -15,11 +15,14 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ... import types as T
 from ...columns import Column, Dataset, NumericColumn, VectorColumn
 from ...evaluators.base import OpEvaluatorBase
+from ...obs import registry as obs_registry
 from ...obs import trace
 from ...stages.base import AllowLabelAsInput, BinaryEstimator
 from ..tuning.splitters import Splitter, SplitterSummary
@@ -29,6 +32,13 @@ from .predictor import PredictorEstimator, PredictorModel
 #: Prediction/label column keys in summaries (reference ModelSelectorNames)
 HOLDOUT_EVAL = "holdoutEvaluation"
 TRAIN_EVAL = "trainEvaluation"
+
+#: how a fit takes its row sets, for ``obs.snapshot()`` (``["selector"]``):
+#: count and bytes of the ``selector.rows`` gathers taken on the device from
+#: the resident matrix, and of those taken by numpy on the host
+_scope = obs_registry.scope("selector", defaults={
+    "device_row_gathers": 0, "device_row_bytes": 0,
+    "host_row_gathers": 0, "host_row_bytes": 0})
 
 
 def _scrub(obj: Any) -> Any:
@@ -328,12 +338,13 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
 
     def _fit_columns(self, label_col: NumericColumn,
                      vec_col: VectorColumn) -> "SelectedModel":
+        from ...workflow import stream as _stream
+
+        values = vec_col.values
         keep = label_col.mask
-        # avoid a full-matrix copy when no labels are missing (10M x p data)
-        X = vec_col.values if keep.all() else vec_col.values[keep]
         y = label_col.values[keep].astype(np.float32)
         n = len(y)
-        row_bytes = int(X.shape[1]) * X.itemsize
+        row_bytes = int(values.shape[1]) * values.itemsize
 
         # 1. holdout reservation (splitter.split, Splitter.scala:58)
         with trace.span("selector.split", rows=n) as sp:
@@ -373,20 +384,28 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
                     prep_w = prep_w[sub]
             sp.set(kept_rows=len(train_idx))
 
-        from ...workflow import stream as _stream
-
+        view = _stream.device_view(values)
         with trace.span("selector.gather", bytes=len(train_idx) * row_bytes,
-                        handoff=_stream.device_view(vec_col.values) is not None):
-            Xtr = _rows(X, train_idx, "train")
-            # device-side handoff: when the streaming transform executor
-            # produced this feature matrix, its chunks are still
-            # device-resident — gather the training rows ON DEVICE and seed
-            # the sweep's devcache under Xtr's identity, so the fused sweep
-            # finds a resident buffer instead of re-uploading the host matrix
-            # (workflow/stream.handoff_rows)
-            _stream.handoff_rows(
-                vec_col.values, Xtr,
-                train_idx if keep.all() else np.flatnonzero(keep)[train_idx])
+                        handoff=view is not None):
+            # the streamed transform's device view of the matrix where there
+            # is one, else the device cache's copy where the size rule takes
+            # one (_resident), else the host matrix
+            src = view if view is not None else _resident(values)
+            if src is None:
+                # avoid a full-matrix copy when no labels are missing (10M x
+                # p data)
+                X, at = (values if keep.all() else values[keep]), None
+            else:
+                # the labelled rows' positions in the resident matrix
+                X, at = src, None if keep.all() else np.flatnonzero(keep)
+
+            def rows(idx: np.ndarray, what: str):
+                """The labelled rows ``idx`` of the fit's matrix."""
+                return _rows(X, idx if at is None else at[idx], what)
+
+            Xtr = rows(train_idx, "train")
+            if view is not None:
+                _stream.count_handoff(Xtr.nbytes)
 
         # 3. the sweep (skipped when workflow-level CV already chose a winner)
         if self.best_estimator is not None:
@@ -428,7 +447,7 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
                         ytr[ridx], pred_tr, prob_tr))
             holdout_eval = None
             if len(hold_idx):
-                Xho, yho = _rows(X, hold_idx, "holdout"), y[hold_idx]
+                Xho, yho = rows(hold_idx, "holdout"), y[hold_idx]
                 pred_ho, prob_ho = _winner_predict(refit, params, Xho,
                                                    "holdout")
                 holdout_eval = {}
@@ -465,18 +484,56 @@ class ModelSelector(BinaryEstimator, AllowLabelAsInput):
         return model
 
 
-def _rows(X: np.ndarray, idx: np.ndarray, what: str) -> np.ndarray:
-    """``X[idx]``: one of a fit's host row gathers, in a ``selector.rows``
-    span (``what``: ``train``, ``refit``, ``evaluate`` or ``holdout``)."""
-    with trace.span("selector.rows", rows=len(idx),
-                    bytes=len(idx) * int(X.shape[1]) * X.itemsize, what=what):
-        return X[idx]
+def _device_bytes_limit() -> Optional[int]:
+    """The default device's memory limit in bytes; None where the backend
+    reports none (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
 
 
-def _winner_predict(refit, params, X: np.ndarray, what: str):
+def _resident(values: np.ndarray) -> Optional[jax.Array]:
+    """The device cache's float32 copy of the fit's matrix, to take its row
+    sets from, or None for the host path.
+
+    The copy is keyed by the column array's identity and lives as long as
+    the column does, so a column fitted again is uploaded once.  A copy the
+    cache already holds is taken as it is.  A new one is made where it and
+    everything the cache and the streamed views already keep on the device
+    come to at most an eighth of the device's memory limit, or where the
+    backend states no limit; so the copies of several columns stay under
+    that eighth together."""
+    from ...utils import devcache
+    from ...workflow import stream as _stream
+
+    limit = _device_bytes_limit()
+    if limit is not None and not devcache.holds(values, np.float32):
+        held = devcache.resident_bytes() + _stream.view_bytes()
+        if held + values.size * 4 > limit // 8:
+            return None
+    return devcache.device_array(values, np.float32)
+
+
+def _rows(X, idx: np.ndarray, what: str):
+    """``X[idx]``: one of a fit's row gathers, in a ``selector.rows`` span
+    (``what``: ``train``, ``refit``, ``evaluate`` or ``holdout``).  From a
+    ``jax.Array`` it is a ``jnp.take`` on the device (``where="device"``),
+    from a host array numpy's copy (``where="host"``); the ``selector``
+    scope counts both."""
+    where = "device" if isinstance(X, jax.Array) else "host"
+    nbytes = len(idx) * int(X.shape[1]) * X.dtype.itemsize
+    with trace.span("selector.rows", rows=len(idx), bytes=nbytes, what=what,
+                    where=where):
+        out = jnp.take(X, jnp.asarray(idx), axis=0) if where == "device" \
+            else X[idx]
+    _scope.inc(f"{where}_row_gathers")
+    _scope.inc(f"{where}_row_bytes", nbytes)
+    return out
+
+
+def _winner_predict(refit, params, X, what: str):
     """The refit winner's ``(prediction, probability | None)`` on ``X`` as
-    host arrays: its upload, device call and the pull, in a
-    ``selector.winner_predict`` span."""
+    host arrays: its upload (none for rows on the device), device call and
+    the pull, in a ``selector.winner_predict`` span."""
     with trace.span("selector.winner_predict", rows=len(X), what=what):
         pred, _, prob = refit.predict_arrays(params, X)
         return np.asarray(pred), None if prob is None else np.asarray(prob)

@@ -793,7 +793,7 @@ def build_sweep_plan(candidates: Sequence[Tuple[Any, Sequence[Dict[str, Any]]]],
     else:
         return None
 
-    X = np.ascontiguousarray(np.asarray(X, np.float32))
+    X = devcache.as_f32(X)  # a jax.Array stays on the device
     blob = _Blob()
     xbs: List = []
     frags: List = []

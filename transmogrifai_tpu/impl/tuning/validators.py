@@ -273,21 +273,11 @@ class OpValidator:
                 candidates, max(int(budget // max(per_cand, 1.0)), 1))
             # convert ONCE: devcache keys device buffers by host-array
             # identity, so each chunk's plan must see the SAME ndarray or
-            # every chunk re-uploads and re-quantizes the matrix.  When the
-            # selector seeded a streamed device-resident X (f32, contiguous),
-            # the conversion is the identity and the seed survives; any other
-            # dtype/layout gets its cached f32 product carried over so the
-            # device-side handoff is never silently dropped.
-            Xc = np.ascontiguousarray(np.asarray(X, np.float32))
-            if Xc is not X:
-                from ...utils import devcache as _devcache
+            # every chunk re-uploads and re-quantizes the matrix.  The
+            # selector's device rows (a jax.Array) stay where they are.
+            from ...utils import devcache as _devcache
 
-                prior = _devcache._slot(X)
-                dev = prior.get(("base", np.dtype(np.float32).str, None)) \
-                    if prior else None
-                if dev is not None:
-                    _devcache.seed(Xc, dev, np.float32)
-            X = Xc
+            X = _devcache.as_f32(X)
             plans = []
             for chunk in chunks:
                 with trace.span("sweep.plan", rows=len(y), candidates=sum(

@@ -117,7 +117,7 @@ def bin_with_edges(X: np.ndarray, edges: np.ndarray,
     Bin b holds values in (edges[b-1], edges[b]]; value <= edges[0] is bin 0;
     value > edges[-1] is the last bin.
     """
-    X = np.asarray(X, np.float32)
+    X = devcache.as_f32(X)  # a jax.Array is binned where it lies
     n = X.shape[0]
     n_bins = edges.shape[1] + 1
     dt = _bin_dtype(n_bins)

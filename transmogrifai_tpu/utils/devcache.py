@@ -130,6 +130,25 @@ def device_array(arr, dtype=None, tag: str = "base", device=None):
     return dev
 
 
+def holds(arr, dtype=None, tag: str = "base", device=None) -> bool:
+    """Whether :func:`device_array` would serve ``arr`` from the cache."""
+    ent = _entries.get(id(arr)) if isinstance(arr, np.ndarray) else None
+    return ent is not None and (
+        tag, None if dtype is None else np.dtype(dtype).str,
+        None if device is None else str(device)) in ent["products"]
+
+
+def resident_bytes() -> int:
+    """The bytes of every device buffer the cache keeps, derived products
+    included."""
+    import jax
+
+    return sum(int(leaf.nbytes) for ent in list(_entries.values())
+               for leaf in jax.tree_util.tree_leaves(
+                   list(ent["products"].values()))
+               if isinstance(leaf, jax.Array))
+
+
 def put(x, dtype=None, tag: str = "put", device=None):
     """Uncached upload: what ``jnp.asarray(x, dtype)`` does (with
     ``device``, what ``jax.device_put`` onto it does), seen by the trace and
@@ -162,12 +181,25 @@ def put(x, dtype=None, tag: str = "put", device=None):
     return a
 
 
+def as_f32(x):
+    """``x`` in float32 where it already lies: a ``jax.Array`` stays on its
+    device (cast there, itself when already float32), anything else becomes
+    a C-contiguous host ``np.ndarray``.  For consumers that take either the
+    selector's device rows or a host matrix, so that neither is moved."""
+    import jax
+    import jax.numpy as jnp
+
+    if isinstance(x, jax.Array):
+        return jnp.asarray(x, jnp.float32)
+    return np.ascontiguousarray(np.asarray(x, np.float32))
+
+
 def seed(arr: np.ndarray, dev, dtype=None, tag: str = "base",
          device=None) -> bool:
     """Pre-populate ``arr``'s cached device product with ``dev``.
 
-    The streaming transform executor uses this to hand a freshly computed
-    device-resident matrix straight to the selector sweep: after seeding,
+    The serving executor uses this to keep a freshly computed
+    device-resident matrix discoverable: after seeding,
     ``device_array(arr, dtype)`` returns ``dev`` without re-uploading the
     host copy.  The caller GUARANTEES ``dev`` equals ``arr`` (same values,
     rows, dtype) — the contract is the same as the no-in-place-mutation one

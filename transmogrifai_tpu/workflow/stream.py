@@ -32,10 +32,9 @@ the cliff:
   With TMOG_MESH unset and no explicit shard request the executor is
   bit-identical to the single-device path.
 - When a downstream consumer is the model selector, the final feature
-  matrix chunks are additionally kept device-side (``device_view`` /
-  ``handoff_rows``) and seeded into ``utils.devcache`` so the selector
-  sweep's ``devcache.device_array(X, float32)`` finds the resident buffer
-  and skips the host->device re-upload entirely.
+  matrix chunks are additionally kept device-side (``device_view``): the
+  selector takes its row sets from that view on the device, and the host
+  matrix never goes back up.
 
 Chunk-safe ``jax_transform`` contract (documented here, asserted in the
 planner): stages must be row-wise — output row i depends only on input
@@ -145,7 +144,7 @@ def enabled() -> bool:
 def handoff_budget_bytes() -> int:
     """Device-byte budget for keeping selector-bound output chunks resident
     (TMOG_STREAM_HANDOFF_BYTES, default 2 GiB).  Above it the handoff is
-    skipped and the selector re-uploads from host as before."""
+    skipped and the selector takes its rows as from any other matrix."""
     return _knob("TMOG_STREAM_HANDOFF_BYTES", 2_147_483_648,
                  "handoff_budget_bytes", floor=None)
 
@@ -619,7 +618,7 @@ def _register_view(host_arr: np.ndarray, chunks: List[Tuple[Any, int]],
     except TypeError:
         return False
     _views[key] = {"_ref": ref, "chunks": list(chunks), "full": None,
-                   "rows": n_rows}
+                   "rows": n_rows, "bytes": total}
     return True
 
 
@@ -650,24 +649,16 @@ def device_view(host_arr) -> Optional[Any]:
     return ent["full"]
 
 
-def handoff_rows(src_host, dst_host, idx) -> bool:
-    """Device-side row gather: when ``src_host`` has a streamed device view,
-    compute ``src[idx]`` on device and seed it into devcache under
-    ``dst_host``'s identity, so the sweep's ``device_array(dst, float32)``
-    resolves to the resident buffer and the host matrix never re-uploads."""
-    view = device_view(src_host)
-    if view is None:
-        return False
-    import jax.numpy as jnp
-
-    from ..utils import devcache
-
-    dev = jnp.take(view, jnp.asarray(np.asarray(idx)), axis=0)
-    if not devcache.seed(dst_host, dev, np.float32):
-        return False
+def count_handoff(nbytes: int) -> None:
+    """One selector fit's training rows taken on the device from a streamed
+    device view."""
     _stream_scope.inc("device_handoffs")
-    _stream_scope.inc("handoff_bytes", float(dev.nbytes))
-    return True
+    _stream_scope.inc("handoff_bytes", float(nbytes))
+
+
+def view_bytes() -> int:
+    """The bytes the registered device views keep on the device."""
+    return sum(int(ent["bytes"]) for ent in list(_views.values()))
 
 
 def clear_views() -> None:

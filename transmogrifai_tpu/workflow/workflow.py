@@ -170,7 +170,7 @@ class OpWorkflow(OpWorkflowCore):
         # per-train streaming telemetry window (ops/sweep.reset_run_stats
         # cadence): stream_stats() after train() reports THIS run's chunk
         # counts / streamed bytes / compiles, and stale device views from a
-        # prior train cannot serve a new fit's handoff
+        # prior train cannot serve a new fit's rows
         stream.reset_stream_stats()
         stream.clear_views()
         data = self._generate_raw_data(params)
